@@ -84,18 +84,23 @@ class MutualInformation(Job):
         names = [schema.field_by_ordinal(f.ordinal).name
                  for f in enc.binned_fields]
         merged: dict = {}
+        engine = mi.MutualInformation(mesh=mesh)
         if distributed:
             data = self.distributed_stream(data, acc, rows_fn, merged)
             result = self.distributed_fit(
-                lambda d: mi.MutualInformation(mesh=mesh).fit(
-                    d, feature_names=names, accumulator=acc),
+                lambda d: engine.fit(d, feature_names=names,
+                                     accumulator=acc),
                 data, acc, merged)
             if result is None:             # zero-chunk non-writer process
                 counters.set("Records", "Processed", merged["rows"])
                 return
         else:
-            result = mi.MutualInformation(mesh=mesh).fit(
-                data, feature_names=names, accumulator=acc)
+            result = engine.fit(data, feature_names=names, accumulator=acc)
+        # which count route this run took (kernel / sharded / einsum) and
+        # over how many chunks — the fused scan journals the same tag on
+        # its `scan` span; the standalone job had no record of it
+        counters.set("Records", f"CountPath.{engine.count_path}",
+                     engine.chunks_seen)
         lines = mi_output_lines(conf, result, names)
         rows = merged["rows"] if distributed else rows_fn()
         if self.is_output_writer():

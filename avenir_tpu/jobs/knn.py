@@ -177,3 +177,9 @@ class NearestNeighbor(Job):
                 counters.merge(result.counters)
         write_output(output_path, out)
         counters.set("Records", "Processed", test_ds.num_rows)
+        if model.fused_rows:
+            # which search route answered: the fused Pallas kernel, and
+            # the rows its exactness certificate sent to the XLA scan
+            counters.set("Records", "Search.fused", model.fused_rows)
+            counters.set("Records", "Search.certFallback",
+                         model.cert_fallback_rows)

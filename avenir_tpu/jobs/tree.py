@@ -267,6 +267,8 @@ class DecisionTreeBuilder(Job):
                          int(st["select_ms"] * 1e3))
             counters.set("TreePhase", f"level.{lv}.partition.us",
                          int(st["partition_ms"] * 1e3))
+            # the level contraction's route: cross / packed / einsum
+            counters.set("TreePhase", f"level.{lv}.path.{st['path']}", 1)
         write_output(output_path, [model.to_string(),
                                    json.dumps({"encoder": enc.state_dict()})])
         if conf.get("prediction.mode") == "validation":

@@ -18,6 +18,12 @@ Three modes, one command line (docs/jobs.md "Fleet launcher"):
   router — merges into one ``fleet-<run>.jsonl``
   (docs/deployment.md "Cross-host serving").
 
+On ONE host's TPU chips the spawn and serve modes have not run: every
+worker inherits the parent's environment and would claim every chip (the
+second dies on the TPU library's lock).  The examples are CPU recipes
+(``JAX_PLATFORMS=cpu``); one process driving all of a host's chips is the
+``shard.devices`` path (README "Multi-chip").
+
 Examples::
 
     # 2 workers × 4 virtual CPU devices each, job CLI argv

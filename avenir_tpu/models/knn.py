@@ -63,6 +63,11 @@ class KNNModel:
     class_values: List[str]
     cont_lo: np.ndarray                 # [Fc] train min (normalization)
     cont_hi: np.ndarray                 # [Fc] train max
+    # search-route tally (the NearestNeighbor job prints it): query rows
+    # the fused Pallas search answered, and how many of those failed the
+    # exactness certificate and were recomputed by the exact XLA scan
+    fused_rows: int = 0
+    cert_fallback_rows: int = 0
 
     @property
     def num_refs(self) -> int:
@@ -195,7 +200,7 @@ def _topk_over_tiles(test_codes, test_cont, ref_codes_t, ref_cont_t, n_real,
 
     ``approx=True`` swaps only the per-tile candidate selection for
     ``jax.lax.approx_min_k`` (the TPU PartialReduce unit; measured 0.9988
-    end-to-end recall at 1M refs / k=10, BASELINE.md — on CPU/GPU backends
+    end-to-end recall at 1M refs / k=10, 2026-07 — on CPU/GPU backends
     approx_min_k falls back to exact top-k). The cross-tile merge of the 2k
     running candidates stays exact either way, so recall loss is bounded to
     the within-tile approximation."""
@@ -233,11 +238,8 @@ def _pallas_available(metric: str, k: int) -> bool:
     from avenir_tpu.ops import pallas_knn
     if k + 1 > pallas_knn.SLOTS:
         return False
-    try:
-        # the Mosaic kernel lowers on TPU only — never dispatch it on gpu
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # the Mosaic kernel lowers on TPU only — never dispatch it on gpu
+    return jax.default_backend() == "tpu"
 
 
 def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int
@@ -260,6 +262,8 @@ def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int
     d = np.asarray(d_dev)
     idx = np.asarray(i_dev)
     cert = np.asarray(cert_dev)
+    model.fused_rows += int(cert.size)
+    model.cert_fallback_rows += int(cert.size - cert.sum())
     if not cert.all():
         # np.asarray of a device array is a read-only view; the fallback
         # writes row-wise
@@ -347,7 +351,8 @@ def nearest_neighbors(
 
     ``mode="exact"`` (default): on TPU backends the euclidean metric
     dispatches to the fused Pallas search (segment key-tournament + exact
-    re-rank, ~9× the XLA scan at 1M refs — BASELINE.md); everything else
+    re-rank, ~9× the XLA scan at 1M refs when measured in 2026-07);
+    everything else
     uses the compiled XLA tile scan. ``mode="approx"``: a quality floor,
     not a method — when the fused exact path applies it is BOTH faster and
     exact, so an approx request routes there (≥-quality results, like the

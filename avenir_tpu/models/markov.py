@@ -532,10 +532,7 @@ def viterbi_time_sharded(log_a: jax.Array, log_b: jax.Array, log_pi: jax.Array,
     """
     import functools as _ft
 
-    try:
-        from jax import shard_map
-    except ImportError:                    # pre-move jax (parallel/collectives)
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     s = log_a.shape[0]

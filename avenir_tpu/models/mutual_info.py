@@ -177,6 +177,8 @@ class MutualInformation:
         integer counts make the result bit-identical to single-device."""
         self.pair_chunk = pair_chunk
         self.mesh = mesh
+        self.count_path = None      # routing tag of the last fit()
+        self.chunks_seen = 0        # chunks the last fit() folded
 
     def fit(self, data: Union[EncodedDataset, Iterable[EncodedDataset]],
             feature_names: Optional[Sequence[str]] = None,
@@ -236,7 +238,13 @@ class MutualInformation:
                 accumulator.load(g)
             elif "fc" in accumulator and step is not None:
                 step = None
+        # same tags as SharedScan.count_path (pipeline/scan.py), so the
+        # job's counters say which route the counts took
+        self.count_path = ("einsum" if step is None else
+                           "kernel" if self.mesh is None else "sharded")
+        self.chunks_seen = 0
         for ds in chunks:
+            self.chunks_seen += 1
             from avenir_tpu.parallel.mesh import maybe_shard_batch
             codes, labels = maybe_shard_batch(self.mesh, ds.codes, ds.labels)
             acc.add("class", agg.class_counts(labels, c))

@@ -5,7 +5,7 @@ The count tables of the flagship pipeline (the rebuild of the reference's
 ``bayesian/BayesianDistribution.java:203-328`` shuffle) were previously
 one-hot einsums that XLA lowers to scatter-adds — measured wall of
 ~7 G updates/s (66 updates/row on the hosp_readmit shape, <1% of any
-hardware peak; BASELINE.md round-2 perf notes).  This kernel replaces the
+hardware peak; round-2 perf notes, git history).  This kernel replaces the
 scatter lowering entirely:
 
     every NB/MI count table is a sub-block of  G = Xᵀ X,
@@ -54,8 +54,7 @@ xla_gram_probe.py):
   rig drift ±20% on ~30-minute scales (the identical fused config
   re-measured 333M half an hour later; r3's driver artifact captured
   366M for the old kernel) — only same-session A/B deltas are
-  comparable, and BENCH_r04.json records whatever the driver's session
-  captures;
+  comparable;
 - zero-expand floor (dot + streaming only): 37.8 ms/chunk — i.e. the
   expand costs ~4 ms (~10%), NOT the ~60% round 3 estimated;
 - the governing wall is the W=384 int8 gram itself: ~115-125 effective
@@ -86,13 +85,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams → CompilerParams; a module-local alias
-# (no mutation of the shared pltpu module) keeps the kernels running on
-# either side of the rename — pallas_knn and the standalone probes carry
-# the same two-liner
-COMPILER_PARAMS = (pltpu.CompilerParams if hasattr(pltpu, "CompilerParams")
-                   else pltpu.TPUCompilerParams)
 
 # joint-code marker for invalid rows / padding: never equals a selector
 # value (selectors are in [0, B·C) plus the pad marker below)
@@ -450,7 +442,7 @@ def cooc_counts_cols(codes_t: jax.Array, labels: jax.Array, num_bins: int,
                                    lambda r, i: (0, r, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.int32),
-            compiler_params=COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=110 * 1024 * 1024),
             interpret=interpret,
@@ -474,7 +466,7 @@ def cooc_counts_cols(codes_t: jax.Array, labels: jax.Array, num_bins: int,
                                memory_space=pltpu.VMEM)],
         out_specs=out_specs,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.int32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=110 * 1024 * 1024),
         interpret=interpret,
@@ -582,7 +574,7 @@ def cross_cooc_counts_cols(codes_t: jax.Array, sel: jax.Array,
         out_specs=pl.BlockSpec((wp, sp_dim), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((wp, sp_dim), jnp.int32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=110 * 1024 * 1024),
         interpret=interpret,
@@ -785,7 +777,7 @@ def counts_from_cooc(g, num_feat: int, num_bins: int, num_classes: int,
 # ---------------------------------------------------------------------------
 # PackGraft (round 16): block-diagonal gram packing.
 #
-# The efficiency-vs-width curve (BASELINE.md wide-schema tier: ~77% of int8
+# The efficiency-vs-width curve (wide-schema tier as measured in 2026-07: ~77% of int8
 # peak at per-class widths ≥ 2000 vs 18-30% at the flagship W=384) makes
 # joint width the biggest single-chip lever.  A pack descriptor lays several
 # INDEPENDENT narrow tables' one-hot blocks along ONE joint width so all of
@@ -1063,26 +1055,15 @@ def mesh_on_tpu(mesh) -> bool:
     dryrun) run the same step with ``interpret=True`` instead."""
     if mesh is None:
         return False
-    try:
-        devices = list(np.asarray(mesh.devices).flat)
-    except Exception:                                   # pragma: no cover
-        return False
-    return bool(devices) and all(
-        d.platform == "tpu" or "tpu" in (getattr(d, "device_kind", "") or
-                                         "").lower()
-        for d in devices)
+    devices = list(np.asarray(mesh.devices).flat)
+    return bool(devices) and all(d.platform == "tpu" for d in devices)
 
 
 def on_tpu_single_device(*arrays) -> bool:
     """Runtime gate: default backend is a TPU and no operand is sharded
     across devices (the sharded einsum path owns multi-device execution —
     its psum-over-data collective is what the mesh tests attest)."""
-    try:
-        dev = jax.devices()[0]
-    except Exception:                                   # pragma: no cover
-        return False
-    kind = getattr(dev, "device_kind", "") or ""
-    if dev.platform != "tpu" and "tpu" not in kind.lower():
+    if jax.devices()[0].platform != "tpu":
         return False
     for x in arrays:
         sharding = getattr(x, "sharding", None)

@@ -18,27 +18,17 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from avenir_tpu.ops.agg import (_check_chunk, one_hot as _onehot,
                                 pair_class_counts)
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _shard_map_norep(step, mesh, in_specs, out_specs):
-    """shard_map with the replicated-output check disabled — the kwarg was
-    renamed check_rep → check_vma across jax versions, so probe once here
-    instead of copy-pasting the shim at every call site."""
-    try:
-        return _shard_map(step, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover
-        return _shard_map(step, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """shard_map with the replicated-output check disabled."""
+    return _shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
 
 
 def sharded_nb_fit_step(mesh: Mesh, num_classes: int, num_bins: int, num_cont: int):

@@ -201,7 +201,7 @@ class ReinforcementLearnerServer:
     (``serving/batcher.py``): a ``Serving.<model_name>`` counter group plus
     a :class:`LatencyTracker`, published through :meth:`stats` — so the two
     online paths (RL loop, ServeGraft) report through one shape and
-    BASELINE.md's serving rows compare like for like.  The RL loop
+    their serving rows compare like for like.  The RL loop
     dispatches one event at a time, so its whole size histogram lands in
     ``bucket.1``.  Pass shared ``counters``/``latency`` objects to
     aggregate several servers (e.g. a fleet's per-group learners) into one
@@ -433,8 +433,7 @@ class ProcessServingFleet:
     single-threaded), bounded per-worker queues apply ``max.spout.pending``
     backpressure, ``close()`` drains and re-raises the first worker error.
     Because workers are processes, CPU-bound learner updates scale past the
-    GIL on multi-core hosts (thread workers cannot — BASELINE.md serving
-    notes; on the 1-core dev rig both measure flat).
+    GIL on multi-core hosts (thread workers cannot).
 
     Process-boundary additions:
     - action writes are forwarded to the parent (``actions()`` after

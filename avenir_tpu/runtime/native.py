@@ -1,7 +1,7 @@
 """ctypes bridge to the C++ data plane (runtime/native/csv_encode.cpp).
 
-Compiles the shared library on first use (g++, cached next to the source;
-rebuilt when the source is newer) and exposes :func:`encode_bytes` — CSV
+Compiles the shared library on first use (g++, kept next to the source,
+never committed; rebuilt when the source is newer) and exposes :func:`encode_bytes` — CSV
 bytes → :class:`EncodedDataset` with semantics identical to
 ``DatasetEncoder.transform``. All callers must treat this as an optional fast
 path: :func:`is_available` gates it, and ``DatasetEncoder`` stays the
@@ -27,19 +27,16 @@ _build_error: Optional[str] = None
 
 def _lib_path() -> str:
     """Where the compiled library lives: next to the source when that
-    directory is writable (repo checkouts — keeps the prebuilt .so in
-    place), else a per-user cache dir (pip installs into read-only
-    site-packages must not silently lose the native fast path). The cache
-    filename embeds a hash of the source so a package upgrade can never be
-    served a stale-ABI build (mtime comparison is unreliable there —
-    wheel extraction preserves archive timestamps)."""
+    directory is writable (repo checkouts; the .so is git-ignored and
+    always built from the checkout's own csv_encode.cpp on first use),
+    else a per-user cache dir (pip installs into read-only site-packages
+    must not silently lose the native fast path). The cache filename
+    embeds a hash of the source so a package upgrade can never be served
+    a stale-ABI build (mtime comparison is unreliable there — wheel
+    extraction preserves archive timestamps)."""
     pkg_dir = os.path.join(os.path.dirname(__file__), "native")
-    pkg_lib = os.path.join(pkg_dir, "libavenir_native.so")
-    if os.path.exists(pkg_lib) and \
-            os.path.getmtime(pkg_lib) >= os.path.getmtime(_SRC):
-        return pkg_lib                 # shipped/prebuilt and current
     if os.access(pkg_dir, os.W_OK):
-        return pkg_lib
+        return os.path.join(pkg_dir, "libavenir_native.so")
     import hashlib
     with open(_SRC, "rb") as fh:
         tag = hashlib.sha1(fh.read()).hexdigest()[:12]

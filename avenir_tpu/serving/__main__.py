@@ -52,6 +52,11 @@ def main(argv: List[str]) -> int:
     from avenir_tpu.serving.pool import ReplicaPool
     from avenir_tpu.serving.registry import ModelRegistry
 
+    # every (model, bucket) program warm() compiles is found again by the
+    # next start of the same conf
+    from avenir_tpu.utils import compile_cache
+
+    compile_cache.configure()
     conf = JobConfig.from_file(args.conf)
     for item in args.overrides:
         key, eq, value = item.partition("=")

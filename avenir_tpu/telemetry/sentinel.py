@@ -1,9 +1,9 @@
-"""Perf-regression sentinel — the consumer the BENCH_r*.json trajectory
+"""Perf-regression sentinel — the consumer the bench-artifact trajectory
 never had.
 
 Every round publishes bench artifacts, and until now a regression like
 the r05 ``families.tree`` 0.21× row was only caught when a human reread
-BASELINE.md.  This module turns the trajectory into an automated gate:
+the notes.  This module turns the trajectory into an automated gate:
 
     python -m avenir_tpu.telemetry regress BENCH_new.json \
         --baseline BENCH_prev.json [--tolerance-pct 25] \
@@ -16,7 +16,7 @@ artifact within per-metric tolerance bands and exits 0 (pass) / 1
 capture, so every future artifact carries its own verdict and journals a
 ``bench.regression`` event when tracing is on.
 
-Canary conditioning (the BASELINE.md interpretation contract, reused —
+Canary conditioning (utils/rig_canary.py's interpretation contract, reused —
 never reimplemented): a metric whose capture is canary-flagged — its
 ``value_canary_clean`` is null (no rig-clean pass) or its fresh matmul
 canary exceeds the healthy threshold — is **skipped with a verdict**,
@@ -33,7 +33,7 @@ import fnmatch
 import json
 from typing import Dict, List, Optional
 
-# the BASELINE.md interpretation contract: matmul canary ≲ 7 ms reads
+# the rig_canary interpretation contract: matmul canary ≲ 7 ms reads
 # healthy; the contended regime reads 10-100x higher (bench.py uses the
 # same bound for value_canary_clean)
 CANARY_HEALTHY_MS = 7.0

@@ -22,8 +22,7 @@ instead of five unrelated artifacts.  Design constraints:
 - **honest wall times**: JAX dispatch is async, so a span measuring
   device work registers its output via :meth:`Span.block_on` and the
   close performs the host fetch through the existing
-  ``profiling.device_sync`` discipline (``jax.block_until_ready`` is a
-  no-op on some transports — BASELINE.md "Timing methodology").
+  ``profiling.device_sync`` discipline (one host read per shard).
 - **single-writer journal SHARDS** (GraftFleet, round 15): in
   multi-process runs every process journals to its OWN shard
   (``run-<id>.proc-<k>.jsonl``, each single-writer under its own

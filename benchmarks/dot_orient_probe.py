@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; module-local alias,
-# same as ops/pallas_hist.py
-COMPILER_PARAMS = (pltpu.CompilerParams if hasattr(pltpu, "CompilerParams")
-                   else pltpu.TPUCompilerParams)
-
 
 
 def _kernel_a(x_ref, out_ref):          # x block [W, BN]; G += x·xᵀ
@@ -64,7 +59,7 @@ def gram(x, bn, orient):
             out_specs=pl.BlockSpec((w, w), lambda i: (0, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((w, w), jnp.int32),
-            compiler_params=COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=110 * 1024 * 1024),
         )(x)
@@ -76,7 +71,7 @@ def gram(x, bn, orient):
         out_specs=pl.BlockSpec((w, w), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((w, w), jnp.int32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=110 * 1024 * 1024),
     )(x)

@@ -40,11 +40,11 @@ Families and shapes (reference-derived):
                Baseline: the same tokenizer feeding ``collections.Counter``
                — BOTH run on host, so the honest ratio is ~1: this family
                has no device compute and says so instead of implying a
-               TPU win (1-core-rig caveat in BASELINE.md).
+               TPU win.
 
 Baselines are median-of-3 like bench.py's numpy NB+MI baseline, with
 buffers hoisted out of the timed region.  Sync discipline for the device
-side: chain dispatches, fetch once (BASELINE.md "Timing methodology").
+side: chain dispatches, fetch once.
 Run ONE family per process:
 
   python -m benchmarks.family_bench --family viterbi
@@ -122,7 +122,7 @@ def bench_tree(passes: int, n: int = 2_000_000, baseline_sub: int = 100_000,
                 "multi-way/categorical candidate-split search "
                 "(ClassPartitionGenerator.java:280-432) which sklearn "
                 "does not perform; tree_binary is the apples-to-apples "
-                "row — see BASELINE.md family table")
+                "row")
         metric = "tree_induction_rows_per_sec"
     return {"metric": metric, "unit": "rows/sec/chip",
             "n_rows": n, "max_depth": 4, "nodes": len(model.nodes),

@@ -31,11 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; module-local alias,
-# same as ops/pallas_hist.py
-COMPILER_PARAMS = (pltpu.CompilerParams if hasattr(pltpu, "CompilerParams")
-                   else pltpu.TPUCompilerParams)
-
 
 from avenir_tpu.ops import pallas_knn as pk
 
@@ -121,7 +116,7 @@ def run(a_mat, b_mat, variant):
         ],
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((m, nbp), jnp.int32)] * 3,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024),
     )(a_mat, b_mat)

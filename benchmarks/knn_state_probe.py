@@ -18,7 +18,7 @@ Each run prints one JSON line with the matmul canary (rig state), the bare
 distance-dot canary against the actual packed reference buffer (kernel
 lower bound), and the pipelined pass list.  Run interleaved
 (fresh, after_nbmi, fresh, after_nbmi, ...) so the ±20% rig drift
-(BASELINE.md "Timing methodology") averages out of the comparison:
+averages out of the comparison:
 
     for m in fresh after_nbmi fresh after_nbmi; do
         python benchmarks/knn_state_probe.py --mode $m; done

@@ -4,8 +4,8 @@ measured per device count — per-chip + aggregate rows/sec and scaling
 efficiency — with byte-identity to the single-chip fold ASSERTED before
 any rate is recorded (the acceptance oracle rides the artifact).
 
-Runs the nb_mi-shaped fold (NaiveBayes + MutualInfo consumers — the
-BASELINE.md band's workload) over a fixed synthetic chunk stream:
+Runs the nb_mi-shaped fold (NaiveBayes + MutualInfo consumers) over a
+fixed synthetic chunk stream:
 
 - ``single_chip``: today's unsharded path, the byte-identity oracle and
   the band anchor;
@@ -20,13 +20,11 @@ BASELINE.md band's workload) over a fixed synthetic chunk stream:
   partial cells fit int8 — true for the host-mesh chunk slices, not for
   the TPU-size chunks; max bin-count deviation is published either way).
 
-On a host with fewer devices than 8 and no TPU, the harness re-execs
-itself once with ``--xla_force_host_platform_device_count=8`` so the
-scaling SHAPE is exercisable anywhere; host-mesh folds run the Pallas
-interpreter, so those rates measure the harness, not the kernel —
-``interpret_mode: true`` in the artifact flags them.  A fresh matmul
-canary rides each section per the PR-2 convention (a loaded rig indicts
-itself, not the scan).  One JSON object on stdout.
+The harness measures the chips: where JAX finds no TPU it raises (a
+host-mesh fold runs the Pallas interpreter and its rate would measure the
+interpreter, not the kernel).  A fresh matmul canary rides each section
+per the PR-2 convention (a loaded host indicts itself, not the scan).
+One JSON object on stdout.
 
 CrossGraft (``--nprocs N``): the REAL multi-process capture — the
 harness drives itself through the fleet launcher
@@ -37,8 +35,9 @@ dispatch, byte-identity to each worker's local unsharded fold is
 asserted BEFORE any rate is recorded, and the artifact publishes
 aggregate + per-process rates, ``scaling_efficiency`` against the
 1-process local-mesh fold at the same per-process width, and the
-quantized cross-host hop's measured deviation — the first non-stub row
-of BASELINE.md's MULTICHIP table.
+quantized cross-host hop's measured deviation.  The launcher's workers
+inherit the parent's environment, so N workers on ONE host's chips would
+each claim every chip: that layout has not run (README "Multi-chip").
 """
 
 import argparse
@@ -53,31 +52,6 @@ N_FEAT = 8
 N_BINS = 8
 N_CLASSES = 2
 N_CONT = 2
-_FORCED = "AVENIR_MULTICHIP_FORCED"
-
-
-def _maybe_force_host_mesh():
-    """Single-device CPU container → re-exec once with an 8-device host
-    mesh (the tier-1 trick) so the scaling harness has shards to measure;
-    a TPU or pre-forced environment passes straight through."""
-    if os.environ.get(_FORCED):
-        return
-    import jax
-
-    if len(jax.devices()) > 1 or jax.devices()[0].platform != "cpu":
-        return
-    env = dict(os.environ)
-    env[_FORCED] = "1"
-    env["JAX_PLATFORMS"] = "cpu"
-    # the child resolves avenir_tpu the way the parent did: repo root
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    os.execve(sys.executable, [sys.executable, os.path.abspath(__file__)],
-              env)
 
 
 def gen_data(n_rows, seed=29):
@@ -109,12 +83,10 @@ def _multiproc_worker(args):
     from avenir_tpu.utils.metrics import Counters
     from avenir_tpu.utils.rig_canary import matmul_canary_ms
 
+    require_tpu("benchmarks/multichip_scan.py --nprocs")
     nprocs = jax.process_count()
     d_local = len(jax.local_devices())
-    on_tpu = jax.local_devices()[0].platform == "tpu"
-    chunk = 262_144 if on_tpu else 2_048
-    n_chunks = 8 if on_tpu else 3
-    passes = 3 if on_tpu else 2
+    chunk, n_chunks, passes = 262_144, 8, 3
     codes, cont, labels = gen_data(chunk * n_chunks)
     ds = EncodedDataset(
         codes=codes, cont=cont, labels=labels,
@@ -187,7 +159,6 @@ def _multiproc_worker(args):
             "metric": "nb_mi_global_mesh_scan_throughput",
             "mode": "multiprocess",
             "topology": spec.announce(),
-            "interpret_mode": not on_tpu,
             "rows_total": n_rows,
             "chunk_rows": chunk,
             "passes": passes,
@@ -255,8 +226,8 @@ def _launch_multiproc(args):
 
 def main():
     # resolve avenir_tpu from the repo root no matter how the script was
-    # invoked (the re-exec path passes PYTHONPATH; direct --nprocs runs
-    # need it here, and the launcher's workers inherit it)
+    # invoked (direct --nprocs runs need it here, and the launcher's
+    # workers inherit it)
     _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if _root not in sys.path:
         sys.path.insert(0, _root)
@@ -281,7 +252,6 @@ def main():
 
 
 def _single_process_main():
-    _maybe_force_host_mesh()
     import jax
 
     from avenir_tpu.core.config import JobConfig
@@ -290,14 +260,11 @@ def _single_process_main():
     from avenir_tpu.pipeline import scan
     from avenir_tpu.utils.metrics import Counters
     from avenir_tpu.utils.rig_canary import matmul_canary_ms
+    from avenir_tpu.utils.roofline import require_tpu
 
+    require_tpu("benchmarks/multichip_scan.py")
     devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    # interpret-mode folds are ~10⁴× the kernel; size the stream so a CPU
-    # host-mesh run finishes in minutes while a TPU run amortizes dispatch
-    chunk = 262_144 if on_tpu else 2_048
-    n_chunks = 8 if on_tpu else 3
-    passes = 3 if on_tpu else 2
+    chunk, n_chunks, passes = 262_144, 8, 3
     codes, cont, labels = gen_data(chunk * n_chunks)
     ds = EncodedDataset(
         codes=codes, cont=cont, labels=labels,
@@ -392,7 +359,6 @@ def _single_process_main():
         "benchmark": "multichip_scan",
         "metric": "nb_mi_sharded_scan_throughput",
         "topology": qspec.announce(),
-        "interpret_mode": not on_tpu,
         "rows_total": n_rows,
         "chunk_rows": chunk,
         "passes": passes,

@@ -124,8 +124,7 @@ def gil_contention_probe(n_events: int = 3000, burn_loops: int = 60_000):
     top of OS scheduling.  What it CANNOT demonstrate here: the
     multi-core win — with W cores and W process workers the same
     GIL-holding update scales ~W× while thread workers stay at the pure
-    rate; that claim is an EXTRAPOLATION from this measurement, labeled
-    as such in BASELINE.md."""
+    rate; that claim is an EXTRAPOLATION from this measurement."""
     def heavy_server(_group: str) -> st.ReinforcementLearnerServer:
         learner = _HeavyWrap(
             orl.create_learner("intervalEstimator", ACTIONS, CONF, seed=3),

@@ -170,8 +170,8 @@ def test_approx_search_mode_high_recall(rng):
     # NOTE: on this CPU test backend approx_min_k falls back to exact
     # top-k, so this pins the plumbing (mode dispatch, merge, ordering,
     # index/distance consistency), not the approximation itself — the real
-    # recall is measured on TPU by benchmarks/knn_qps.py (BASELINE.md:
-    # 0.9988 at 1M refs, k=10)
+    # recall is measured on TPU by benchmarks/knn_qps.py (0.9988 at
+    # 1M refs, k=10 when measured in 2026-07)
     n, m, k = 20_000, 256, 10
     ds = EncodedDataset(
         codes=rng.integers(0, 8, size=(n, 4)).astype(np.int32),

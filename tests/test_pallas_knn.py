@@ -153,7 +153,8 @@ def test_search_fused_tiny_reference_set(rng):
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_path_matches_oracle(rng):
+def test_search_fused_block2_path_matches_oracle(rng, monkeypatch):
+    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
     # enough reference blocks to engage the block top-2 sweep
     # (2*nblocks >= k+margin) — the production path at scale; verify exact
     # results + certificate against the oracle
@@ -183,7 +184,8 @@ def test_search_fused_block2_path_matches_oracle(rng):
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
+def test_search_fused_block2_short_last_block_not_falsely_certified(rng, monkeypatch):
+    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
     # regression: n_real = 8*TN+1 puts one real ref in the last block, so a
     # pad lands in the candidate pool; that must NOT certify rows (the
     # merge-kernel "pad => all refs seen" invariant does not hold here —
@@ -215,7 +217,8 @@ def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_heavy_ties_and_duplicates(rng):
+def test_search_fused_block2_heavy_ties_and_duplicates(rng, monkeypatch):
+    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
     # adversarial for the block top-2 sweep: many duplicated reference rows
     # (ties across and within blocks) — certified rows must still be exact
     import jax.numpy as jnp
