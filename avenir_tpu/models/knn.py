@@ -67,6 +67,7 @@ class KNNModel:
     # the fused Pallas search answered, and how many of those failed the
     # exactness certificate and were recomputed by the exact XLA scan
     fused_rows: int = 0
+    tourney_rows: int = 0               # of fused_rows: tournament kernel
     cert_fallback_rows: int = 0
 
     @property
@@ -263,6 +264,8 @@ def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int
     idx = np.asarray(i_dev)
     cert = np.asarray(cert_dev)
     model.fused_rows += int(cert.size)
+    if pallas_knn.tourney_engages(n, r_mat.shape[0], k):
+        model.tourney_rows += int(cert.size)
     model.cert_fallback_rows += int(cert.size - cert.sum())
     if not cert.all():
         # np.asarray of a device array is a read-only view; the fallback

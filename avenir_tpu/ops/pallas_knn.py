@@ -277,18 +277,6 @@ def topk_candidates(q_mat, r_mat, k: int, margin: int = MARGIN
 
 TB = 16384             # reference rows per grid step (one DMA, 8 segments)
 SEG = 2048             # certificate granularity: top-2 + third-min bound
-# Off since PR 23: on today's TPU v5e (jax 0.9.0 / libtpu 0.0.34) the fused
-# search certified rows that were NOT exact when the tournament generated the
-# candidates — 13 of 4096 elearn-shaped queries over 131072 refs, each
-# missing a true neighbour that was the third of its segment.  Run as its
-# own program the kernel's outputs are right; inside the fused program some
-# segments' lanes are lost (third-min bound 0.0494 instead of 0.0440), so
-# the certificate passes; interpret mode is exact; the merge kernel certified
-# 0 wrong rows on the same data (chip runs, PR 23; PERF.md Findings).  An exact
-# search that is sometimes wrong is worse than a slower one, so search_fused
-# takes the merge kernel until that is understood.  Tests pin the
-# tournament path by setting this True.
-TOURNAMENT = False
 # pad-lane key: the int32 bit pattern of _BIG (finite; NEVER 0x7fffffff,
 # whose truncated bitcast is NaN and would poison every downstream min)
 _PAD_KEY = int(np.float32(_BIG).view(np.int32))
@@ -415,12 +403,19 @@ def _topk_tourney_traced(a_mat, b_mat, k: int):
 # the round-trip latency amortizes away.
 
 def _limbs_dev(v: jax.Array, n: int = 3):
-    """Device-side bf16 limb split (matches :func:`_limbs`: astype(bf16)
-    rounds to nearest-even exactly like _bf16_round)."""
+    """Device-side bf16 limb split (matches :func:`_limbs`: rounds to
+    nearest-even exactly like _bf16_round).
+
+    The rounding is ``lax.reduce_precision``, never
+    ``astype(bf16).astype(f32)``: under jit the TPU compiler may drop that
+    round trip as excess precision, the remainder is then 0 and the low
+    limbs vanish — d² came back with ~2⁻⁸ relative error instead of 2⁻²⁶
+    and the certificate passed rows that were not exact (chip run, PR 23).
+    """
     out = []
     rem = v.astype(jnp.float32)
     for _ in range(n):
-        hi = rem.astype(jnp.bfloat16).astype(jnp.float32)
+        hi = jax.lax.reduce_precision(rem, exponent_bits=8, mantissa_bits=7)
         out.append(hi)
         rem = rem - hi
     return out
@@ -538,6 +533,16 @@ def _topk_pallas_traced(a_mat, b_mat, k: int):
     return -neg, jnp.take_along_axis(best_i[:, :k], pos, axis=1)
 
 
+def tourney_engages(n_real: int, n_packed: int, k: int,
+                    margin: int = MARGIN) -> bool:
+    """Which candidate kernel :func:`search_fused` takes: the tournament
+    only when enough REAL segments exist to fill the candidate pool —
+    pad-dominated segments would produce a uselessly small bound and fail
+    every certificate — and the operand is TB-aligned (prepare_refs)."""
+    kk = min(k + margin, SLOTS)
+    return 2 * -(-n_real // SEG) >= kk and n_packed % TB == 0
+
+
 def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
                  codes_r_dev: jax.Array, cont01_r_dev: jax.Array, n_real: int,
                  num_bins: int, k: int, total_attrs: int,
@@ -549,16 +554,12 @@ def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
     kk = min(k + margin, SLOTS)
     eps = D2_EPS if fc else 0.0
     rows = _round_up(max(m, TM), TM)
-    # tournament engages only when enough REAL segments exist to fill the
-    # candidate pool — pad-dominated segments would produce a uselessly
-    # small bound and fail every certificate
-    use_tourney = (TOURNAMENT and 2 * -(-n_real // SEG) >= kk
-                   and r_mat.shape[0] % TB == 0)
     return _search_fused(
         jnp.asarray(codes_q), jnp.asarray(cont01_q, jnp.float32), r_mat,
         codes_r_dev, cont01_r_dev, n_real,
         num_bins=num_bins, rows=rows, extra_norm=float(f), k=k, kk=kk,
-        total_attrs=total_attrs, eps=eps, use_tourney=use_tourney)
+        total_attrs=total_attrs, eps=eps,
+        use_tourney=tourney_engages(n_real, r_mat.shape[0], k, margin))
 
 
 def exact_rerank(cand_idx: np.ndarray, cand_d2: np.ndarray,

@@ -82,6 +82,7 @@ def _multiproc_worker(args):
     from avenir_tpu.pipeline import scan
     from avenir_tpu.utils.metrics import Counters
     from avenir_tpu.utils.rig_canary import matmul_canary_ms
+    from avenir_tpu.utils.roofline import require_tpu
 
     require_tpu("benchmarks/multichip_scan.py --nprocs")
     nprocs = jax.process_count()

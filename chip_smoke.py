@@ -28,6 +28,7 @@ The last line of stdout is exactly
 
 import argparse
 import concurrent.futures
+import functools
 import glob
 import json
 import os
@@ -52,7 +53,6 @@ HOLDOUT_ROWS = 20_000
 TREE_ROWS = 1 << 18
 KNN_REFS = 1 << 17                  # > pallas_knn.TB: the tournament engages
 KNN_QUERIES = 4096
-KNN_SAMPLE = 256                    # queries checked against brute force
 KNN_K = 10
 SERVE_BUCKETS = "1,16"
 DEADLINE_S = 1150.0
@@ -599,22 +599,40 @@ def phase_tree():
     say("tree root splits on campaignType, as the generator plants it")
 
 
+@functools.lru_cache(maxsize=None)
 def elearn_reference():
+    """Plain numpy brute force for EVERY query: the KNN_K + 6 nearest
+    references (min-max normalised Euclidean, as the schema has only
+    numeric features), rows ordered by (distance, reference row)."""
     def load(path):
         return np.loadtxt(os.path.join(WORK, path), dtype="S16",
                           delimiter=",")
     refs, queries = load("elearn_refs.csv"), load("elearn_queries.csv")
     x = refs[:, 1:10].astype(np.float64)
-    q = queries[:KNN_SAMPLE, 1:10].astype(np.float64)
+    q = queries[:, 1:10].astype(np.float64)
     lo, hi = x.min(0), x.max(0)
     span = np.maximum(hi - lo, 1e-9)
     x01 = np.clip((x - lo) / span, 0, 1)
     q01 = np.clip((q - lo) / span, 0, 1)
-    d2 = ((q01 ** 2).sum(1)[:, None] + (x01 ** 2).sum(1)[None, :]
-          - 2.0 * q01 @ x01.T)
-    dist = np.sqrt(np.maximum(d2, 0.0) / x.shape[1])
-    return ([r.decode() for r in refs[:, 0]],
-            [r.decode() for r in queries[:KNN_SAMPLE, 0]], dist)
+    xn = (x01 ** 2).sum(1)
+    keep = KNN_K + 6
+    top = np.empty((len(q01), keep), np.int64)
+    for s in range(0, len(q01), 256):
+        qq = q01[s:s + 256]
+        d2 = (qq ** 2).sum(1)[:, None] + xn[None, :] - 2.0 * qq @ x01.T
+        cand = np.sort(np.argpartition(d2, keep + 8, axis=1)[:, :keep + 8],
+                       axis=1)
+        order = np.argsort(np.take_along_axis(d2, cand, axis=1), axis=1,
+                           kind="stable")[:, :keep]
+        top[s:s + 256] = np.take_along_axis(cand, order, axis=1)
+
+    def dist(rows):                       # [Q, n] ref rows -> distances
+        d2 = ((q01[:, None, :] - x01[rows]) ** 2).sum(-1)
+        return np.sqrt(d2 / x.shape[1])
+    return {"ref_ids": [r.decode() for r in refs[:, 0]],
+            "query_ids": [r.decode() for r in queries[:, 0]],
+            "ref_class": np.array([r.decode() for r in refs[:, 10]]),
+            "top": top, "top_dist": dist(top), "dist": dist}
 
 
 KNN_PROPS = {"feature.schema.file.path": "elearn.json",
@@ -629,49 +647,93 @@ def phase_knn(dev):
                              "elearn_queries.csv", "knn_pred")
     new = sorted(n.split("-")[0] for n in cache_entries(cache) - before)
     fused = counters["Records"].get("Search.fused", 0)
+    tourney = counters["Records"].get("Search.tournament", 0)
     fallback = counters["Records"].get("Search.certFallback", 0)
     say(f"NearestNeighbor {wall:.1f}s: {KNN_REFS} refs x {KNN_QUERIES} "
         f"queries, k={KNN_K}; {fused} rows answered by the fused Pallas "
-        f"search, {fallback} of them failed its exactness certificate and "
-        f"were recomputed by the exact XLA scan; cache +{new}")
-    if fused != KNN_QUERIES or fallback > KNN_QUERIES // 2:
+        f"search ({tourney} with the tournament candidate kernel), "
+        f"{fallback} of them failed its exactness certificate and were "
+        f"recomputed by the exact XLA scan; cache +{new}")
+    if fused != KNN_QUERIES or tourney != KNN_QUERIES or \
+            fallback > KNN_QUERIES // 2:
         raise RuntimeError(f"kNN: fused search answered {fused} of "
-                           f"{KNN_QUERIES} rows and certified only "
+                           f"{KNN_QUERIES} rows ({tourney} through the "
+                           f"tournament) and certified only "
                            f"{fused - fallback}")
-    if len(part("knn_pred")) != KNN_QUERIES:
+    check_knn_votes()
+
+
+def check_knn_votes():
+    """NearestNeighbor's part file against the brute force: the majority
+    class of the KNN_K nearest (first class of the schema on a tied vote),
+    for every query whose K-th and (K+1)-th distances differ."""
+    t0 = time.monotonic()
+    ref = elearn_reference()
+    with open(os.path.join(WORK, "elearn.json")) as fh:
+        classes = [f for f in json.load(fh)["fields"]
+                   if "cardinality" in f][-1]["cardinality"]
+    votes = np.stack([(ref["ref_class"][ref["top"][:, :KNN_K]] == c).sum(1)
+                      for c in classes], axis=1)
+    want = np.array(classes)[np.argmax(votes, axis=1)]
+    decided = ref["top_dist"][:, KNN_K - 1] < ref["top_dist"][:, KNN_K] - 1e-9
+    rows = part("knn_pred")
+    if len(rows) != KNN_QUERIES:
         raise RuntimeError("NearestNeighbor row count")
+    got = np.array([r.rsplit(",", 1)[1] for r in rows])
+    ids = [r.split(",", 1)[0] for r in rows]
+    if ids != ref["query_ids"]:
+        raise RuntimeError("NearestNeighbor part file: query ids/order")
+    bad = np.flatnonzero(decided & (got != want))
+    if len(bad):
+        raise RuntimeError(
+            f"NearestNeighbor: {len(bad)} of {KNN_QUERIES} predictions "
+            f"differ from the brute-force vote, first {ids[bad[0]]}: "
+            f"got {got[bad[0]]}, votes {dict(zip(classes, votes[bad[0]]))}")
+    say(f"NearestNeighbor part file: all {int(decided.sum())} predictions "
+        f"whose {KNN_K}-th neighbour is not tied equal the numpy brute-"
+        f"force vote over {KNN_REFS} refs ({KNN_QUERIES - int(decided.sum())}"
+        f" tied rows not compared; reference {time.monotonic() - t0:.1f}s)")
 
 
 def phase_knn_neighbours():
-    with open(os.path.join(WORK, "elearn_queries.csv")) as fh:
-        sample = fh.readlines()[:KNN_SAMPLE]
-    with open(os.path.join(WORK, "elearn_sample.csv"), "w") as fh:
-        fh.writelines(sample)
+    """EVERY query's neighbour set against the brute force: a row the
+    fused search certified exact and got wrong fails the run here."""
     _c, wall = run_job("knn.pairs", "SameTypeSimilarity",
                        dict(KNN_PROPS, **{"distance.scale": 1000000}),
-                       "elearn_sample.csv", "knn_pairs")
-    ref_ids, query_ids, dist = elearn_reference()
-    row_of = {r: i for i, r in enumerate(ref_ids)}
-    got = {}
+                       "elearn_queries.csv", "knn_pairs")
+    ref = elearn_reference()
+    row_of = {r: i for i, r in enumerate(ref["ref_ids"])}
+    at = {q: i for i, q in enumerate(ref["query_ids"])}
+    got = np.full((KNN_QUERIES, KNN_K), -1, np.int64)
+    scaled = np.zeros((KNN_QUERIES, KNN_K))
+    fill = np.zeros(KNN_QUERIES, np.int64)
     for line in part("knn_pairs"):
-        qid, rid, scaled = line.split(",")
-        got.setdefault(qid, []).append((row_of[rid], int(scaled)))
-    same_ids = 0
-    for qi, qid in enumerate(query_ids):
-        order = np.argsort(dist[qi], kind="stable")[:KNN_K]
-        want = dist[qi][order]
-        mine = sorted(dist[qi][r] for r, _ in got[qid])
-        if len(mine) != KNN_K or np.abs(np.array(mine) - want).max() > 1e-6:
-            raise RuntimeError(
-                f"query {qid}: neighbours {got[qid]} are not the brute-"
-                f"force nearest {list(zip(order, want))}")
-        if any(abs(s - dist[qi][r] * 1e6) > 2 for r, s in got[qid]):
-            raise RuntimeError(f"query {qid}: reported distances differ")
-        same_ids += set(r for r, _ in got[qid]) == set(order.tolist())
-    say(f"SameTypeSimilarity {wall:.1f}s: {KNN_SAMPLE} sampled queries, "
-        f"every neighbour set equals the numpy brute force over "
-        f"{KNN_REFS} refs by distance ({same_ids} also id for id; the "
-        f"rest differ only among exact ties)")
+        qid, rid, sc = line.split(",")
+        qi = at[qid]
+        got[qi, fill[qi]], scaled[qi, fill[qi]] = row_of[rid], int(sc)
+        fill[qi] += 1
+    if (fill != KNN_K).any():
+        raise RuntimeError(f"SameTypeSimilarity: {int((fill != KNN_K).sum())}"
+                           f" queries without exactly {KNN_K} neighbours")
+    mine = ref["dist"](got)
+    want = np.sort(ref["top_dist"][:, :KNN_K], axis=1)
+    wrong = np.flatnonzero(
+        np.abs(np.sort(mine, axis=1) - want).max(1) > 1e-6)
+    if len(wrong):
+        qi = wrong[0]
+        raise RuntimeError(
+            f"{len(wrong)} of {KNN_QUERIES} queries: neighbours are not the "
+            f"brute-force nearest; first {ref['query_ids'][qi]}: got "
+            f"{sorted(zip(mine[qi], got[qi]))} want "
+            f"{list(zip(want[qi], ref['top'][qi, :KNN_K]))}")
+    if (np.abs(scaled - mine * 1e6) > 2).any():
+        raise RuntimeError("SameTypeSimilarity: reported distances differ")
+    same_ids = int((np.sort(got, axis=1)
+                    == np.sort(ref["top"][:, :KNN_K], axis=1)).all(1).sum())
+    say(f"SameTypeSimilarity {wall:.1f}s: all {KNN_QUERIES} queries, every "
+        f"neighbour set equals the numpy brute force over {KNN_REFS} refs "
+        f"by distance ({same_ids} also id for id; the rest differ only "
+        f"among exact ties)")
 
 
 def phase_elearn_nb():
@@ -787,6 +849,28 @@ def phase_serving(dev):
 # four chips: the sharded fused pipeline against the unsharded one
 # ---------------------------------------------------------------------------
 
+SHARD_STEP = r"""
+import json
+from avenir_tpu.core.config import JobConfig
+from avenir_tpu.jobs.base import Job
+from avenir_tpu.parallel.shard import ShardSpec
+from avenir_tpu.pipeline import scan
+conf = JobConfig({"feature.schema.file.path": "hosp.json",
+                  "shard.devices": "4"})
+spec = ShardSpec.from_conf(conf)
+_enc, ds, _rows = Job.encode_input(conf, "hosp_holdout.csv", need_rows=False)
+ds = ds.slice(0, 16384)
+folder = scan.ChunkFolder([scan.NaiveBayesConsumer(name="nb"),
+                           scan.MutualInfoConsumer(name="mi")], ds,
+                          shard=spec)
+text = folder._shard_step.lower(
+    *spec.shard_batch(ds.codes, ds.labels, ds.cont)).compile().as_text()
+print(json.dumps({"path": folder.step, "mesh": dict(spec.mesh.shape),
+                  "tpu_custom_call": text.count("tpu_custom_call"),
+                  "all_reduce": text.count("all-reduce")}))
+"""
+
+
 def phase_four_chips(dev, ref):
     # unsharded = ONE chip of the four takes the kernel path (the auto
     # data-parallel mesh is off, or it would shard this run too)
@@ -817,10 +901,20 @@ def phase_four_chips(dev, ref):
     if len(topo) != 1 or topo[0]["devices"] != 4 or \
             topo[0]["device_kind"] != dev["kind"]:
         raise RuntimeError(f"shard.topology events: {topo}")
-    # a TPU mesh compiles the kernel (interpret=False is mesh_on_tpu of
-    # exactly these devices); the interpreter would be ~1e4x slower
     say(f"shard.topology: {topo[0]['devices']} x {topo[0]['device_kind']} "
-        f"mesh {topo[0]['mesh']} -> kernel compiled, not interpreted")
+        f"mesh {topo[0]['mesh']}")
+    # interpret mode gives the same bytes, so only the compiled text can
+    # tell: the step pipeline/scan.py builds for THIS mesh must hold the
+    # Mosaic kernel and the all-reduce
+    out, wall = run_child("shard.step", ["-c", SHARD_STEP])
+    step = json.loads(out.strip().splitlines()[-1])
+    say(f"sharded step as ChunkFolder builds it for {step['mesh']} "
+        f"({wall:.1f}s): compiled text holds {step['tpu_custom_call']} "
+        f"tpu_custom_call and {step['all_reduce']} all-reduce")
+    if step["path"] != "shard" or step["tpu_custom_call"] < 1 or \
+            step["all_reduce"] < 1:
+        raise RuntimeError(f"sharded step is not the compiled kernel + "
+                           f"collective: {step}")
     if shard.get("chunks") != HOSP_ROWS // CHUNK_ROWS or \
             not shard.get("collective.bytes", 0) > 0:
         raise RuntimeError(f"Shard counters {shard}")

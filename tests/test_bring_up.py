@@ -67,17 +67,22 @@ def test_chip_peaks_raises_on_unknown_tpu_kind(monkeypatch):
     assert roofline.chip_peaks()["bf16_flops"] == 0.0
 
 
-def test_measurement_entry_points_refuse_the_cpu():
+def test_measurement_entry_points_refuse_the_cpu(monkeypatch):
     """bench.py and benchmarks/multichip_scan.py print device metrics:
     their main() raises where JAX finds no TPU (the import stays free —
-    tests/test_benchmarks_import.py imports every bench module)."""
+    tests/test_benchmarks_import.py imports every bench module).  The
+    ``--nprocs`` worker refuses too, once it has joined its fleet."""
     import bench
+    from avenir_tpu import launch
     from benchmarks import multichip_scan
 
     with pytest.raises(RuntimeError, match="measures the TPU"):
         bench.main()
     with pytest.raises(RuntimeError, match="measures the TPU"):
         multichip_scan._single_process_main()
+    monkeypatch.setattr(launch, "join_from_env", lambda: 0)
+    with pytest.raises(RuntimeError, match="measures the TPU"):
+        multichip_scan._multiproc_worker(types.SimpleNamespace(out=None))
 
 
 def test_chip_smoke_parent_imports_no_jax():

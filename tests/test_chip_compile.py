@@ -116,7 +116,11 @@ def test_knn_fused_search_compiles_for_v5e(one_chip, use_tourney):
         _shape((), jnp.int32, one_chip),
         num_bins=1, rows=m, extra_norm=0.0, k=k, kk=k + pk.MARGIN,
         total_attrs=fc, eps=pk.D2_EPS, use_tourney=use_tourney).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the query pack's bf16 limb split must reach the chip as roundings the
+    # compiler cannot drop: 3 limbs each of the coordinates and the norm
+    assert text.count("reduce-precision(") >= 6
 
 
 def test_sharded_scan_step_compiles_for_four_v5e_chips(topo):

@@ -81,6 +81,23 @@ def test_tiny_reference_set_pads_masked(rng):
     assert (i == oi).all()
 
 
+def test_device_limb_split_rounds_with_reduce_precision(rng):
+    """The device-side pack splits with ``lax.reduce_precision``: under jit
+    the TPU compiler may drop an ``astype(bf16).astype(f32)`` round trip as
+    excess precision — the low limbs then come back 0, d² is off by ~2⁻⁸
+    and wrong rows were certified on the chip (PR 23).  XLA on the CPU keeps
+    either spelling, so pin the op and its bit-equality with the host
+    split."""
+    import jax
+    import jax.numpy as jnp
+
+    v = (rng.random(size=(257, 9)) * 3).astype(np.float32)
+    text = str(jax.make_jaxpr(pk._limbs_dev)(jnp.asarray(v)))
+    assert text.count("reduce_precision") == 3 and "bf16" not in text
+    for host, dev in zip(pk._limbs(v), jax.jit(pk._limbs_dev)(jnp.asarray(v))):
+        np.testing.assert_array_equal(host, np.asarray(dev))
+
+
 def test_certificate_flags_close_calls():
     # rows where the k-th and (k'+1)-th distances collide within the error
     # bound must not be certified exact
@@ -153,8 +170,7 @@ def test_search_fused_tiny_reference_set(rng):
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_path_matches_oracle(rng, monkeypatch):
-    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
+def test_search_fused_block2_path_matches_oracle(rng):
     # enough reference blocks to engage the block top-2 sweep
     # (2*nblocks >= k+margin) — the production path at scale; verify exact
     # results + certificate against the oracle
@@ -184,8 +200,7 @@ def test_search_fused_block2_path_matches_oracle(rng, monkeypatch):
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_short_last_block_not_falsely_certified(rng, monkeypatch):
-    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
+def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
     # regression: n_real = 8*TN+1 puts one real ref in the last block, so a
     # pad lands in the candidate pool; that must NOT certify rows (the
     # merge-kernel "pad => all refs seen" invariant does not hold here —
@@ -217,8 +232,7 @@ def test_search_fused_block2_short_last_block_not_falsely_certified(rng, monkeyp
 
 
 @needs_tpu_interpret
-def test_search_fused_block2_heavy_ties_and_duplicates(rng, monkeypatch):
-    monkeypatch.setattr(pk, "TOURNAMENT", True)   # off by default (PR 23)
+def test_search_fused_block2_heavy_ties_and_duplicates(rng):
     # adversarial for the block top-2 sweep: many duplicated reference rows
     # (ties across and within blocks) — certified rows must still be exact
     import jax.numpy as jnp

@@ -275,8 +275,11 @@ def test_wedged_dispatcher_detected_by_heartbeat_deadline(traced):
     exits WITHOUT finishing pending work): the pool's deadline detection
     reaps the stranded queue, requests fail over, every submission still
     completes, and the journal explains the loss."""
+    # the deadline must outlast a HEALTHY dispatcher's worst scheduling
+    # delay: with 150 ms, six xdist workers beside the chip-compile cases
+    # (PR 23) starved the survivor past it and the pool shed at the door
     pool = echo_pool({"pool.replicas": "2",
-                      "pool.heartbeat.ms": "150",
+                      "pool.heartbeat.ms": "600",
                       "pool.monitor.interval.ms": "40",
                       "fault.serve.heartbeat.crash.after": "3"})
     try:
