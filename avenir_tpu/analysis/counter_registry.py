@@ -39,11 +39,26 @@ SPAN_SITES = {
     'chunk': 'docs/observability.md',
     'feeder.stage': 'docs/observability.md',
     'job.*': 'docs/observability.md',
+    'knn.classify': 'docs/observability.md',
+    'knn.fallback': 'docs/observability.md',
+    'knn.readback': 'docs/observability.md',
+    'knn.search': 'docs/observability.md',
+    'knn.stage': 'docs/observability.md',
+    'knn.vote': 'docs/observability.md',
+    'knn.weights': 'docs/observability.md',
     'pipeline.run': 'docs/observability.md',
     'probe': 'docs/jobs.md',
     'scan': 'docs/observability.md',
     'scan.chunk': 'docs/observability.md',
     'scan.fused': 'docs/observability.md',
+    'servable.encode': 'docs/observability.md',
+    'servable.format': 'docs/observability.md',
+    'servable.parse': 'docs/observability.md',
+    'servable.score': 'docs/observability.md',
+    'serve.dispatch': 'docs/architecture.md',
+    'serve.queue': 'docs/observability.md',
+    'serve.reply': 'docs/observability.md',
     'serve.request': 'docs/architecture.md',
+    'serve.slot': 'docs/observability.md',
     'stage.*': 'docs/observability.md',
 }

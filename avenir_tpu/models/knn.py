@@ -41,6 +41,7 @@ import numpy as np
 
 from avenir_tpu.core.encoding import EncodedDataset
 from avenir_tpu.ops import agg
+from avenir_tpu.telemetry import spans as tel
 from avenir_tpu.utils.metrics import ConfusionMatrix, CostBasedArbitrator, Counters
 
 KERNELS = ("none", "linearMultiplicative", "linearAdditive", "gaussian")
@@ -243,7 +244,8 @@ def _pallas_available(metric: str, k: int) -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int
+def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int,
+                              span=tel.NOOP_SPAN
                               ) -> Tuple[np.ndarray, np.ndarray]:
     """Fused-kernel path: ONE jitted dispatch runs query pack → pallas
     candidate kernel → exact f32 re-rank + per-row exactness certificate
@@ -251,39 +253,56 @@ def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int
     query transfer and the tiny [M,k] result read-back — the single-core
     numpy pack/re-rank and the extra device round-trip the previous
     host-side path paid (~115 ms + ~100 ms per 4096-query batch on the dev
-    rig) are gone."""
+    rig) are gone.  ``span`` is the caller's ``knn.search`` span: it gets
+    ``kernel_rows`` and ``refused``."""
     from avenir_tpu.ops import pallas_knn
+    tracer = tel.tracer()
     nb = int(model.n_bins.max()) if model.n_bins.size else 1
     r_mat, n = model.device_packed(nb)
     codes_r_dev, cont01_r_dev = model.device_rerank_arrays()
-    cont01_q = _normalize01(test.cont, model.cont_lo, model.cont_hi)
-    d_dev, i_dev, cert_dev = pallas_knn.search_fused(
-        test.codes, cont01_q, r_mat, codes_r_dev, cont01_r_dev, n, nb, k,
-        test.codes.shape[1] + test.cont.shape[1])
-    d = np.asarray(d_dev)
-    idx = np.asarray(i_dev)
-    cert = np.asarray(cert_dev)
+    with tracer.span("knn.stage"):
+        # normalise, upload the queries, enqueue the program
+        cont01_q = _normalize01(test.cont, model.cont_lo, model.cont_hi)
+        d_dev, i_dev, cert_dev = pallas_knn.search_fused(
+            test.codes, cont01_q, r_mat, codes_r_dev, cont01_r_dev, n, nb, k,
+            test.codes.shape[1] + test.cont.shape[1])
+    with tracer.span("knn.readback"):
+        d = np.asarray(d_dev)
+        idx = np.asarray(i_dev)
+        cert = np.asarray(cert_dev)
     model.fused_rows += int(cert.size)
     if pallas_knn.tourney_engages(n, r_mat.shape[0], k):
         model.tourney_rows += int(cert.size)
-    model.cert_fallback_rows += int(cert.size - cert.sum())
-    if not cert.all():
-        # np.asarray of a device array is a read-only view; the fallback
-        # writes row-wise
-        d, idx = d.copy(), idx.copy()
-        # certificate failed for some rows (approx candidate set might miss a
-        # true neighbor): recompute those rows with the exact XLA scan
-        rows = np.flatnonzero(~cert)
-        sub = EncodedDataset(
-            codes=test.codes[rows], cont=test.cont[rows],
-            labels=None if test.labels is None else test.labels[rows],
-            ids=None, n_bins=test.n_bins, class_values=test.class_values,
-            binned_ordinals=test.binned_ordinals,
-            cont_ordinals=test.cont_ordinals)
-        d_sub, i_sub = _nearest_neighbors_xla(model, sub, k, "euclidean",
-                                              65536, 8192)
-        d[rows] = d_sub
-        idx[rows] = i_sub
+    refused = int(cert.size - cert.sum())
+    model.cert_fallback_rows += refused
+    # the kernel sweeps whole TM-row query tiles, whatever it was handed
+    # (search_fused's own rounding)
+    span.set("kernel_rows", pallas_knn._round_up(
+        max(test.num_rows, pallas_knn.TM), pallas_knn.TM))
+    span.set("refused", refused)
+    if refused:
+        # the exact scan compiles one program per count of refused rows
+        seen = model.__dict__.setdefault("_fallback_counts", set())
+        with tracer.span("knn.fallback", {"rows": refused,
+                                          "new_program": refused not in seen}):
+            seen.add(refused)
+            # np.asarray of a device array is a read-only view; the fallback
+            # writes row-wise
+            d, idx = d.copy(), idx.copy()
+            # certificate failed for some rows (approx candidate set might
+            # miss a true neighbor): recompute those rows with the exact
+            # XLA scan
+            rows = np.flatnonzero(~cert)
+            sub = EncodedDataset(
+                codes=test.codes[rows], cont=test.cont[rows],
+                labels=None if test.labels is None else test.labels[rows],
+                ids=None, n_bins=test.n_bins, class_values=test.class_values,
+                binned_ordinals=test.binned_ordinals,
+                cont_ordinals=test.cont_ordinals)
+            d_sub, i_sub = _nearest_neighbors_xla(model, sub, k, "euclidean",
+                                                  65536, 8192)
+            d[rows] = d_sub
+            idx[rows] = i_sub
     return d, idx
 
 
@@ -366,20 +385,24 @@ def nearest_neighbors(
     reference has no analog for, OFF unless asked for."""
     if mode not in ("exact", "approx"):
         raise ValueError(f"unknown search mode {mode!r}; use exact|approx")
-    if mesh is not None and mesh.shape.get("data", 1) > 1:
-        # the sharded-reference path is exact AND parallel, so it serves
-        # both modes (an approx request gets ≥-quality results); the
-        # all_gather merge needs k candidates per device shard
-        if min(k, model.num_refs) <= _shard_rows(model.num_refs,
-                                                 mesh.shape["data"]):
-            return _nearest_neighbors_sharded(model, test, k, metric, mesh,
-                                              test_tile, ref_tile)
-    if _pallas_available(metric, k) and min(k, model.num_refs) == k:
-        return _nearest_neighbors_pallas(model, test, k)
-    if mode == "approx":
+    rows = test.num_rows
+    with tel.tracer().span("knn.search", {"rows": rows, "kernel_rows": rows,
+                                          "refused": 0}) as span:
+        if mesh is not None and mesh.shape.get("data", 1) > 1:
+            # the sharded-reference path is exact AND parallel, so it serves
+            # both modes (an approx request gets ≥-quality results); the
+            # all_gather merge needs k candidates per device shard
+            if min(k, model.num_refs) <= _shard_rows(model.num_refs,
+                                                     mesh.shape["data"]):
+                span.set("path", "sharded")
+                return _nearest_neighbors_sharded(model, test, k, metric,
+                                                  mesh, test_tile, ref_tile)
+        if _pallas_available(metric, k) and min(k, model.num_refs) == k:
+            span.set("path", "fused")
+            return _nearest_neighbors_pallas(model, test, k, span)
+        span.set("path", "xla")
         return _nearest_neighbors_xla(model, test, k, metric, ref_tile,
-                                      test_tile, approx=True)
-    return _nearest_neighbors_xla(model, test, k, metric, ref_tile, test_tile)
+                                      test_tile, approx=mode == "approx")
 
 
 def _nearest_neighbors_xla(
@@ -485,34 +508,42 @@ class KNN:
                 validate: bool = False) -> KNNResult:
         if model.labels is None:
             raise ValueError("classification requires labels in the reference set")
+        with tel.tracer().span("knn.classify"):
+            return self._classify(model, test, validate)
+
+    def _classify(self, model: KNNModel, test: EncodedDataset,
+                  validate: bool) -> KNNResult:
+        tracer = tel.tracer()
         dists, idx = nearest_neighbors(model, test, self.k, self.metric,
                                        self.ref_tile, self.test_tile,
                                        mode=self.search_mode, mesh=self.mesh)
-        w = kernel_weights(dists, self.kernel, self.kernel_sigma, self.inverse_distance)
-        neigh_labels = model.labels[idx]                        # [M, k]
-        c = len(model.class_values)
-        if self.class_cond_weighting:
-            if model.class_probs is None:
-                raise ValueError("class_cond_weighting requires class_probs in the model")
-            post = np.take_along_axis(model.class_probs[idx], neigh_labels[..., None],
-                                      axis=2)[..., 0]           # [M, k]
-            w = w * post
-        scores = np.zeros((dists.shape[0], c), np.float32)
-        for cls in range(c):
-            scores[:, cls] = (w * (neigh_labels == cls)).sum(axis=1)
-        shares = scores / np.maximum(scores.sum(axis=1, keepdims=True), 1e-9)
-        if self.cost is not None:
-            predicted = CostBasedArbitrator(model.class_values, self.cost).arbitrate(shares)
-        elif self.decision_threshold is not None:
-            # binary pos-score threshold, as in NearestNeighbor.java:253-262
-            if self.pos_class is None:
-                raise ValueError("decision_threshold requires pos_class")
-            if c != 2:
-                raise ValueError("decision_threshold supports binary classification only")
-            p = model.class_values.index(self.pos_class)
-            predicted = np.where(shares[:, p] >= self.decision_threshold, p, 1 - p).astype(np.int32)
-        else:
-            predicted = np.argmax(shares, axis=1).astype(np.int32)
+        with tracer.span("knn.weights"):
+            w = kernel_weights(dists, self.kernel, self.kernel_sigma, self.inverse_distance)
+            neigh_labels = model.labels[idx]                        # [M, k]
+            c = len(model.class_values)
+            if self.class_cond_weighting:
+                if model.class_probs is None:
+                    raise ValueError("class_cond_weighting requires class_probs in the model")
+                post = np.take_along_axis(model.class_probs[idx], neigh_labels[..., None],
+                                          axis=2)[..., 0]           # [M, k]
+                w = w * post
+        with tracer.span("knn.vote"):
+            scores = np.zeros((dists.shape[0], c), np.float32)
+            for cls in range(c):
+                scores[:, cls] = (w * (neigh_labels == cls)).sum(axis=1)
+            shares = scores / np.maximum(scores.sum(axis=1, keepdims=True), 1e-9)
+            if self.cost is not None:
+                predicted = CostBasedArbitrator(model.class_values, self.cost).arbitrate(shares)
+            elif self.decision_threshold is not None:
+                # binary pos-score threshold, as in NearestNeighbor.java:253-262
+                if self.pos_class is None:
+                    raise ValueError("decision_threshold requires pos_class")
+                if c != 2:
+                    raise ValueError("decision_threshold supports binary classification only")
+                p = model.class_values.index(self.pos_class)
+                predicted = np.where(shares[:, p] >= self.decision_threshold, p, 1 - p).astype(np.int32)
+            else:
+                predicted = np.argmax(shares, axis=1).astype(np.int32)
         result = KNNResult(predicted=predicted, class_scores=shares,
                            neighbor_idx=idx, neighbor_dist=dists)
         if validate:

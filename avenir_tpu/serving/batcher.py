@@ -86,14 +86,17 @@ class PendingRequest:
     attribute is what lets ``telemetry slo --label tenant=<id>`` gate one
     tenant's requests out of a merged fleet journal."""
 
-    __slots__ = ("model", "line", "enqueued", "result", "error", "_done",
-                 "trace_ctx", "rid", "probe", "tenant")
+    __slots__ = ("model", "line", "enqueued", "queued", "result", "error",
+                 "_done", "trace_ctx", "rid", "probe", "tenant")
 
     def __init__(self, model: str, line: str, rid: Optional[str] = None,
                  probe: bool = False, tenant: Optional[str] = None):
         self.model = model
         self.line = line
         self.enqueued = time.monotonic()
+        # ``time.perf_counter()`` when appended to its queue: where the
+        # request's ``serve.queue`` span starts (the recorder's clock)
+        self.queued = 0.0
         self.result: Optional[str] = None
         self.error: Optional[ServingError] = None
         self._done = threading.Event()
@@ -308,6 +311,7 @@ class BucketedMicrobatcher:
                 shed_depth = len(queue)
             else:
                 queue.append(req)
+                req.queued = time.perf_counter()
                 depth = len(queue)
                 self._cond.notify()
         if shed_depth is None:
@@ -454,6 +458,31 @@ class BucketedMicrobatcher:
                         self.heartbeat = time.monotonic()
 
     def _dispatch(self, model: str, reqs: List[PendingRequest]) -> None:
+        """One batch under its ``serve.dispatch`` span, then one
+        retroactive ``serve.queue`` span a request: appended to the queue →
+        taken by this dispatch (the span's start — batches popped together
+        wait their turn in the queue span), linked by ``dispatch``."""
+        tracer = tel.tracer()
+        # a batch joins the trace of the first request that carries one
+        # (under a ScoringPlane stage: the stage's own trace)
+        ctx = next((r.trace_ctx for r in reqs if r.trace_ctx is not None),
+                   None)
+        with tracer.span("serve.dispatch", {"model": model},
+                         parent=ctx) as span:
+            self._dispatch_batch(model, reqs, span)
+        if span.enabled:
+            for req in reqs:
+                if req.probe:
+                    continue
+                attrs = {"model": model, "dispatch": span.span_id}
+                if req.rid is not None:
+                    attrs["rid"] = req.rid
+                tracer.emit_span("serve.queue", span.start - req.queued,
+                                 parent=req.trace_ctx, attrs=attrs,
+                                 start=req.queued)
+
+    def _dispatch_batch(self, model: str, reqs: List[PendingRequest],
+                        span) -> None:
         scorable = [r for r in reqs if not r.probe]
         for req in reqs:
             if req.probe:
@@ -485,6 +514,7 @@ class BucketedMicrobatcher:
             return
         entry = self.registry.get(model)
         bucket = self._bucket_for(len(live))
+        span.set("rows", len(live)).set("bucket", bucket)
         try:
             # GraftPool (round 18): the batch draws an arbitrated device
             # slot under this plane's tenant contract before it scores —
@@ -495,9 +525,12 @@ class BucketedMicrobatcher:
             # than stranding requests) and ticks the heartbeat while
             # queued — being PACED is not being WEDGED, and the pool's
             # deadline watch must not reap a merely-contended replica.
-            with tenancy.pool().slot(tenant=self.tenant or None,
-                                     timeout_s=self.request_timeout_s,
-                                     on_wait=self._beat):
+            with contextlib.ExitStack() as held:
+                with tel.tracer().span("serve.slot"):
+                    held.enter_context(tenancy.pool().slot(
+                        tenant=self.tenant or None,
+                        timeout_s=self.request_timeout_s,
+                        on_wait=self._beat))
                 t0 = time.monotonic()
                 outs = entry.score_lines([r.line for r in live], bucket)
                 dispatch_s = time.monotonic() - t0
@@ -570,6 +603,12 @@ class BucketedMicrobatcher:
                        live: List[PendingRequest], outs: List[str],
                        bucket: int,
                        dispatch_s: Optional[float] = None) -> None:
+        with tel.tracer().span("serve.reply"):
+            self._reply(entry, group, model, live, outs, bucket, dispatch_s)
+
+    def _reply(self, entry, group: str, model: str,
+               live: List[PendingRequest], outs: List[str], bucket: int,
+               dispatch_s: Optional[float]) -> None:
         # a shape outside the warmed set means this batch paid a compile
         # on the hot path — the invariant violation the counter exposes
         # (the monitor's key feed also registers each key as a GraftProf

@@ -34,6 +34,7 @@ from avenir_tpu.core.encoding import (DatasetEncoder, EncodedDataset,
                                       pad_ballast)
 from avenir_tpu.jobs.base import Job, read_lines
 from avenir_tpu.serving.errors import RequestError
+from avenir_tpu.telemetry import spans as tel
 
 
 # ---------------------------------------------------------------------------
@@ -386,14 +387,32 @@ class KNNServable(ServableModel):
         return cls(est, model, enc, delim=conf.field_delim)
 
     def score_lines(self, lines: Sequence[str], pad_to: int) -> List[str]:
-        rows = _parse_rows(lines, self.delim, self.enc.max_ordinal(False))
-        ds = _pad_ds(self.enc.transform(rows, with_labels=False), pad_to)
+        # the work is a call of its own so that its locals (the parsed
+        # rows, the encoded batch) are freed inside the span too
+        with tel.tracer().span("servable.score",
+                               {"rows": len(lines), "pad_to": pad_to}):
+            return self._score(lines, pad_to)
+
+    def _score(self, lines: Sequence[str], pad_to: int) -> List[str]:
+        tracer = tel.tracer()
+        with tracer.span("servable.parse"):
+            rows = _parse_rows(lines, self.delim, self.enc.max_ordinal(False))
+        with tracer.span("servable.encode"):
+            ds = _pad_ds(self.enc.transform(rows, with_labels=False), pad_to)
         self.compile_keys.add((pad_to,))
+        refused = self.model.cert_fallback_rows
         result = self.est.predict(self.model, ds)
-        return [
-            f"{line}{self.delim}"
-            f"{self.model.class_values[int(result.predicted[i])]}"
-            for i, line in enumerate(lines)]
+        refused = self.model.cert_fallback_rows - refused
+        if refused:
+            # the exact scan that answers refused rows is one compiled
+            # program per COUNT of them: a count first met on the hot path
+            # is a recompile the batcher's monitor has to see
+            self.compile_keys.add(("fallback", refused))
+        with tracer.span("servable.format"):
+            return [
+                f"{line}{self.delim}"
+                f"{self.model.class_values[int(result.predicted[i])]}"
+                for i, line in enumerate(lines)]
 
     def warmup(self, pad_to: int) -> None:
         self.compile_keys.add((pad_to,))

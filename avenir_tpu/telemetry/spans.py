@@ -8,10 +8,19 @@ wedged run reads as ONE tree (``python -m avenir_tpu.telemetry <journal>``)
 instead of five unrelated artifacts.  Design constraints:
 
 - **off by default is free**: the process :class:`Tracer` is a no-op until
-  ``trace.on`` enables it — ``span()`` then returns a shared inert span
-  object, so the hot paths pay one attribute check and no allocation
-  (asserted against the published nb_mi band; measured in
+  ``trace.on`` enables it or a JAX profiler session starts — ``span()``
+  otherwise returns a shared inert span object, so the hot paths pay one
+  attribute check, one static call and no allocation (asserted against
+  the published nb_mi band; measured in
   ``benchmarks/telemetry_overhead.py``).
+- **one span, three sinks**: a live span is journaled when ``trace.on``
+  is set; while a profiler session runs
+  (``jax.profiler.TraceAnnotation.is_enabled()``) it also enters a
+  ``TraceAnnotation`` of its name, so it lands on the host plane of the
+  xplane on the trace's own clock beside ``XLA Ops``; and either way it
+  is appended at close to a bounded in-memory recorder
+  (:meth:`Tracer.recorded`) stamped with ``time.perf_counter()`` — the
+  clock a benchmark's own host records use.
 - **contextvar propagation**: the current span rides a ``contextvars``
   variable, so nesting needs no plumbing and concurrent threads never
   share a current span.  Work that *crosses* threads (DeviceFeeder
@@ -47,15 +56,54 @@ import contextlib
 import contextvars
 import itertools
 import os
+import random
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterable, Iterator, Optional
+from collections import deque
+from typing import (Any, Deque, Dict, Iterable, Iterator, List, NamedTuple,
+                    Optional)
 
 from avenir_tpu.telemetry import blackbox as _blackbox
 from avenir_tpu.telemetry.journal import Journal
 
 _CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
     "avenir_tpu_current_span", default=None)
+
+# the in-memory recorder keeps this many closed spans and drops the oldest
+# beyond it (a 20 s serving window at 800 requests/s makes ~25 k)
+RECORDER_CAPACITY = 1 << 17
+
+
+class SpanRecord(NamedTuple):
+    """One closed span as the recorder keeps it; ``start``/``end`` are
+    ``time.perf_counter()`` seconds."""
+
+    name: str
+    start: float
+    end: float
+    span_id: str
+    parent_id: Optional[str]
+    trace_id: str
+    thread: int
+    attrs: Dict[str, Any]
+
+
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, bound on first use
+
+
+def profiler_session() -> bool:
+    """True while a JAX profiler session is running.  jax is looked up,
+    never imported here: a process that has not imported it has no
+    session (the journal CLI stays stdlib-only)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION.is_enabled()
 
 # GraftPool (round 18): ambient journal labels.  A tenant's workload runs
 # under ``label_scope(tenant=...)`` and EVERY event emitted from inside —
@@ -121,6 +169,11 @@ class Span:
     def enabled(self) -> bool:
         return True
 
+    @property
+    def start(self) -> float:
+        """``time.perf_counter()`` at open."""
+        return self._t0
+
     def set(self, key: str, value: Any) -> "Span":
         self.attrs[key] = value
         return self
@@ -136,13 +189,15 @@ class Span:
         self.tracer._journal_emit(ev, trace=self.trace_id,
                                   span=self.span_id, **fields)
 
-    def _close(self) -> None:
+    def _close(self) -> float:
         if self._pending is not None:
             from avenir_tpu.utils.profiling import device_sync
 
             device_sync(self._pending)
             self._pending = None
-        self.dur_ms = (time.perf_counter() - self._t0) * 1e3
+        end = time.perf_counter()
+        self.dur_ms = (end - self._t0) * 1e3
+        return end
 
 
 class _NoopSpan:
@@ -173,17 +228,32 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+# ids come from a generator seeded from the system's entropy once a process
+# (again in a forked child), not from a system call an id: a root span pays
+# for its trace id on the hot path, and a sandboxed host charges tens of
+# microseconds for ``os.urandom`` (my chip call, PR 27: 64 root spans cost a
+# dispatch 5 ms)
+_IDS = random.Random()
+os.register_at_fork(after_in_child=_IDS.seed)
+
+
 def _new_id(prefix: str) -> str:
-    return prefix + os.urandom(6).hex()
+    return f"{prefix}{_IDS.getrandbits(48):012x}"
 
 
 class Tracer:
-    """Process-wide span factory + journal front.  Disabled (free) until
-    :meth:`enable`; ``configure(conf)`` wires it from ``trace.*`` keys."""
+    """Process-wide span factory + journal front.  ``enabled`` is the
+    journal's switch (:meth:`enable`; ``configure(conf)`` wires it from
+    ``trace.*`` keys); spans are live while it is set OR while a JAX
+    profiler session runs, and free otherwise."""
 
-    def __init__(self):
+    def __init__(self, capacity: int = RECORDER_CAPACITY):
         self.enabled = False
         self.journal: Optional[Journal] = None
+        # closed live spans, oldest dropped (and counted) at capacity
+        self._recorded: Deque[SpanRecord] = deque(maxlen=int(capacity))
+        self._rec_lock = threading.Lock()
+        self.dropped = 0
         self._seq = itertools.count(1)           # thread-safe in CPython
         self._lock = threading.Lock()
         self._once: set = set()                  # event_once keys, per journal
@@ -290,10 +360,11 @@ class Tracer:
              parent: Optional[Span] = None):
         """Open a child of the context's current span (or of ``parent``
         when crossing a thread); a span with no parent roots a new trace.
-        Disabled: returns the shared NOOP span directly — one attribute
-        check, no generator frame, no allocation (the off-is-free
-        contract; benchmarks/telemetry_overhead.py)."""
-        if not self.enabled:
+        With no journal and no profiler session: returns the shared NOOP
+        span directly — one attribute check, one static call, no
+        generator frame, no allocation (the off-is-free contract;
+        benchmarks/telemetry_overhead.py)."""
+        if not self.enabled and not profiler_session():
             return NOOP_SPAN
         return self._live_span(name, attrs, parent)
 
@@ -306,9 +377,16 @@ class Tracer:
         sp = Span(self, trace_id, self._next_span_id(),
                   up.span_id if up is not None else None, name, attrs)
         token = _CURRENT.set(sp)
-        self._journal_emit("span.open", trace=sp.trace_id, span=sp.span_id,
-                           parent=sp.parent_id, name=sp.name,
-                           attrs=sp.attrs)
+        # the sinks are chosen at open and hold to the close, whatever
+        # starts or stops meanwhile
+        journaled = self.enabled
+        if journaled:
+            self._journal_emit("span.open", trace=sp.trace_id,
+                               span=sp.span_id, parent=sp.parent_id,
+                               name=sp.name, attrs=sp.attrs)
+        annotation = _ANNOTATION(name) if profiler_session() else None
+        if annotation is not None:
+            annotation.__enter__()
         try:
             yield sp
         except BaseException as exc:
@@ -316,31 +394,69 @@ class Tracer:
             raise
         finally:
             _CURRENT.reset(token)
-            sp._close()
-            self._journal_emit("span.close", trace=sp.trace_id,
-                               span=sp.span_id, name=sp.name,
-                               dur_ms=round(sp.dur_ms, 3),
-                               status=sp.status, attrs=sp.attrs)
+            end = sp._close()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            self._record(SpanRecord(
+                sp.name, sp.start, end, sp.span_id, sp.parent_id,
+                sp.trace_id, threading.get_ident(), sp.attrs))
+            if journaled:
+                self._journal_emit("span.close", trace=sp.trace_id,
+                                   span=sp.span_id, name=sp.name,
+                                   dur_ms=round(sp.dur_ms, 3),
+                                   status=sp.status, attrs=sp.attrs)
 
     def emit_span(self, name: str, dur_s: float,
                   parent: Optional[Span] = None,
                   attrs: Optional[Dict[str, Any]] = None,
-                  status: str = "ok") -> None:
-        """Retroactively journal a completed span — the cross-thread form
+                  status: str = "ok",
+                  start: Optional[float] = None) -> None:
+        """Retroactively emit a completed span — the cross-thread form
         (feeder workers, the serving dispatcher) where the work finished
-        on a thread that never held the submitting context."""
-        if not self.enabled:
+        on a thread that never held the submitting context.  ``start``
+        (``time.perf_counter()`` seconds) keeps the span's real interval
+        when it was measured elsewhere; without it the span ends now.
+        A retroactive span goes to the recorder and, under ``trace.on``,
+        to the journal — never to the profiler's trace: a ``TraceMe``
+        cannot be back-dated."""
+        if not self.enabled and not profiler_session():
             return
         trace_id = (parent.trace_id if parent is not None
                     else self._root_trace or _new_id("t"))
         span_id = self._next_span_id()
-        ts = time.time()
+        parent_id = parent.span_id if parent else None
+        now = time.perf_counter()
+        if start is None:
+            start = now - dur_s
+        self._record(SpanRecord(name, start, start + dur_s, span_id,
+                                parent_id, trace_id, threading.get_ident(),
+                                dict(attrs or {})))
+        if not self.enabled:
+            return
+        ts = time.time() - (now - start - dur_s)      # wall time of the end
         self._journal_emit("span.open", trace=trace_id, span=span_id,
-                           parent=parent.span_id if parent else None,
+                           parent=parent_id,
                            name=name, attrs=dict(attrs or {}), ts=ts - dur_s)
         self._journal_emit("span.close", trace=trace_id, span=span_id,
                            name=name, dur_ms=round(dur_s * 1e3, 3),
                            status=status, attrs=dict(attrs or {}), ts=ts)
+
+    # -- the in-memory recorder ----------------------------------------------
+    def _record(self, rec: SpanRecord) -> None:
+        with self._rec_lock:
+            if len(self._recorded) == self._recorded.maxlen:
+                self.dropped += 1
+            self._recorded.append(rec)
+
+    def recorded(self, clear: bool = False) -> List[SpanRecord]:
+        """A copy of the closed spans the recorder holds, in the order
+        they closed; ``clear=True`` also empties it (``dropped`` counts
+        on)."""
+        with self._rec_lock:
+            out = list(self._recorded)
+            if clear:
+                self._recorded.clear()
+        return out
 
     def _next_span_id(self) -> str:
         """Fleet-unique span id: the writer prefix (``p<k>[-<suffix>].``,

@@ -36,6 +36,15 @@ always-on recorder's whole off-state price) and ``event_site_ns_on``
 (ring append + journal line).  ``span_ns_off`` is measured by the SAME
 code as before the recorder merged — the span sites do not touch the
 ring, so the published off-is-free span bound is unchanged by round 21.
+
+PR 27 makes a span site live under a running JAX profiler session too, so
+the off state now pays one static call besides the attribute check
+(``jax.profiler.TraceAnnotation.is_enabled()``): ``span_ns_off`` is
+measured with jax imported, as in a serving process, and
+``span_ns_on_profiled`` is the cost of a span while a profiler session
+runs and no journal is open — a ``TraceAnnotation`` entered and left plus
+one record appended to the in-memory recorder, what a traced benchmark
+run pays per span.
 """
 
 from __future__ import annotations
@@ -45,6 +54,7 @@ import os
 import tempfile
 import time
 
+import jax
 import numpy as np
 
 from avenir_tpu.telemetry import blackbox
@@ -126,6 +136,17 @@ def measure() -> dict:
         fed_bytes = os.path.getsize(fed.journal_path)
         fed.disable()
 
+    # a profiler session and no journal: annotation + recorder append
+    profiled = Tracer()
+    with tempfile.TemporaryDirectory() as tmp:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(tmp, profiler_options=opts)
+        try:
+            profiled_ns = measure_span_ns(profiled)
+        finally:
+            jax.profiler.stop_trace()
+
     # the nb_mi bench adds ~7 span sites per run (one bench span, five
     # pass spans, plus per-pass canary events); a pass is seconds of
     # device time, so project the off cost onto one 1-second pass
@@ -139,6 +160,8 @@ def measure() -> dict:
         "event_site_ns_off": round(event_off_ns, 1),
         "event_site_ns_on": round(event_on_ns, 1),
         "span_ns_on_journaled": round(on_ns, 1),
+        "span_ns_on_profiled": round(profiled_ns, 1),
+        "host": f"{os.cpu_count()} cores, {jax.devices()[0].platform}",
         "span_ns_on_federated": round(fed_ns, 1),
         "journal_bytes_per_span": round(journal_bytes
                                         / (SPANS_PER_BATCH * BATCHES), 1),
