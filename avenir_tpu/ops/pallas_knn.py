@@ -249,42 +249,47 @@ def topk_candidates(q_mat, r_mat, k: int, margin: int = MARGIN
 
 
 # ---------------------------------------------------------------------------
-# segmented key-tournament sweep — the round-3 candidate kernel
+# segmented key-tournament sweep — the candidate kernel at scale
 # ---------------------------------------------------------------------------
-# Round 2's block top-2 sweep cost ~26-42 ms/call at 1M refs. A round-3
-# bisection (chained-sync, fresh process) re-attributed the cost: the dot
-# itself reaches the bare-XLA matmul bound (~11 ms) once the ref block is
-# 16K rows (the "3× Mosaic overhead" of round 2 was the 16 MB default
-# scoped-VMEM limit forcing 2K-row blocks — raising vmem_limit_bytes
-# admits the big tiles), f32 min-reductions carry a ~3× NaN-semantics
-# penalty over int32, and every equality-masked extraction pass costs a
-# materialized full-array traversal. This kernel:
+# Why (round-3 bisection; 2026-07 record at 4096 × 1M refs on an
+# older stack: 22.1 ms/call vs 42.1 for the top-2 sweep): the
+# dot reaches the bare-XLA matmul bound once the ref block is 16K rows
+# (vmem_limit_bytes admits it; the 16 MiB default refuses packed widths
+# ≥ 256), f32 min-reductions cost ~3× int32 ones, and an equality-masked
+# extraction pass is a materialized full-array traversal. The kernel
 #   - packs each distance into ONE int32 sort key,
 #     (bitcast(max(d2,0)) & ~(SEG-1)) | col — positive-float bitcast is
-#     order-preserving, so min-of-key IS argmin, columns ride in the low
-#     11 bits, and all comparisons become cheap int32 min/max;
-#   - extracts each 2048-ref segment's smallest two keys plus its
-#     third-smallest as the non-candidate bound via a lane-halving
-#     TOURNAMENT of sorted (m1,m2,m3) triples — pure min/max merges, no
-#     equality masks, no data-dependent control flow;
-#   - streams refs in 16K-row blocks (8 segments per DMA) so per-grid-step
-#     overhead amortizes.
-# Measured 22.1 ms/call vs 42.1 for the round-2 structure in the identical
-# fresh-process harness (1.9×). Exactness contract is unchanged from the
-# top-2 sweep: true top-k ⊆ candidates unless a segment hides ≥3 of the
-# true top-k; key truncation only LOWERS the per-segment bound (by
-# ≤ 2⁻¹² relative), which can only add cert failures, never unsound ones.
+#     order-preserving, so min-of-key IS argmin and the column rides in the
+#     low 11 bits;
+#   - takes each 2048-ref segment's smallest two keys, plus the third as
+#     the non-candidate bound, by a lane-halving TOURNAMENT of sorted
+#     (m1,m2,m3) triples: min/max merges only, no data-dependent control;
+#   - streams refs in 16K-row blocks (8 segments a DMA);
+#   - deposits a step's 8 × 3 results into ONE resident [TM, 128] column
+#     block per output. Until PR 28 that block was the whole
+#     [TM, refs/2048] row, re-selected on every deposit: O(refs) a step,
+#     423 of 572 ms a sweep at 13×2^20 refs (docs/architecture.md).
+# Exact: true top-k ⊆ candidates unless a segment hides ≥3 of it; key
+# truncation only LOWERS a segment's bound (≤ 2⁻¹² relative): sound.
 
 TB = 16384             # reference rows per grid step (one DMA, 8 segments)
 SEG = 2048             # certificate granularity: top-2 + third-min bound
 # pad-lane key: the int32 bit pattern of _BIG (finite; NEVER 0x7fffffff,
 # whose truncated bitcast is NaN and would poison every downstream min)
 _PAD_KEY = int(np.float32(_BIG).view(np.int32))
+_COL_STEPS = 128 // (TB // SEG)    # grid steps per 128-lane output block
 
 
-def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out, *, nbp: int):
+def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out):
     j = pl.program_id(1)
     nseg = TB // SEG
+    # the _COL_STEPS grid steps that share an output block fill its lanes; the
+    # first pins all to the pad key, which lanes past the last segment keep
+    @pl.when(j % _COL_STEPS == 0)
+    def _init():
+        for out in (k1_out, k2_out, k3_out):
+            out[:] = jnp.full((TM, 128), _PAD_KEY, jnp.int32)
+
     d2v = jax.lax.dot_general(
         a_ref[:], b_ref[:], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -294,7 +299,9 @@ def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out, *, nbp: int):
     # points; negative-float bitcast would invert the int ordering
     di = jax.lax.bitcast_convert_type(jnp.maximum(d2v, 0.0), jnp.int32)
     key = (di & jnp.int32(~(SEG - 1))) | col
-    outlane = jax.lax.broadcasted_iota(jnp.int32, (TM, nbp), 1)
+    # output-block lane, counted from this step's first segment
+    outlane = (jax.lax.broadcasted_iota(jnp.int32, (TM, 128), 1)
+               - (j % _COL_STEPS) * nseg)
     for s in range(nseg):
         seg = key[:, s * SEG:(s + 1) * SEG]
         # round 1: adjacent halves -> sorted pairs
@@ -332,10 +339,32 @@ def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out, *, nbp: int):
         em2 = jnp.where(em == t2[:, None],
                         jnp.where(m1 == t1[:, None], m3, m2), em)
         t3 = jnp.min(em2, axis=1)
-        sel = outlane == (j * nseg + s)
-        k1_out[:] = jnp.where(sel, t1[:, None], k1_out[:])
-        k2_out[:] = jnp.where(sel, t2[:, None], k2_out[:])
-        k3_out[:] = jnp.where(sel, t3[:, None], k3_out[:])
+        for out, t in ((k1_out, t1), (k2_out, t2), (k3_out, t3)):
+            out[:] = jnp.where(outlane == s, t[:, None], out[:])
+
+
+def _tourney_keys(a_mat, b_mat):
+    """The sweep's raw output: three int32 arrays, a lane a segment (rounded
+    up to 128s): its three smallest keys, ``_PAD_KEY`` past the last one."""
+    m, n = a_mat.shape[0], b_mat.shape[0]
+    spec = pl.BlockSpec((TM, 128), lambda i, j: (i, j // _COL_STEPS),
+                        memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _knn_tourney_kernel,
+        grid=(m // TM, n // TB),
+        in_specs=[
+            pl.BlockSpec((TM, a_mat.shape[1]), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((TB, b_mat.shape[1]), lambda i, j: (j, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=[spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct(
+            (m, _round_up(n // SEG, 128)), jnp.int32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024),
+    )(a_mat, b_mat)
 
 
 def _topk_tourney_traced(a_mat, b_mat, k: int):
@@ -345,37 +374,9 @@ def _topk_tourney_traced(a_mat, b_mat, k: int):
     indices, [Mpad] non-candidate lower bound = min over segments of the
     segment's truncated third-smallest distance).
     Requires 2 * (n/SEG) >= k and n % TB == 0 (prepare_refs pads to TB)."""
-    m, n = a_mat.shape[0], b_mat.shape[0]
-    nb = n // TB
-    nseg = n // SEG
-    nbp = _round_up(nseg, 128)
-    grid = (m // TM, nb)
-    kern = functools.partial(_knn_tourney_kernel, nbp=nbp)
-    spec = pl.BlockSpec((TM, nbp), lambda i, j: (i, 0),
-                        memory_space=pltpu.VMEM)
-    k1, k2, k3 = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TM, a_mat.shape[1]), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((TB, b_mat.shape[1]), lambda i, j: (j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((m, nbp), jnp.int32)] * 3,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024),
-    )(a_mat, b_mat)
-    # unwritten pad lanes (seg >= nseg) hold garbage: pin to the pad key
-    pad = jnp.arange(nbp) >= nseg
-    pk_ = jnp.int32(_PAD_KEY)
-    k1 = jnp.where(pad[None, :], pk_, k1)
-    k2 = jnp.where(pad[None, :], pk_, k2)
-    k3 = jnp.where(pad[None, :], pk_, k3)
+    k1, k2, k3 = _tourney_keys(a_mat, b_mat)
     segmask = jnp.int32(~(SEG - 1))
-    seg_base = jnp.arange(nbp, dtype=jnp.int32) * SEG
+    seg_base = jnp.arange(k1.shape[1], dtype=jnp.int32) * SEG
 
     def unpack(kk_):
         d = jax.lax.bitcast_convert_type(kk_ & segmask, jnp.float32)
@@ -462,8 +463,7 @@ def _search_fused(codes_q: jax.Array, cont01_q: jax.Array, r_mat: jax.Array,
     q_mat = _pack_queries_dev(codes_q, cont01_q, num_bins, rows, extra_norm)
     block2 = use_tourney
     if block2:
-        # segment key-tournament sweep (1.9× the round-2 top-2 sweep); the
-        # per-segment truncated third-min bound keeps the certificate exact
+        # tournament sweep; its third-min bound keeps the cert exact
         cand_d2, cand_idx, bound3 = _topk_tourney_traced(q_mat, r_mat, kk)
     else:
         cand_d2, cand_idx = _topk_pallas_traced(q_mat, r_mat, kk)

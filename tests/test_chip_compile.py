@@ -96,14 +96,18 @@ def test_shared_scan_gram_moments_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("use_tourney", [True, False],
-                         ids=["tournament", "merge"])
-def test_knn_fused_search_compiles_for_v5e(one_chip, use_tourney):
-    """1 M elearn-shaped references (9 continuous attributes, packed width
-    128) x 4096 queries through the whole fused search program."""
+@pytest.mark.parametrize("n,use_tourney", [
+    (1_000_000, True),
+    (1_000_000, False),
+    (13 << 20, True),           # the benchmark's cells (perfbench/configs)
+    (1 << 24, True),            # refused until PR 28: 104.25M of scoped VMEM
+], ids=["tournament", "merge", "tournament-13Mi", "tournament-16Mi"])
+def test_knn_fused_search_compiles_for_v5e(one_chip, n, use_tourney):
+    """elearn-shaped references (9 continuous attributes, packed width 128)
+    x 4096 queries through the whole fused search program."""
     from avenir_tpu.ops import pallas_knn as pk
 
-    n, m, fc, k = 1_000_000, 4096, 9, 10
+    m, fc, k = 4096, 9, 10
     npad = pk._round_up(n, pk.TB)
     width = pk._width(0, 1, fc)
     assert width == 128 and npad % pk.TN == 0

@@ -199,6 +199,36 @@ def test_search_fused_block2_path_matches_oracle(rng):
     assert (i[ok] == oi[ok]).mean() == 1.0
 
 
+@pytest.mark.parametrize("nblocks", [3, 17, 33])
+@needs_tpu_interpret
+def test_tourney_keys_match_sorted_segments(rng, nblocks):
+    # the raw kernel outputs, before the XLA assembly, against a sort of
+    # every 2048-reference segment's keys: 1, 2 and 3 column blocks of 128
+    # segments (the last two end in a partly filled block) and two query
+    # tiles. Operands are multiples of 1/8 below 2, so every partial sum of
+    # the dot is exact in f32 whatever the order, and the key's truncation
+    # (1/16 at these magnitudes) still bites.
+    import jax.numpy as jnp
+
+    m, n, width = 2 * pk.TM, nblocks * pk.TB, 128
+    a = rng.integers(0, 16, size=(m, width)).astype(np.float32) / 8
+    b = rng.integers(0, 16, size=(n, width)).astype(np.float32) / 8
+    with pltpu.force_tpu_interpret_mode():
+        got = [np.asarray(x) for x in pk._tourney_keys(
+            jnp.asarray(a, jnp.bfloat16), jnp.asarray(b, jnp.bfloat16))]
+    nseg = n // pk.SEG
+    assert all(g.shape == (m, pk._round_up(nseg, 128)) for g in got)
+    col = np.arange(pk.SEG, dtype=np.int32)
+    want = np.empty((3, m, nseg), np.int32)
+    for s in range(nseg):
+        d2 = a @ b[s * pk.SEG:(s + 1) * pk.SEG].T
+        key = (d2.view(np.int32) & np.int32(~(pk.SEG - 1))) | col
+        want[:, :, s] = np.sort(key, axis=1)[:, :3].T
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g[:, :nseg], w)
+        assert (g[:, nseg:] == pk._PAD_KEY).all()
+
+
 @needs_tpu_interpret
 def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
     # regression: n_real = 8*TN+1 puts one real ref in the last block, so a
