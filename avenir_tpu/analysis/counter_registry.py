@@ -41,6 +41,7 @@ SPAN_SITES = {
     'job.*': 'docs/observability.md',
     'knn.classify': 'docs/observability.md',
     'knn.fallback': 'docs/observability.md',
+    'knn.place': 'docs/observability.md',
     'knn.readback': 'docs/observability.md',
     'knn.search': 'docs/observability.md',
     'knn.stage': 'docs/observability.md',

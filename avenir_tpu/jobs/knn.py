@@ -185,3 +185,7 @@ class NearestNeighbor(Job):
             counters.set("Records", "Search.tournament", model.tourney_rows)
             counters.set("Records", "Search.certFallback",
                          model.cert_fallback_rows)
+            if model.shard_fused_rows:
+                # of Search.fused: answered over a row-sharded index
+                counters.set("Records", "Search.sharded",
+                             model.shard_fused_rows)

@@ -32,6 +32,7 @@ applied only in the serde view (a documented deliberate fix).
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -49,6 +50,8 @@ KERNELS = ("none", "linearMultiplicative", "linearAdditive", "gaussian")
 # The fused Pallas TPU kernel (ops/pallas_knn.py) is used automatically on
 # TPU backends for the euclidean metric; set to False to force the XLA scan.
 USE_PALLAS = True
+# reference rows a step of the exact XLA tile scan, on one device or a shard
+SCAN_TILE = 65536
 
 
 @dataclass
@@ -70,6 +73,7 @@ class KNNModel:
     fused_rows: int = 0
     tourney_rows: int = 0               # of fused_rows: tournament kernel
     cert_fallback_rows: int = 0
+    shard_fused_rows: int = 0           # of fused_rows: row-sharded index
 
     @property
     def num_refs(self) -> int:
@@ -103,6 +107,42 @@ class KNNModel:
             c = self.__dict__["_dev_rerank"] = (
                 jnp.asarray(self.codes), jnp.asarray(self.cont01()))
         return c
+
+    def sharded_index(self, mesh):
+        """The index placed over ``mesh`` by :meth:`device_sharded`, or None
+        where it has not been: (packed operand, codes, normalised continuous
+        columns, rows a shard), the arrays row-sharded over ``data``."""
+        return self.__dict__.get("_dev_sharded_index", {}).get(mesh)
+
+    def device_sharded(self, mesh, num_bins: int):
+        """The sharded twin of :meth:`device_packed` and
+        :meth:`device_rerank_arrays` (cached per mesh): the reference rows in
+        ``data`` contiguous row shards, each device holding its shard's codes
+        and normalised coordinates — what the exact re-rank gathers from and
+        what the exact scan of refused rows reads — and the packed bf16
+        operand it built from them itself (parallel/collectives.py::
+        sharded_knn_pack).  The host computes only the rows' squared norms."""
+        from avenir_tpu.ops import pallas_knn
+        from avenir_tpu.parallel import collectives
+        from avenir_tpu.parallel.mesh import data_sharding, pad_batch
+
+        cache = self.__dict__.setdefault("_dev_sharded_index", {})
+        if mesh not in cache:
+            n, d_par = self.num_refs, mesh.shape["data"]
+            shard = _index_shard_rows(n, d_par)
+            with tel.tracer().span("knn.place", {
+                    "refs": n, "shards": d_par, "shard_rows": shard,
+                    "operand_rows": pallas_knn.operand_rows(shard)}):
+                codes_s, cont01_s, norm_s = (
+                    jax.device_put(a, data_sharding(mesh, a.ndim))
+                    for a in pad_batch(shard * d_par, self.codes,
+                                       self.cont01(),
+                                       _row_norms(self.cont01())))
+                r_mat = collectives.sharded_knn_pack(mesh, num_bins)(
+                    codes_s, cont01_s, norm_s, jnp.int32(n))
+                cache[mesh] = (jax.block_until_ready(r_mat), codes_s,
+                               cont01_s, shard)
+        return cache[mesh]
 
     def device_tiles(self, ref_tile: int):
         """Reference set as resident device arrays [T, ref_tile, ·], padded to
@@ -150,6 +190,20 @@ def _normalize_cont(cont, lo, hi):
 def _normalize01(cont: np.ndarray, lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
     span = np.maximum(hi - lo, 1e-9)
     return np.clip((cont - lo) / span, 0.0, 1.0).astype(np.float32)
+
+
+def _row_norms(cont01: np.ndarray, piece: int = 1 << 20) -> np.ndarray:
+    """[N] f32 squared norms, summed in float64 as the host pack sums them
+    (ops/pallas_knn.py::_pack), a piece of rows a thread."""
+    out = np.empty(cont01.shape[0], np.float32)
+
+    def one(s0: int) -> None:
+        out[s0:s0 + piece] = (
+            cont01[s0:s0 + piece].astype(np.float64) ** 2).sum(axis=1)
+
+    with ThreadPoolExecutor(max_workers=8) as pool:
+        list(pool.map(one, range(0, cont01.shape[0], piece)))
+    return out
 
 
 def _tile_distances(
@@ -244,6 +298,47 @@ def _pallas_available(metric: str, k: int) -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _count_fused(model: KNNModel, rows: int, tourney: bool, span,
+                 kernel_rows: int) -> None:
+    """One fused search's rows on the route tally, once each whatever the
+    number of shards, and the kernel's swept rows on the ``knn.search`` span
+    (the kernel sweeps whole TM-row query tiles, whatever it was handed)."""
+    model.fused_rows += rows
+    if tourney:
+        model.tourney_rows += rows
+    span.set("kernel_rows", kernel_rows)
+
+
+def _rescan_refused(model: KNNModel, test: EncodedDataset, k: int,
+                    d: np.ndarray, idx: np.ndarray, cert: np.ndarray, span,
+                    scan) -> Tuple[np.ndarray, np.ndarray]:
+    """The fused search's answers with every row whose certificate failed
+    (the candidate set might miss a true neighbour) recomputed by the exact
+    scan ``scan(rows) -> (d, idx)``; counted on ``cert_fallback_rows`` and
+    the span's ``refused``."""
+    refused = int(cert.size - cert.sum())
+    model.cert_fallback_rows += refused
+    span.set("refused", refused)
+    if not refused:
+        return d, idx
+    # the exact scan compiles one program per count of refused rows
+    seen = model.__dict__.setdefault("_fallback_counts", set())
+    with tel.tracer().span("knn.fallback", {
+            "rows": refused, "new_program": refused not in seen}):
+        seen.add(refused)
+        # np.asarray of a device array is a read-only view; the fallback
+        # writes row-wise
+        d, idx = d.copy(), idx.copy()
+        rows = np.flatnonzero(~cert)
+        d[rows], idx[rows] = scan(EncodedDataset(
+            codes=test.codes[rows], cont=test.cont[rows],
+            labels=None if test.labels is None else test.labels[rows],
+            ids=None, n_bins=test.n_bins, class_values=test.class_values,
+            binned_ordinals=test.binned_ordinals,
+            cont_ordinals=test.cont_ordinals))
+    return d, idx
+
+
 def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int,
                               span=tel.NOOP_SPAN
                               ) -> Tuple[np.ndarray, np.ndarray]:
@@ -270,46 +365,43 @@ def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int,
         d = np.asarray(d_dev)
         idx = np.asarray(i_dev)
         cert = np.asarray(cert_dev)
-    model.fused_rows += int(cert.size)
-    if pallas_knn.tourney_engages(n, r_mat.shape[0], k):
-        model.tourney_rows += int(cert.size)
-    refused = int(cert.size - cert.sum())
-    model.cert_fallback_rows += refused
-    # the kernel sweeps whole TM-row query tiles, whatever it was handed
-    # (search_fused's own rounding)
-    span.set("kernel_rows", pallas_knn._round_up(
-        max(test.num_rows, pallas_knn.TM), pallas_knn.TM))
-    span.set("refused", refused)
-    if refused:
-        # the exact scan compiles one program per count of refused rows
-        seen = model.__dict__.setdefault("_fallback_counts", set())
-        with tracer.span("knn.fallback", {"rows": refused,
-                                          "new_program": refused not in seen}):
-            seen.add(refused)
-            # np.asarray of a device array is a read-only view; the fallback
-            # writes row-wise
-            d, idx = d.copy(), idx.copy()
-            # certificate failed for some rows (approx candidate set might
-            # miss a true neighbor): recompute those rows with the exact
-            # XLA scan
-            rows = np.flatnonzero(~cert)
-            sub = EncodedDataset(
-                codes=test.codes[rows], cont=test.cont[rows],
-                labels=None if test.labels is None else test.labels[rows],
-                ids=None, n_bins=test.n_bins, class_values=test.class_values,
-                binned_ordinals=test.binned_ordinals,
-                cont_ordinals=test.cont_ordinals)
-            d_sub, i_sub = _nearest_neighbors_xla(model, sub, k, "euclidean",
-                                                  65536, 8192)
-            d[rows] = d_sub
-            idx[rows] = i_sub
-    return d, idx
+    _count_fused(model, int(cert.size),
+                 pallas_knn.tourney_engages(n, r_mat.shape[0], k), span,
+                 pallas_knn.query_rows(test.num_rows))
+    return _rescan_refused(model, test, k, d, idx, cert, span,
+                           lambda sub: _nearest_neighbors_xla(model, sub, k))
 
 
 def _shard_rows(n: int, d_par: int) -> int:
     """ceil(n / d_par) — the per-device shard row count; one spelling shared
     by the mesh routing gate and the sharded search path."""
     return max(-(-n // d_par), 1)
+
+
+def _index_shard_rows(n: int, d_par: int) -> int:
+    """Rows a shard of the sharded index holds (KNNModel.device_sharded):
+    ceil(n / d_par), past one scan tile rounded up to the kernels' 2048-row
+    tile, so that the exact scan of refused rows walks the placed arrays in
+    whole tiles of 2048·2^j rows (13 × 2^20 rows a shard: 65536)."""
+    shard = _shard_rows(n, d_par)
+    return shard if shard <= SCAN_TILE else -(-shard // 2048) * 2048
+
+
+def sharded_route(mesh, metric: str, k: int, refs: int) -> Optional[str]:
+    """The search a set of ``refs`` references takes over ``mesh`` — the one
+    gate :func:`nearest_neighbors` routes by.  None: the mesh does not shard
+    ``data``.  ``"sharded_fused"``: the certified fused Pallas search on
+    every shard and one certified all_gather merge — euclidean on a TPU,
+    ``k`` within the kernel's slots and within every shard's real rows.
+    ``"sharded_scan"``: the exact XLA tile scan over the row shards —
+    everything else, and every row the fused search refuses."""
+    if mesh is None or mesh.shape.get("data", 1) < 2:
+        return None
+    d_par = mesh.shape["data"]
+    last = refs - (d_par - 1) * _index_shard_rows(refs, d_par)
+    if _pallas_available(metric, k) and last >= k:
+        return "sharded_fused"
+    return "sharded_scan"
 
 
 def _pad_topk(d: np.ndarray, i: np.ndarray, k: int, k_eff: int
@@ -324,14 +416,19 @@ def _pad_topk(d: np.ndarray, i: np.ndarray, k: int, k_eff: int
 
 def _nearest_neighbors_sharded(model: KNNModel, test: EncodedDataset, k: int,
                                metric: str, mesh, test_tile: int,
-                               ref_tile: int = 65536,
+                               ref_tile: int = SCAN_TILE,
                                ) -> Tuple[np.ndarray, np.ndarray]:
     """Reference rows sharded over the mesh's ``data`` axis, exact global
     top-k via one all_gather merge (parallel/collectives.sharded_knn_topk,
-    lru-cached so repeated queries reuse the compiled program). The sharded
-    reference set is cached on the model like device_tiles; each device
+    lru-cached so repeated queries reuse the compiled program). Each device
     scans its shard in ``ref_tile``-row tiles, so per-device memory is
-    bounded exactly like the single-device scan."""
+    bounded exactly like the single-device scan.
+
+    Where the model's sharded index is placed on this mesh
+    (KNNModel.device_sharded — the fused route's refused rows come here)
+    the scan reads ITS codes and normalised coordinates: no further
+    resident copy.  Otherwise the raw reference set is sharded and cached
+    on the model like device_tiles."""
     from avenir_tpu.parallel import collectives
     from avenir_tpu.parallel.mesh import data_sharding, pad_batch
 
@@ -339,64 +436,124 @@ def _nearest_neighbors_sharded(model: KNNModel, test: EncodedDataset, k: int,
     d_par = mesh.shape["data"]
     nb = int(model.n_bins.max()) if model.n_bins.size else 1
     k_eff = min(k, n)
-    shard = _shard_rows(n, d_par)
-    tile = min(ref_tile, shard)
-    padded_local = -(-shard // tile) * tile        # whole tiles per device
-    npad = padded_local * d_par
-    cache = model.__dict__.setdefault("_dev_sharded", {})
-    key = (mesh, tile)                             # Mesh is hashable
-    if key not in cache:
-        # pad fill −1 is safe: pad rows are masked by global index ≥ n_real
-        rc, rx = pad_batch(npad, model.codes, model.cont)
-        cache[key] = (jax.device_put(rc, data_sharding(mesh, 2)),
-                      jax.device_put(rx, data_sharding(mesh, 2)))
-    rc_s, rx_s = cache[key]
+    placed = model.sharded_index(mesh)
+    if placed is not None:
+        _r_mat, rc_s, rx_s, shard = placed
+        tile = shard if shard <= SCAN_TILE else int(np.gcd(shard, SCAN_TILE))
+        # already normalised: the scan's own normalisation is then the
+        # identity (lo 0, hi 1)
+        test_cont = _normalize01(test.cont, model.cont_lo, model.cont_hi)
+        lo, hi = jnp.zeros_like(model.cont_lo), jnp.ones_like(model.cont_hi)
+    else:
+        shard = _shard_rows(n, d_par)
+        tile = min(ref_tile, shard)
+        padded_local = -(-shard // tile) * tile        # whole tiles per device
+        npad = padded_local * d_par
+        cache = model.__dict__.setdefault("_dev_sharded", {})
+        key = (mesh, tile)                             # Mesh is hashable
+        if key not in cache:
+            # pad fill −1 is safe: pad rows are masked by global index ≥ n_real
+            rc, rx = pad_batch(npad, model.codes, model.cont)
+            cache[key] = (jax.device_put(rc, data_sharding(mesh, 2)),
+                          jax.device_put(rx, data_sharding(mesh, 2)))
+        rc_s, rx_s = cache[key]
+        test_cont = test.cont
+        lo, hi = jnp.asarray(model.cont_lo), jnp.asarray(model.cont_hi)
     step = collectives.sharded_knn_topk(mesh, k=k_eff, num_bins=nb,
                                         metric=metric, ref_tile=tile)
-    lo, hi = jnp.asarray(model.cont_lo), jnp.asarray(model.cont_hi)
     out_d, out_i = [], []
     for m0 in range(0, test.num_rows, test_tile):
         bd, bi = step(jnp.asarray(test.codes[m0:m0 + test_tile]),
-                      jnp.asarray(test.cont[m0:m0 + test_tile]),
+                      jnp.asarray(test_cont[m0:m0 + test_tile]),
                       rc_s, rx_s, lo, hi, jnp.int32(n))
         out_d.append(np.asarray(bd))
         out_i.append(np.asarray(bi))
     return _pad_topk(np.concatenate(out_d), np.concatenate(out_i), k, k_eff)
 
 
+def _nearest_neighbors_sharded_fused(model: KNNModel, test: EncodedDataset,
+                                     k: int, mesh, test_tile: int, span
+                                     ) -> Tuple[np.ndarray, np.ndarray]:
+    """The fused path over a row-sharded index: ONE jitted dispatch runs the
+    fused search of :func:`_nearest_neighbors_pallas` on every shard and
+    merges the shards' top-k (parallel/collectives.py::sharded_knn_fused).
+    A row is certified where its merged k-th distance is within EVERY
+    shard's limit on what it hides; a row any shard refuses is answered by
+    the exact scan over the same placed arrays.
+    ``span`` is the caller's ``knn.search`` span: it gets ``shards``,
+    ``kernel_rows``, ``refused`` and ``refused_by_shard``."""
+    from avenir_tpu.ops import pallas_knn
+    from avenir_tpu.parallel import collectives
+    tracer = tel.tracer()
+    nb = int(model.n_bins.max()) if model.n_bins.size else 1
+    n, d_par = model.num_refs, mesh.shape["data"]
+    r_mat, codes_r, cont01_r, shard = model.device_sharded(mesh, nb)
+    m, f = test.codes.shape
+    fc = test.cont.shape[1]
+    statics = pallas_knn.fused_statics(m, f, fc, k)
+    # one kernel for every shard: the one the shortest (the last) can fill
+    tourney = pallas_knn.tourney_engages(
+        n - (d_par - 1) * shard, r_mat.shape[0] // d_par, k)
+    with tracer.span("knn.stage"):
+        # normalise, upload the queries, enqueue the program
+        cont01_q = _normalize01(test.cont, model.cont_lo, model.cont_hi)
+        out = collectives.sharded_knn_fused(
+            mesh, shard, num_bins=nb, total_attrs=f + fc,
+            use_tourney=tourney, **statics)(
+                jnp.asarray(test.codes), jnp.asarray(cont01_q), r_mat,
+                codes_r, cont01_r, jnp.int32(n))
+    with tracer.span("knn.readback"):
+        d, idx, cert, by_shard = (np.asarray(a) for a in out)
+    _count_fused(model, int(cert.size), tourney, span, statics["rows"])
+    model.shard_fused_rows += int(cert.size)
+    span.set("shards", d_par)
+    span.set("refused_by_shard", by_shard.tolist())
+    return _rescan_refused(
+        model, test, k, d, idx, cert, span,
+        lambda sub: _nearest_neighbors_sharded(model, sub, k, "euclidean",
+                                               mesh, test_tile))
+
+
 def nearest_neighbors(
     model: KNNModel, test: EncodedDataset, k: int,
-    metric: str = "euclidean", ref_tile: int = 65536, test_tile: int = 8192,
-    mode: str = "exact", mesh=None,
+    metric: str = "euclidean", ref_tile: int = SCAN_TILE,
+    test_tile: int = 8192, mode: str = "exact", mesh=None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """([M, k] distances, [M, k] reference indices), ascending by distance.
 
     ``mode="exact"`` (default): on TPU backends the euclidean metric
     dispatches to the fused Pallas search (segment key-tournament + exact
-    re-rank, ~9× the XLA scan at 1M refs when measured in 2026-07);
-    everything else
-    uses the compiled XLA tile scan. ``mode="approx"``: a quality floor,
+    re-rank); everything else uses the compiled XLA tile scan.  How much
+    faster the fused search is has one reading on today's code: 19× at
+    4 × 13 × 2^20 references row-sharded over four chips, 4096-query blocks
+    (22 451 against 1 169.5 queries/s end to end, PERF_LEDGER.jsonl, PR 29's
+    lines); the "~9× at 1M refs" this said until PR 30 was a 2026-07 record,
+    not measured on today's code.  ``mode="approx"``: a quality floor,
     not a method — when the fused exact path applies it is BOTH faster and
     exact, so an approx request routes there (≥-quality results, like the
-    sharded route below); only configurations the kernel cannot serve
+    sharded routes below); only configurations the kernel cannot serve
     (manhattan metric, k > kernel slots, non-TPU backends) run the
     per-tile ``lax.approx_min_k`` + exact cross-tile merge (0.9988
-    measured end-to-end recall at 1M refs, k=10) — a capability knob the
-    reference has no analog for, OFF unless asked for."""
+    end-to-end recall at 1M refs, k=10 in the 2026-07 records; not measured
+    on today's code) — a capability knob the reference has no analog for,
+    OFF unless asked for.
+
+    A ``mesh`` that shards ``data`` holds the reference rows in contiguous
+    row shards and routes by :func:`sharded_route`; both sharded routes are
+    exact AND parallel, so they serve both modes."""
     if mode not in ("exact", "approx"):
         raise ValueError(f"unknown search mode {mode!r}; use exact|approx")
     rows = test.num_rows
     with tel.tracer().span("knn.search", {"rows": rows, "kernel_rows": rows,
                                           "refused": 0}) as span:
-        if mesh is not None and mesh.shape.get("data", 1) > 1:
-            # the sharded-reference path is exact AND parallel, so it serves
-            # both modes (an approx request gets ≥-quality results); the
-            # all_gather merge needs k candidates per device shard
-            if min(k, model.num_refs) <= _shard_rows(model.num_refs,
-                                                     mesh.shape["data"]):
-                span.set("path", "sharded")
-                return _nearest_neighbors_sharded(model, test, k, metric,
-                                                  mesh, test_tile, ref_tile)
+        route = sharded_route(mesh, metric, k, model.num_refs)
+        if route is not None:
+            span.set("path", route)
+            if route == "sharded_fused":
+                return _nearest_neighbors_sharded_fused(model, test, k, mesh,
+                                                        test_tile, span)
+            return _nearest_neighbors_sharded(model, test, k, metric, mesh,
+                                              test_tile, ref_tile)
         if _pallas_available(metric, k) and min(k, model.num_refs) == k:
             span.set("path", "fused")
             return _nearest_neighbors_pallas(model, test, k, span)

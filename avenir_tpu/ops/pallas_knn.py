@@ -227,7 +227,7 @@ def prepare_refs(codes: np.ndarray, cont01: np.ndarray, num_bins: int
     route to the merge kernel — round only to TN, avoiding up-to-8× padded
     scan work on every query batch."""
     n = codes.shape[0]
-    npad = _round_up(n, TB) if n > TB else _round_up(max(n, TN), TN)
+    npad = operand_rows(n)
     return _pack(codes, cont01, num_bins, npad, True, _PADC), n
 
 
@@ -448,22 +448,17 @@ def _pack_queries_dev(codes: jax.Array, cont01: jax.Array, num_bins: int,
     return mat.astype(jnp.bfloat16)
 
 
-@functools.partial(jax.jit, static_argnames=("num_bins", "rows", "extra_norm",
-                                             "k", "kk", "total_attrs", "eps",
-                                             "use_tourney"))
-def _search_fused(codes_q: jax.Array, cont01_q: jax.Array, r_mat: jax.Array,
-                  codes_r: jax.Array, cont01_r: jax.Array, n_real: int,
-                  *, num_bins: int, rows: int, extra_norm: float, k: int,
-                  kk: int, total_attrs: int, eps: float, use_tourney: bool):
-    """One dispatch: pack queries, run the pallas kernel, exact f32 re-rank.
-
-    Returns ([M, k] distances in [0,1], [M, k] ref indices, [M] certificate)
-    for the first ``codes_q.shape[0]`` rows of the padded query block."""
+def fused_candidates(codes_q, cont01_q, r_mat, codes_r, cont01_r, n_real, *,
+                     num_bins: int, rows: int, extra_norm: float, k: int,
+                     kk: int, eps: float, use_tourney: bool):
+    """Pack queries, run the pallas kernel, re-rank its kk candidates in
+    exact f32: (d2s [M, kk] d² ascending, idxs [M, kk], kth [M] = d2s's
+    k-th, limit [M], cand_idx [M, kk]).  No reference outside the candidates
+    is nearer than ``limit``: the kk-th approx candidate and (tournament)
+    every block's third-smallest, less 2·eps of limb error."""
     m = codes_q.shape[0]
     q_mat = _pack_queries_dev(codes_q, cont01_q, num_bins, rows, extra_norm)
-    block2 = use_tourney
-    if block2:
-        # tournament sweep; its third-min bound keeps the cert exact
+    if use_tourney:
         cand_d2, cand_idx, bound3 = _topk_tourney_traced(q_mat, r_mat, kk)
     else:
         cand_d2, cand_idx = _topk_pallas_traced(q_mat, r_mat, kk)
@@ -478,21 +473,26 @@ def _search_fused(codes_q: jax.Array, cont01_q: jax.Array, r_mat: jax.Array,
     d2 = mism + (diff * diff).sum(-1)
     d2 = jnp.where(cand_idx < 0, _BIG, d2)
     neg, order = jax.lax.top_k(-d2, kk)
-    d2s = -neg
-    idxs = jnp.take_along_axis(cand_idx, order, axis=1)
+    d2s, idxs = -neg, jnp.take_along_axis(cand_idx, order, axis=1)
     kth = d2s[:, min(k, kk) - 1]
-    # certificate: nothing outside the candidate set can beat the k-th
-    # exact candidate — non-candidates are ≥ both the kk-th approx
-    # candidate and (block2 path) every block's third-smallest
-    cert = kth <= jnp.minimum(cand_d2[:, -1], bound3) - 2 * eps
-    if not block2:
-        # merge kernel only: a pad in the last slot proves every real ref
-        # was kept (all real d² beat _PADC). On the block2 path a pad in
-        # the pool merely means some block ran short of real rows — blocks
-        # still hide non-candidates, so the bound term must decide.
+    limit = jnp.minimum(cand_d2[:, -1], bound3) - 2 * eps
+    return d2s, idxs, kth, limit, cand_idx
+
+
+@functools.partial(jax.jit, static_argnames=("num_bins", "rows", "extra_norm",
+                                             "k", "kk", "total_attrs", "eps",
+                                             "use_tourney"))
+def _search_fused(*operands, k: int, total_attrs: int, use_tourney: bool,
+                  **statics):
+    """One dispatch → ([M, k] distances in [0,1], [M, k] indices, [M] cert)."""
+    d2s, idxs, kth, limit, cand_idx = fused_candidates(
+        *operands, k=k, use_tourney=use_tourney, **statics)
+    cert = kth <= limit     # nothing outside the candidates beats the k-th
+    if not use_tourney:
+        # merge kernel only: a pad in the last slot proves every real ref was
+        # kept. A tournament's blocks still hide non-candidates: limit decides
         cert = cert | (cand_idx[:, -1] < 0)
-    d = jnp.sqrt(jnp.maximum(d2s[:, :k], 0.0) / max(total_attrs, 1))
-    return jnp.clip(d, 0.0, 1.0), idxs[:, :k], cert
+    return unit_distances(d2s[:, :k], total_attrs), idxs[:, :k], cert
 
 
 def _topk_pallas_traced(a_mat, b_mat, k: int):
@@ -549,17 +549,12 @@ def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
                  margin: int = MARGIN):
     """Single-dispatch exact search. Returns device arrays
     ([M,k] dist, [M,k] idx, [M] cert) — the caller syncs (or pipelines)."""
-    m, f = codes_q.shape
-    fc = cont01_q.shape[1]
-    kk = min(k + margin, SLOTS)
-    eps = D2_EPS if fc else 0.0
-    rows = _round_up(max(m, TM), TM)
     return _search_fused(
         jnp.asarray(codes_q), jnp.asarray(cont01_q, jnp.float32), r_mat,
         codes_r_dev, cont01_r_dev, n_real,
-        num_bins=num_bins, rows=rows, extra_norm=float(f), k=k, kk=kk,
-        total_attrs=total_attrs, eps=eps,
-        use_tourney=tourney_engages(n_real, r_mat.shape[0], k, margin))
+        num_bins=num_bins, total_attrs=total_attrs,
+        use_tourney=tourney_engages(n_real, r_mat.shape[0], k, margin),
+        **fused_statics(*codes_q.shape, cont01_q.shape[1], k, margin))
 
 
 def exact_rerank(cand_idx: np.ndarray, cand_d2: np.ndarray,
@@ -601,3 +596,83 @@ def exact_rerank(cand_idx: np.ndarray, cand_d2: np.ndarray,
     cert |= cand_idx[:, -1] < 0          # fewer refs than k': all seen
     d = np.sqrt(np.maximum(d2s[:, :k], 0.0) / max(total_attrs, 1))
     return np.clip(d, 0.0, 1.0), idxs[:, :k], cert
+
+
+# ---------------------------------------------------------------------------
+# the reference operand packed on the device that holds the rows
+# ---------------------------------------------------------------------------
+# A row-sharded index (models/knn.py::KNNModel.device_sharded) packs every
+# shard where it lives, under shard_map: four one-core host packs in a row
+# would be minutes of set-up at 13 x 2^20 rows a shard.  The operand is the
+# host pack's bit for bit (tests/test_knn_sharded.py), so a shard's search is
+# the one-chip search over the same rows.
+
+def query_rows(m: int) -> int:
+    """Query rows the kernels sweep for a block of ``m``: whole TM-row tiles."""
+    return _round_up(max(m, TM), TM)
+
+
+def fused_statics(m: int, f: int, fc: int, k: int, margin: int = MARGIN
+                  ) -> dict:
+    """The static arguments of ``_search_fused`` that follow from a query
+    block's shape ([m, f] codes, [m, fc] continuous) and ``k``."""
+    return dict(rows=query_rows(m), extra_norm=float(f), k=k,
+                kk=min(k + margin, SLOTS), eps=D2_EPS if fc else 0.0)
+
+
+def unit_distances(d2: jax.Array, total_attrs: int) -> jax.Array:
+    """Squared distances summed over the attributes → distances in [0, 1]."""
+    return jnp.clip(jnp.sqrt(jnp.maximum(d2, 0.0) / max(total_attrs, 1)),
+                    0.0, 1.0)
+
+
+def operand_rows(n: int) -> int:
+    """Rows of the packed operand of ``n`` references (see prepare_refs)."""
+    return _round_up(n, TB) if n > TB else _round_up(max(n, TN), TN)
+
+
+def pack_refs_dev(codes: jax.Array, cont01: jax.Array, norm: jax.Array,
+                  n_real: jax.Array, num_bins: int) -> jax.Array:
+    """Device-side equivalent of ``_pack(..., is_ref=True)``: the packed
+    ``[operand_rows(n), W]`` bf16 operand of the first ``n_real`` of the
+    ``n`` rows given (the rest are pad rows, as every row past ``n``).
+    ``norm`` is each row's squared norm as the host pack computes it (summed
+    in float64, then f32).  Built a chunk of rows at a time into the
+    operand's own buffer: the f32 staging of a whole 13 x 2^20-row shard
+    would be 7 GB."""
+    n, f = codes.shape
+    fc = cont01.shape[1]
+    rows, width = operand_rows(n), _width(f, num_bins, fc)
+    chunk = int(np.gcd(rows, 1 << 17))
+    pad = ((0, rows - n), (0, 0))
+    codes, cont01 = jnp.pad(codes, pad), jnp.pad(cont01, pad)
+    norm = jnp.pad(norm, (0, rows - n))
+
+    def body(c, out):
+        at = c * chunk
+        real = (at + jnp.arange(chunk, dtype=jnp.int32) < n_real)[:, None]
+        parts = []
+        if f:
+            blk = jax.lax.dynamic_slice(codes, (at, 0), (chunk, f))
+            onehot = (blk[:, :, None] ==
+                      jnp.arange(num_bins, dtype=blk.dtype)) & real[:, :, None]
+            parts.append(-onehot.astype(jnp.float32).reshape(chunk,
+                                                             f * num_bins))
+        if fc:
+            hi, lo, lo2 = _limbs_dev(jnp.where(
+                real, jax.lax.dynamic_slice(cont01, (at, 0), (chunk, fc)),
+                0.0))
+            parts.extend(-2.0 * g for g in (hi, lo, hi, lo, lo2, hi))
+        colc = jnp.where(real[:, 0],
+                         jax.lax.dynamic_slice(norm, (at,), (chunk,)), _PADC)
+        ones = jnp.ones((chunk,), jnp.float32)
+        parts.append(jnp.stack(
+            [-2.0 * limb for limb in _limbs_dev(-0.5 * colc)]
+            + [ones, ones, ones], axis=1))
+        mat = jnp.concatenate(parts, axis=1)
+        mat = jnp.pad(mat, ((0, 0), (0, width - mat.shape[1])))
+        return jax.lax.dynamic_update_slice(out, mat.astype(jnp.bfloat16),
+                                            (at, 0))
+
+    return jax.lax.fori_loop(0, rows // chunk, body,
+                             jnp.zeros((rows, width), jnp.bfloat16))

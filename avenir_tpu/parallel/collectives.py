@@ -157,6 +157,109 @@ def sharded_knn_topk(mesh: Mesh, k: int, num_bins: int,
     return jax.jit(wrapped)
 
 
+def merge_shard_topk(d2: jax.Array, i: jax.Array, limit: jax.Array, k: int,
+                     data_axis: str = "data"):
+    """Inside a ``shard_map``: every shard's local top-k (``d2`` [M, k] exact
+    squared distances ascending, ``i`` [M, k] GLOBAL reference indices, −1 =
+    no reference) and its ``limit`` [M] — no reference of the shard outside
+    what it returned, or outranked among it, is nearer — → the global top-k
+    and its certificate, the same on every device.  ONE tiled ``all_gather``
+    of an int32 payload (distances and limit bit-cast, the indices: (2k +
+    1)·D words a query cross ICI) and one ``lax.top_k`` over the [M, D·k]
+    candidates.  A row is exact where its merged k-th distance is within
+    EVERY shard's limit (implied by each shard's own certificate, and weaker:
+    the merged k-th is no farther than any shard's own k-th).  Returns (d2
+    [M, k], i [M, k], certificate [M], rows each shard's limit refused [D]).
+    Ties keep shard order, then the shard's own order: the order of a search
+    over the concatenated rows."""
+    shards = jax.lax.axis_size(data_axis)
+    bits = functools.partial(jax.lax.bitcast_convert_type,
+                             new_dtype=jnp.int32)
+    payload = jnp.concatenate([bits(d2), i, bits(limit)[:, None]], axis=1)
+    g = jax.lax.all_gather(payload, data_axis, axis=1, tiled=True)
+    g = g.reshape(d2.shape[0], shards, 2 * k + 1)
+    ig = g[:, :, k:2 * k].reshape(-1, shards * k)
+    dg = jax.lax.bitcast_convert_type(g[:, :, :k], jnp.float32)
+    # a slot with no reference behind it must lose to every real one
+    dg = jnp.where(ig < 0, jnp.inf, dg.reshape(-1, shards * k))
+    neg, pos = jax.lax.top_k(-dg, k)
+    within = -neg[:, -1:] <= jax.lax.bitcast_convert_type(g[:, :, 2 * k],
+                                                          jnp.float32)
+    return (-neg, jnp.take_along_axis(ig, pos, axis=1), within.all(axis=1),
+            (~within).sum(axis=0, dtype=jnp.int32))
+
+
+@functools.lru_cache(maxsize=32)
+def sharded_knn_fused(mesh: Mesh, shard_rows: int, k: int, kk: int,
+                      num_bins: int, rows: int, extra_norm: float,
+                      total_attrs: int, eps: float, use_tourney: bool,
+                      data_axis: str = "data"):
+    """The fused search's candidates (ops/pallas_knn.py::fused_candidates:
+    query pack → candidate kernel → exact f32 re-rank, and the limit below
+    which the shard hides nothing) on every shard of a row-sharded index,
+    merged and certified by :func:`merge_shard_topk` — one compiled program
+    a (query rows, k) shape.
+
+    Shard ``s`` holds reference rows [s·shard_rows, (s+1)·shard_rows) of the
+    concatenated set: its packed operand, and the codes and normalised
+    coordinates the re-rank gathers from (KNNModel.device_sharded).  Its own
+    real-row count, min(n − s·shard_rows, shard_rows), masks its pad rows;
+    local indices leave the shard as global ones.  Queries are replicated.
+
+    Returns a jitted fn(codes_q, cont01_q, r_mat, codes_r, cont01_r, n) →
+    ([M, k] distances, [M, k] global indices, [M] certificate, [D] rows
+    each shard's limit refused), all replicated.  The caller sends the rows
+    without a certificate to :func:`sharded_knn_topk`."""
+    from avenir_tpu.ops import pallas_knn
+
+    def _shard_search(codes_q, cont01_q, r_mat, codes_r, cont01_r, n):
+        base = jax.lax.axis_index(data_axis) * shard_rows
+        d2s, idxs, _kth, limit, cand_idx = pallas_knn.fused_candidates(
+            codes_q, cont01_q, r_mat, codes_r, cont01_r,
+            jnp.clip(n - base, 0, shard_rows), num_bins=num_bins, rows=rows,
+            extra_norm=extra_norm, k=k, kk=kk, eps=eps,
+            use_tourney=use_tourney)
+        if not use_tourney:
+            # merge kernel: a pad in its last slot proves the shard kept
+            # every real row, so it hides nothing (as in _search_fused)
+            limit = jnp.where(cand_idx[:, -1] < 0, jnp.inf, limit)
+        i = idxs[:, :k]
+        d2, gi, cert, refused = merge_shard_topk(
+            d2s[:, :k], jnp.where(i < 0, -1, i + base), limit, k, data_axis)
+        return (pallas_knn.unit_distances(d2, total_attrs), gi, cert,
+                refused)
+
+    by_rows = P(data_axis, None)
+    # norep: pallas_call outputs carry no varying-mesh-axis metadata, and
+    # the merged outputs are replicated by construction
+    return jax.jit(_shard_map_norep(
+        _shard_search, mesh, (P(), P(), by_rows, by_rows, by_rows, P()),
+        (P(), P(), P(), P())))
+
+
+@functools.lru_cache(maxsize=32)
+def sharded_knn_pack(mesh: Mesh, num_bins: int, data_axis: str = "data"):
+    """The fused search's packed reference operand, built by every device
+    from the rows it holds (ops/pallas_knn.py::pack_refs_dev).
+
+    Returns a jitted fn(codes, cont01, norm, n) — row-sharded [D·R, ·]
+    arrays and the real row count of the whole set — → the [D·operand_rows(R),
+    W] bf16 operand, row-sharded the same way."""
+    from avenir_tpu.ops import pallas_knn
+
+    def _shard_pack(codes, cont01, norm, n):
+        local = cont01.shape[0]
+        base = jax.lax.axis_index(data_axis) * local
+        return pallas_knn.pack_refs_dev(
+            codes, cont01, norm, jnp.clip(n - base, 0, local), num_bins)
+
+    by_rows = P(data_axis, None)
+    # norep: the loop's zero-filled carry is not marked as varying over the
+    # mesh, the chunks written into it are
+    return jax.jit(_shard_map_norep(
+        _shard_pack, mesh, (by_rows, by_rows, P(data_axis), P()), by_rows))
+
+
 def sharded_lr_step(mesh: Mesh, data_axis: str = "data"):
     """Data-parallel logistic-regression step: per-device partial gradient
     (the reference's per-mapper Σ x·(y−σ(wᵀx)) accumulation,

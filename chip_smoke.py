@@ -3,7 +3,8 @@
 
     python chip_smoke.py             one chip: every phase below
     python chip_smoke.py --chips 4   four chips: the sharded fused pipeline
-                                     against the unsharded one, nothing else
+                                     against the unsharded one and the
+                                     row-sharded kNN index, nothing else
 
 Drives the system the way a user does — ``python -m avenir_tpu <Job>``,
 ``python -m avenir_tpu.pipeline run`` and ``python -m avenir_tpu.serving`` as
@@ -870,6 +871,63 @@ print(json.dumps({"path": folder.step, "mesh": dict(spec.mesh.shape),
                   "all_reduce": text.count("all-reduce")}))
 """
 
+# the kNN index row-sharded over the four chips: the fused certified search on
+# every shard + one all-gather merge, against a numpy brute force in float64
+SHARD_KNN = r"""
+import json, time
+import numpy as np
+import jax.numpy as jnp
+from avenir_tpu.core.encoding import EncodedDataset
+from avenir_tpu.models import knn as mknn
+from avenir_tpu.ops import pallas_knn
+from avenir_tpu.parallel import collectives
+from avenir_tpu.parallel.mesh import make_mesh
+
+REFS, QUERIES, K, ATTRS = %(refs)d, %(queries)d, %(k)d, 9
+rng = np.random.default_rng(%(seed)d)
+
+
+def signals(n):
+    return EncodedDataset(
+        codes=np.zeros((n, 0), np.int32),
+        cont=rng.integers(0, 400, size=(n, ATTRS)).astype(np.float32),
+        labels=rng.integers(0, 2, size=n).astype(np.int32), ids=None,
+        n_bins=np.zeros(0, np.int32), class_values=["P", "F"],
+        binned_ordinals=[], cont_ordinals=list(range(1, ATTRS + 1)))
+
+
+refs, queries = signals(REFS), signals(QUERIES)
+mesh = make_mesh(("data",))
+route = mknn.sharded_route(mesh, "euclidean", K, REFS)
+model = mknn.fit_knn(refs)
+t0 = time.monotonic()
+dist, idx = mknn.nearest_neighbors(model, queries, K, mesh=mesh)
+wall = time.monotonic() - t0
+q01 = mknn._normalize01(queries.cont, model.cont_lo, model.cont_hi)
+r = model.cont01().astype(np.float64)
+rr = (r * r).sum(1)
+checked = np.arange(0, QUERIES, 8)       # the brute force is the slow part
+q = q01[checked].astype(np.float64)
+d2 = np.maximum((q * q).sum(1)[:, None] + rr[None, :] - 2.0 * q @ r.T, 0)
+want = np.sqrt(np.sort(np.partition(d2, K, axis=1)[:, :K], axis=1) / ATTRS)
+gap = float(np.abs(dist[checked] - want).max())
+r_mat, codes_s, cont01_s, shard = model.sharded_index(mesh)
+step = collectives.sharded_knn_fused(
+    mesh, shard, num_bins=1, total_attrs=ATTRS, use_tourney=True,
+    **pallas_knn.fused_statics(QUERIES, 0, ATTRS, K))
+text = step.lower(jnp.asarray(queries.codes), jnp.asarray(q01), r_mat,
+                  codes_s, cont01_s, jnp.int32(REFS)).compile().as_text()
+print(json.dumps({
+    "route": route, "mesh": dict(mesh.shape), "shard_rows": shard,
+    "wall_s": wall, "gap": gap, "checked": len(checked), "in_range": bool(
+        ((idx >= 0) & (idx < REFS)).all()),
+    "fused": model.fused_rows, "tournament": model.tourney_rows,
+    "shard_fused": model.shard_fused_rows,
+    "refused": model.cert_fallback_rows,
+    "tpu_custom_call": text.count("tpu_custom_call"),
+    "all_gather": text.count("all-gather")}))
+"""
+
 
 def phase_four_chips(dev, ref):
     # unsharded = ONE chip of the four takes the kernel path (the auto
@@ -934,6 +992,39 @@ def phase_four_chips(dev, ref):
         + " -> every device held and worked its shard")
 
 
+def phase_four_chips_knn():
+    """The sharded fused kNN search over 4 x KNN_REFS references: every
+    query's distances against the brute force, the route counted, and the
+    merged program's compiled text holding the kernel AND the collective."""
+    out, wall = run_child("shard.knn", ["-c", SHARD_KNN % {
+        "refs": 4 * KNN_REFS, "queries": KNN_QUERIES, "k": KNN_K,
+        "seed": SEED + 300}])
+    got = json.loads(out.strip().splitlines()[-1])
+    say(f"sharded kNN ({wall:.1f}s): {4 * KNN_REFS} refs over mesh "
+        f"{got['mesh']} ({got['shard_rows']} rows a shard) x {KNN_QUERIES} "
+        f"queries, k={KNN_K}: route {got['route']}, {got['shard_fused']} "
+        f"rows through the sharded fused search ({got['tournament']} with "
+        f"the tournament kernel), {got['refused']} refused by a shard and "
+        f"rescanned; widest distance gap to the float64 brute force over "
+        f"{got['checked']} of the queries {got['gap']:.2e}; first search {got['wall_s']:.1f}s (placement "
+        f"and compile included); compiled text holds "
+        f"{got['tpu_custom_call']} tpu_custom_call and "
+        f"{got['all_gather']} all-gather")
+    if got["route"] != "sharded_fused" or not got["in_range"] or \
+            got["fused"] != KNN_QUERIES or \
+            got["shard_fused"] != KNN_QUERIES or \
+            got["tournament"] != KNN_QUERIES or \
+            got["refused"] > KNN_QUERIES // 2:
+        raise RuntimeError(f"sharded kNN did not take the sharded fused "
+                           f"route for every row: {got}")
+    if got["gap"] > 5e-6:     # the exact scan of refused rows reads ~2e-6
+        raise RuntimeError(f"sharded kNN distances differ from the brute "
+                           f"force by {got['gap']}")
+    if got["tpu_custom_call"] < 1 or got["all_gather"] < 1:
+        raise RuntimeError(f"merged program is not the compiled kernel + "
+                           f"all-gather: {got}")
+
+
 # ---------------------------------------------------------------------------
 
 def main():
@@ -952,6 +1043,7 @@ def main():
     say(f"numpy parse of hosp.csv {time.monotonic() - t0:.1f}s")
     if chips == 4:
         phase_four_chips(dev, ref)
+        phase_four_chips_knn()
     else:
         datagen("retarget", SEED + 100, "", [("retarget.csv", TREE_ROWS)])
         datagen("elearn", SEED + 200, "U%08d",

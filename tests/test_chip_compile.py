@@ -145,3 +145,40 @@ def test_sharded_scan_step_compiles_for_four_v5e_chips(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def test_sharded_fused_knn_compiles_for_four_v5e_chips(topo):
+    """The benchmark's four-chip cell (perfbench/configs/elearn_knn_x4.json):
+    13 x 2^20 elearn-shaped references a shard x 4096 queries through the
+    whole sharded program — the fused search on every shard and the
+    all-gather merge — and the program that packs a shard's operand on the
+    chip that holds it, inside one chip's memory."""
+    from avenir_tpu.ops import pallas_knn as pk
+    from avenir_tpu.parallel import collectives
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    shard, m, fc, k = 13 << 20, 4096, 9, 10
+    n = mesh.shape["data"] * shard
+    whole = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("data", None))
+    index = (_shape((n, 0), jnp.int32, rows),
+             _shape((n, fc), jnp.float32, rows))
+    compiled = collectives.sharded_knn_fused(
+        mesh, shard, num_bins=1, total_attrs=fc, use_tourney=True,
+        **pk.fused_statics(m, 0, fc, k)).lower(
+            _shape((m, 0), jnp.int32, whole),
+            _shape((m, fc), jnp.float32, whole),
+            _shape((n, pk._width(0, 1, fc)), jnp.bfloat16, rows), *index,
+            _shape((), jnp.int32, whole)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    assert text.count("reduce-precision(") >= 6
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
+    packed = collectives.sharded_knn_pack(mesh, 1).lower(
+        *index, _shape((n,), jnp.float32, NamedSharding(mesh, P("data"))),
+        _shape((), jnp.int32, whole)).compile()
+    assert "all-" not in packed.as_text()       # every shard packs alone
+    mem = packed.memory_analysis()
+    assert mem.output_size_in_bytes == shard * 128 * 2      # bf16, one shard
+    assert mem.temp_size_in_bytes < 1e9
