@@ -17,7 +17,7 @@ one bf16 pass per tile:
   columns. Reference pad rows bake a huge finite norm term (never ±inf: a
   zero padding lane times inf is NaN, and NaN poisons every compare).
 - At scale the candidate kernel is the round-3 SEGMENT KEY-TOURNAMENT
-  sweep (see its section below): int32 packed sort keys + lane-halving
+  sweep (see its section below): int32 packed sort keys + a tournament of
   min/max merges, per-2048-ref-segment top-2 + truncated third-min bound.
   The merge-loop kernel in this section remains the small-reference-set
   path (too few segments to fill the candidate pool): a running per-row
@@ -251,105 +251,137 @@ def topk_candidates(q_mat, r_mat, k: int, margin: int = MARGIN
 # ---------------------------------------------------------------------------
 # segmented key-tournament sweep — the candidate kernel at scale
 # ---------------------------------------------------------------------------
-# Why (round-3 bisection; 2026-07 record at 4096 × 1M refs on an
-# older stack: 22.1 ms/call vs 42.1 for the top-2 sweep): the
-# dot reaches the bare-XLA matmul bound once the ref block is 16K rows
-# (vmem_limit_bytes admits it; the 16 MiB default refuses packed widths
-# ≥ 256), f32 min-reductions cost ~3× int32 ones, and an equality-masked
-# extraction pass is a materialized full-array traversal. The kernel
-#   - packs each distance into ONE int32 sort key,
-#     (bitcast(max(d2,0)) & ~(SEG-1)) | col — positive-float bitcast is
-#     order-preserving, so min-of-key IS argmin and the column rides in the
-#     low 11 bits;
-#   - takes each 2048-ref segment's smallest two keys, plus the third as
-#     the non-candidate bound, by a lane-halving TOURNAMENT of sorted
-#     (m1,m2,m3) triples: min/max merges only, no data-dependent control;
-#   - streams refs in 16K-row blocks (8 segments a DMA);
-#   - deposits a step's 8 × 3 results into ONE resident [TM, 128] column
-#     block per output. Until PR 28 that block was the whole
-#     [TM, refs/2048] row, re-selected on every deposit: O(refs) a step,
-#     423 of 572 ms a sweep at 13×2^20 refs (docs/architecture.md).
+# Each distance becomes ONE int32 sort key,
+# (bitcast(max(d2,0)) & ~(SEG-1)) | col — positive-float bitcast is
+# order-preserving, so min-of-key IS argmin and the column rides in the low
+# 11 bits — and every 2048-ref segment yields its smallest two keys, plus
+# the third as the non-candidate bound, by a TOURNAMENT of sorted (m1,m2,m3)
+# triples: min/max merges only, no data-dependent control.  Refs stream in
+# 16K-row blocks (8 segments a DMA; vmem_limit_bytes admits the block at
+# packed widths >= 256, which the 16 MiB default refuses).
 # Exact: true top-k ⊆ candidates unless a segment hides ≥3 of it; key
 # truncation only LOWERS a segment's bound (≤ 2⁻¹² relative): sound.
+#
+# A grid step is bound by its dot: 8192 result vregs popped from four MXUs,
+# ~16.4k cycles on a v5e.  The tournament hides beside it (a step is ~16.9k
+# bundles by the compiler's own count; PERF.md and docs/architecture.md
+# have the measured decomposition) because of three choices, each of which
+# costs the overlap if undone:
+#   - the keys are merged as FLOATS.  The v5e vector unit has no int32
+#     min/max (a compare AND a select), vmin/vmax.f32 are one operation.
+#     key + 2^23 (one exponent step, folded into the column constant) is a
+#     normal positive float whose order is the key's; the bias comes off
+#     the 8 x 3 results of a step;
+#   - the QUERY tile is the stationary MXU operand and the references
+#     stream, so a result vreg is [8 refs, 128 queries] and a query group's
+#     running triple is three vregs.  The other way round a [512, 128]
+#     column block is 64 vregs, the whole register file, and every tree
+#     level goes through VMEM on the one store slot;
+#   - the tree pairs 8-row groups that leave the MXU next to each other, so
+#     a result is merged out of registers while the MXU delivers the next
+#     (pairing row v with row v + SEG/2 keeps half a segment in flight).
+# The outputs come out [refs/2048, M]; _tourney_keys transposes them, which
+# XLA turns into a layout of the consumers, not a copy.
 
 TB = 16384             # reference rows per grid step (one DMA, 8 segments)
 SEG = 2048             # certificate granularity: top-2 + third-min bound
 # pad-lane key: the int32 bit pattern of _BIG (finite; NEVER 0x7fffffff,
 # whose truncated bitcast is NaN and would poison every downstream min)
 _PAD_KEY = int(np.float32(_BIG).view(np.int32))
-_COL_STEPS = 128 // (TB // SEG)    # grid steps per 128-lane output block
+# added to every key before it is compared as a float: the exponent field
+# of a key of d2 < 2^-126 (every clamped d2) is 0, a denormal, which the
+# vector unit may flush; one exponent step up every key is a normal float
+# and the order of the keys is unchanged
+_KEY_BIAS = 1 << 23
+
+
+def _merge_triples(a, b):
+    """Sorted triples (a1<=a2<=a3), (b1<=b2<=b3) of disjoint key sets ->
+    the sorted three smallest of their union, elementwise.  Seven
+    operations: max(a2, b2) is never among the three."""
+    a1, a2, a3 = a
+    b1, b2, b3 = b
+    hi1 = jnp.maximum(a1, b1)
+    lo2 = jnp.minimum(a2, b2)
+    return (jnp.minimum(a1, b1), jnp.minimum(hi1, lo2),
+            jnp.minimum(jnp.maximum(hi1, lo2), jnp.minimum(a3, b3)))
+
+
+def _segment_keys(d2t):
+    """d2ᵀ of one segment, [SEG refs, queries] f32 -> its biased sort keys,
+    bitcast to f32 (see ``_KEY_BIAS``)."""
+    col = (jax.lax.broadcasted_iota(jnp.int32, d2t.shape, 0)
+           + jnp.int32(_KEY_BIAS))
+    # max(d2, 0): the limb-split dot can go ~eps negative for near-identical
+    # points; negative-float bitcast would invert the int ordering
+    di = jax.lax.bitcast_convert_type(jnp.maximum(d2t, 0.0), jnp.int32)
+    # the low 11 bits are clear after the mask: + col is | col
+    return jax.lax.bitcast_convert_type(
+        (di & jnp.int32(~(SEG - 1))) + col, jnp.float32)
+
+
+def _rows_top3(key):
+    """[rows, queries] keys -> the sorted triple of each column's three
+    smallest among the rows of each residue mod 8: three [8, queries]
+    arrays (a sublane a residue)."""
+    # every reshape splits or merges leading dims of whole [8, 128] tiles:
+    # no data moves
+    n, q = key.shape[0] // 16, key.shape[1]
+    # round 1: the two 8-row groups of a 16-row MXU push -> sorted pairs
+    r = key.reshape(n, 16, q)
+    lo, hi = r[:, :8], r[:, 8:]
+    m1 = jnp.minimum(lo, hi).reshape(n // 2, 2, 8, q)
+    m2 = jnp.maximum(lo, hi).reshape(n // 2, 2, 8, q)
+    # round 2: two neighbouring sorted pairs -> sorted triple of 4
+    hi1 = jnp.maximum(m1[:, 0], m1[:, 1])
+    lo2 = jnp.minimum(m2[:, 0], m2[:, 1])
+    tri = (jnp.minimum(m1[:, 0], m1[:, 1]), jnp.minimum(hi1, lo2),
+           jnp.maximum(hi1, lo2))
+    # then neighbouring triples merge, down to one
+    n //= 2
+    while n > 1:
+        n //= 2
+        halves = [t.reshape(n, 2, 8, q) for t in tri]
+        tri = _merge_triples([h[:, 0] for h in halves],
+                             [h[:, 1] for h in halves])
+    return [t[0] for t in tri]
+
+
+def _sublanes_top3(tri):
+    """A sorted [8, queries] triple of eight disjoint key sets, a sublane a
+    set -> the triple of their union, in every sublane: after rotations by
+    4, 2 and 1 each sublane has met all eight."""
+    for shift in (4, 2, 1):
+        tri = _merge_triples(tri, [pltpu.roll(t, shift, 0) for t in tri])
+    return tri
 
 
 def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out):
-    j = pl.program_id(1)
     nseg = TB // SEG
-    # the _COL_STEPS grid steps that share an output block fill its lanes; the
-    # first pins all to the pad key, which lanes past the last segment keep
-    @pl.when(j % _COL_STEPS == 0)
-    def _init():
-        for out in (k1_out, k2_out, k3_out):
-            out[:] = jnp.full((TM, 128), _PAD_KEY, jnp.int32)
-
-    d2v = jax.lax.dot_general(
-        a_ref[:], b_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (TM, TB), 1)
-    col = lane & jnp.int32(SEG - 1)
-    # max(d2, 0): the limb-split dot can go ~eps negative for near-identical
-    # points; negative-float bitcast would invert the int ordering
-    di = jax.lax.bitcast_convert_type(jnp.maximum(d2v, 0.0), jnp.int32)
-    key = (di & jnp.int32(~(SEG - 1))) | col
-    # output-block lane, counted from this step's first segment
-    outlane = (jax.lax.broadcasted_iota(jnp.int32, (TM, 128), 1)
-               - (j % _COL_STEPS) * nseg)
+    a = a_ref[:]
+    seg_row = jax.lax.broadcasted_iota(jnp.int32, (nseg, TM), 0)
+    outs = [jnp.zeros((nseg, TM), jnp.float32)] * 3
     for s in range(nseg):
-        seg = key[:, s * SEG:(s + 1) * SEG]
-        # round 1: adjacent halves -> sorted pairs
-        w = SEG // 2
-        a, b = seg[:, :w], seg[:, w:]
-        m1 = jnp.minimum(a, b)
-        m2 = jnp.maximum(a, b)
-        # round 2: two sorted pairs -> sorted triple of 4
-        w //= 2
-        a1, b1 = m1[:, :w], m1[:, w:]
-        a2, b2 = m2[:, :w], m2[:, w:]
-        hi1 = jnp.maximum(a1, b1)
-        lo2 = jnp.minimum(a2, b2)
-        m1 = jnp.minimum(a1, b1)
-        m2 = jnp.minimum(hi1, lo2)
-        m3 = jnp.maximum(lo2, hi1)
-        # sorted-triple merges down to 128 lanes
-        while w > 128:
-            w //= 2
-            a1, b1 = m1[:, :w], m1[:, w:]
-            a2, b2 = m2[:, :w], m2[:, w:]
-            a3, b3 = m3[:, :w], m3[:, w:]
-            hi1 = jnp.maximum(a1, b1)
-            lo2 = jnp.minimum(a2, b2)
-            hi2 = jnp.maximum(a2, b2)
-            m1 = jnp.minimum(a1, b1)
-            m2 = jnp.minimum(hi1, lo2)
-            m3 = jnp.minimum(jnp.minimum(jnp.maximum(hi1, lo2), hi2),
-                             jnp.minimum(a3, b3))
-        # final 128 -> 1 by masked extraction on the tiny arrays; keys are
-        # unique (distinct col bits), so each mask hits exactly one lane
-        t1 = jnp.min(m1, axis=1)
-        em = jnp.where(m1 == t1[:, None], m2, m1)
-        t2 = jnp.min(em, axis=1)
-        em2 = jnp.where(em == t2[:, None],
-                        jnp.where(m1 == t1[:, None], m3, m2), em)
-        t3 = jnp.min(em2, axis=1)
-        for out, t in ((k1_out, t1), (k2_out, t2), (k3_out, t3)):
-            out[:] = jnp.where(outlane == s, t[:, None], out[:])
+        # d2ᵀ of one segment, [refs, queries]: the one bf16 MXU pass (the −2
+        # of the norm expansion is folded into the reference operand)
+        d2t = jax.lax.dot_general(
+            b_ref[s * SEG:(s + 1) * SEG, :], a, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        tri = _sublanes_top3(_rows_top3(_segment_keys(d2t)))
+        outs = [jnp.where(seg_row == s, t, o) for t, o in zip(tri, outs)]
+    for out, o in zip((k1_out, k2_out, k3_out), outs):
+        out[:] = (jax.lax.bitcast_convert_type(o, jnp.int32)
+                  - jnp.int32(_KEY_BIAS))
 
 
 def _tourney_keys(a_mat, b_mat):
     """The sweep's raw output: three int32 arrays, a lane a segment (rounded
     up to 128s): its three smallest keys, ``_PAD_KEY`` past the last one."""
     m, n = a_mat.shape[0], b_mat.shape[0]
-    spec = pl.BlockSpec((TM, 128), lambda i, j: (i, j // _COL_STEPS),
+    nseg = n // SEG
+    spec = pl.BlockSpec((TB // SEG, TM), lambda i, j: (j, i),
                         memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    keys = pl.pallas_call(
         _knn_tourney_kernel,
         grid=(m // TM, n // TB),
         in_specs=[
@@ -359,12 +391,14 @@ def _tourney_keys(a_mat, b_mat):
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct(
-            (m, _round_up(n // SEG, 128)), jnp.int32)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((nseg, m), jnp.int32)] * 3,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024),
     )(a_mat, b_mat)
+    # the kernel's rows are segments: a lane a segment is their transpose
+    return [jnp.pad(kk_.T, ((0, 0), (0, _round_up(nseg, 128) - nseg)),
+                    constant_values=_PAD_KEY) for kk_ in keys]
 
 
 def _topk_tourney_traced(a_mat, b_mat, k: int):

@@ -96,30 +96,37 @@ def test_shared_scan_gram_moments_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n,use_tourney", [
-    (1_000_000, True),
-    (1_000_000, False),
-    (13 << 20, True),           # the benchmark's cells (perfbench/configs)
-    (1 << 24, True),            # refused until PR 28: 104.25M of scoped VMEM
-], ids=["tournament", "merge", "tournament-13Mi", "tournament-16Mi"])
-def test_knn_fused_search_compiles_for_v5e(one_chip, n, use_tourney):
-    """elearn-shaped references (9 continuous attributes, packed width 128)
-    x 4096 queries through the whole fused search program."""
+@pytest.mark.parametrize("n,use_tourney,f,bins,fc", [
+    (1_000_000, True, 0, 1, 9),
+    (1_000_000, False, 0, 1, 9),
+    (13 << 20, True, 0, 1, 9),      # the benchmark's cells (perfbench/configs)
+    (1 << 24, True, 0, 1, 9),       # refused until PR 28: 104.25M of scoped VMEM
+    # a wide schema, packed width 256: two MXU passes a segment and a
+    # reference block of 16384 x 256 bf16, which the kernel's
+    # vmem_limit_bytes has to go on admitting
+    (1 << 20, True, 6, 32, 8),
+], ids=["tournament", "merge", "tournament-13Mi", "tournament-16Mi",
+        "tournament-wide256"])
+def test_knn_fused_search_compiles_for_v5e(one_chip, n, use_tourney, f, bins,
+                                           fc):
+    """elearn-shaped references (9 continuous attributes, packed width 128),
+    and one schema with categorical attributes, x 4096 queries through the
+    whole fused search program."""
     from avenir_tpu.ops import pallas_knn as pk
 
-    m, fc, k = 4096, 9, 10
+    m, k = 4096, 10
     npad = pk._round_up(n, pk.TB)
-    width = pk._width(0, 1, fc)
-    assert width == 128 and npad % pk.TN == 0
+    width = pk._width(f, bins, fc)
+    assert width == (256 if f else 128) and npad % pk.TN == 0
     compiled = pk._search_fused.lower(
-        _shape((m, 0), jnp.int32, one_chip),
+        _shape((m, f), jnp.int32, one_chip),
         _shape((m, fc), jnp.float32, one_chip),
         _shape((npad, width), jnp.bfloat16, one_chip),
-        _shape((n, 0), jnp.int32, one_chip),
+        _shape((n, f), jnp.int32, one_chip),
         _shape((n, fc), jnp.float32, one_chip),
         _shape((), jnp.int32, one_chip),
-        num_bins=1, rows=m, extra_norm=0.0, k=k, kk=k + pk.MARGIN,
-        total_attrs=fc, eps=pk.D2_EPS, use_tourney=use_tourney).compile()
+        num_bins=bins, rows=m, extra_norm=float(f), k=k, kk=k + pk.MARGIN,
+        total_attrs=f + fc, eps=pk.D2_EPS, use_tourney=use_tourney).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the query pack's bf16 limb split must reach the chip as roundings the

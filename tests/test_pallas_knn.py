@@ -229,6 +229,82 @@ def test_tourney_keys_match_sorted_segments(rng, nblocks):
         assert (g[:, nseg:] == pk._PAD_KEY).all()
 
 
+# positions within a 2048-reference segment whose distances are planted
+# below everything else, and the multiples (hi, lo) of the query's scale they
+# get.  The kernel merges the keys of a segment in the order the MXU delivers
+# them — 8-row groups, the two halves of a 16-row push, neighbouring pushes,
+# and last the 8 sublanes — so each case puts the three smallest where one
+# of those stages has to keep or to order them.
+_PLANTED = {
+    # all three in one 128-reference column block
+    "one_column_block": [(5 * 128 + 3, 1.0, 0), (5 * 128 + 64, 1.5, 0),
+                         (5 * 128 + 100, 1.25, 0)],
+    # first, a middle and the last column block: arrival order != key order
+    "first_middle_last": [(7, 1.5, 0), (1024 + 13, 1.0, 0), (2047, 1.25, 0)],
+    # one 8-row group: three sublanes of one result vreg
+    "one_row_group": [(16, 1.25, 0), (19, 1.5, 0), (23, 1.0, 0)],
+    # one sublane (rows = 0 mod 8) of three far-apart row groups
+    "one_sublane": [(8, 1.5, 0), (8 + 16 * 5, 1.0, 0), (8 + 1024, 1.25, 0)],
+    # both halves of one 16-row push
+    "push_halves": [(32, 1.25, 0), (40, 1.0, 0), (33, 1.5, 0)],
+    # six distances equal after the key's truncation (1 + j * 2^-20 all
+    # truncate to 1.0): distinct only by column bits, smallest columns win
+    "equal_truncated": [(2000, 1.0, 1 * 2.0 ** -20), (5, 1.0, 6 * 2.0 ** -20),
+                        (900, 1.0, 2 * 2.0 ** -20), (64, 1.0, 5 * 2.0 ** -20),
+                        (1300, 1.0, 3 * 2.0 ** -20), (129, 1.0, 4 * 2.0 ** -20)],
+    # d2 <= 0: the clamp max(d2, 0) catches four of them and the exact zero
+    # needs none; all five keys are the bare column (a denormal, as a float)
+    "clamped": [(1999, -1.0, 0), (3, -0.5, 0), (700, 0.0, 0), (1100, -2.0, 0),
+                (250, -0.25, 0)],
+}
+
+
+@pytest.fixture(scope="module")
+def tourney_keys_jit():
+    import jax
+    return jax.jit(pk._tourney_keys)
+
+
+@pytest.mark.parametrize("case", sorted(_PLANTED))
+@needs_tpu_interpret
+def test_tourney_keys_planted_segments(rng, tourney_keys_jit, case):
+    # two query tiles x two reference blocks (16 segments); d2 = c_q * (hi +
+    # lo) exactly: operand column 0 carries hi, column 1 lo, and a query is
+    # (c_q, c_q, 0, ...) with c_q a power of two.  The two query tiles get
+    # different scales and every segment a rotation of the planted values, so
+    # an output map or transpose that drops the tile or the block index fails.
+    import jax.numpy as jnp
+
+    m, nseg, width = 2 * pk.TM, 2 * pk.TB // pk.SEG, 128
+    n = nseg * pk.SEG
+    hi = 4 + rng.integers(0, 64, size=n).astype(np.float32) / 8
+    lo = np.zeros(n, np.float32)
+    plant = _PLANTED[case]
+    for s in range(nseg):
+        for j, (pos, _, _) in enumerate(plant):
+            _, h, l = plant[(j + s) % len(plant)]
+            hi[s * pk.SEG + pos], lo[s * pk.SEG + pos] = h, l
+    scale = np.where(np.arange(m) < pk.TM, 1.0, 2.0).astype(np.float32)
+    scale[::3] *= 0.5
+    a = np.zeros((m, width), np.float32)
+    b = np.zeros((n, width), np.float32)
+    a[:, 0] = a[:, 1] = scale
+    b[:, 0], b[:, 1] = hi, lo
+    with pltpu.force_tpu_interpret_mode():
+        got = [np.asarray(x) for x in tourney_keys_jit(
+            jnp.asarray(a, jnp.bfloat16), jnp.asarray(b, jnp.bfloat16))]
+    d2 = np.maximum(scale[:, None] * (hi + lo)[None, :], np.float32(0))
+    assert d2.dtype == np.float32
+    key = ((d2.view(np.int32) & np.int32(~(pk.SEG - 1)))
+           | np.tile(np.arange(pk.SEG, dtype=np.int32), nseg))
+    want = np.sort(key.reshape(m, nseg, pk.SEG), axis=2)[:, :, :3]
+    if case in ("equal_truncated", "clamped"):      # the case is what it says
+        assert (want[:, 0, :] >> 11 == want[:, 0, :1] >> 11).all()
+    for j, g in enumerate(got):
+        np.testing.assert_array_equal(g[:, :nseg], want[:, :, j])
+        assert (g[:, nseg:] == pk._PAD_KEY).all()
+
+
 @needs_tpu_interpret
 def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
     # regression: n_real = 8*TN+1 puts one real ref in the last block, so a
