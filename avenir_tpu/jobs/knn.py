@@ -178,8 +178,8 @@ class NearestNeighbor(Job):
         write_output(output_path, out)
         counters.set("Records", "Processed", test_ds.num_rows)
         if model.fused_rows:
-            # which search route answered: the fused Pallas kernel (and
-            # how many rows through its tournament candidate kernel), and
+            # which search route answered: the fused Pallas kernel (all of
+            # them through its one candidate kernel, the tournament), and
             # the rows its exactness certificate sent to the XLA scan
             counters.set("Records", "Search.fused", model.fused_rows)
             counters.set("Records", "Search.tournament", model.tourney_rows)

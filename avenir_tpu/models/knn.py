@@ -71,7 +71,7 @@ class KNNModel:
     # the fused Pallas search answered, and how many of those failed the
     # exactness certificate and were recomputed by the exact XLA scan
     fused_rows: int = 0
-    tourney_rows: int = 0               # of fused_rows: tournament kernel
+    tourney_rows: int = 0               # == fused_rows: one candidate kernel
     cert_fallback_rows: int = 0
     shard_fused_rows: int = 0           # of fused_rows: row-sharded index
 
@@ -289,24 +289,14 @@ def _topk_over_tiles(test_codes, test_cont, ref_codes_t, ref_cont_t, n_real,
 
 
 def _pallas_available(metric: str, k: int) -> bool:
-    if not USE_PALLAS or metric != "euclidean":
-        return False
-    from avenir_tpu.ops import pallas_knn
-    if k + 1 > pallas_knn.SLOTS:
-        return False
+    """Can this process run the fused Pallas search at all: the switch, the
+    metric, the backend.  Whether an index of a given size can take it at
+    ``k`` is ops/pallas_knn.py::fused_serves; the routes ask both (``k``
+    stays in the signature: the benchmark's rehearsal and the tests put
+    their own answer in this function's place)."""
     # the Mosaic kernel lowers on TPU only — never dispatch it on gpu
-    return jax.default_backend() == "tpu"
-
-
-def _count_fused(model: KNNModel, rows: int, tourney: bool, span,
-                 kernel_rows: int) -> None:
-    """One fused search's rows on the route tally, once each whatever the
-    number of shards, and the kernel's swept rows on the ``knn.search`` span
-    (the kernel sweeps whole TM-row query tiles, whatever it was handed)."""
-    model.fused_rows += rows
-    if tourney:
-        model.tourney_rows += rows
-    span.set("kernel_rows", kernel_rows)
+    return (USE_PALLAS and metric == "euclidean"
+            and jax.default_backend() == "tpu")
 
 
 def _rescan_refused(model: KNNModel, test: EncodedDataset, k: int,
@@ -339,39 +329,6 @@ def _rescan_refused(model: KNNModel, test: EncodedDataset, k: int,
     return d, idx
 
 
-def _nearest_neighbors_pallas(model: KNNModel, test: EncodedDataset, k: int,
-                              span=tel.NOOP_SPAN
-                              ) -> Tuple[np.ndarray, np.ndarray]:
-    """Fused-kernel path: ONE jitted dispatch runs query pack → pallas
-    candidate kernel → exact f32 re-rank + per-row exactness certificate
-    (ops/pallas_knn.py::search_fused). Host work per batch is only the raw
-    query transfer and the tiny [M,k] result read-back — the single-core
-    numpy pack/re-rank and the extra device round-trip the previous
-    host-side path paid (~115 ms + ~100 ms per 4096-query batch on the dev
-    rig) are gone.  ``span`` is the caller's ``knn.search`` span: it gets
-    ``kernel_rows`` and ``refused``."""
-    from avenir_tpu.ops import pallas_knn
-    tracer = tel.tracer()
-    nb = int(model.n_bins.max()) if model.n_bins.size else 1
-    r_mat, n = model.device_packed(nb)
-    codes_r_dev, cont01_r_dev = model.device_rerank_arrays()
-    with tracer.span("knn.stage"):
-        # normalise, upload the queries, enqueue the program
-        cont01_q = _normalize01(test.cont, model.cont_lo, model.cont_hi)
-        d_dev, i_dev, cert_dev = pallas_knn.search_fused(
-            test.codes, cont01_q, r_mat, codes_r_dev, cont01_r_dev, n, nb, k,
-            test.codes.shape[1] + test.cont.shape[1])
-    with tracer.span("knn.readback"):
-        d = np.asarray(d_dev)
-        idx = np.asarray(i_dev)
-        cert = np.asarray(cert_dev)
-    _count_fused(model, int(cert.size),
-                 pallas_knn.tourney_engages(n, r_mat.shape[0], k), span,
-                 pallas_knn.query_rows(test.num_rows))
-    return _rescan_refused(model, test, k, d, idx, cert, span,
-                           lambda sub: _nearest_neighbors_xla(model, sub, k))
-
-
 def _shard_rows(n: int, d_par: int) -> int:
     """ceil(n / d_par) — the per-device shard row count; one spelling shared
     by the mesh routing gate and the sharded search path."""
@@ -392,14 +349,16 @@ def sharded_route(mesh, metric: str, k: int, refs: int) -> Optional[str]:
     gate :func:`nearest_neighbors` routes by.  None: the mesh does not shard
     ``data``.  ``"sharded_fused"``: the certified fused Pallas search on
     every shard and one certified all_gather merge — euclidean on a TPU,
-    ``k`` within the kernel's slots and within every shard's real rows.
+    where the fused search can serve the real rows of the shortest (the
+    last) shard at ``k`` (ops/pallas_knn.py::fused_serves).
     ``"sharded_scan"``: the exact XLA tile scan over the row shards —
     everything else, and every row the fused search refuses."""
     if mesh is None or mesh.shape.get("data", 1) < 2:
         return None
+    from avenir_tpu.ops import pallas_knn
     d_par = mesh.shape["data"]
     last = refs - (d_par - 1) * _index_shard_rows(refs, d_par)
-    if _pallas_available(metric, k) and last >= k:
+    if _pallas_available(metric, k) and pallas_knn.fused_serves(last, k):
         return "sharded_fused"
     return "sharded_scan"
 
@@ -471,47 +430,63 @@ def _nearest_neighbors_sharded(model: KNNModel, test: EncodedDataset, k: int,
     return _pad_topk(np.concatenate(out_d), np.concatenate(out_i), k, k_eff)
 
 
-def _nearest_neighbors_sharded_fused(model: KNNModel, test: EncodedDataset,
-                                     k: int, mesh, test_tile: int, span
-                                     ) -> Tuple[np.ndarray, np.ndarray]:
-    """The fused path over a row-sharded index: ONE jitted dispatch runs the
-    fused search of :func:`_nearest_neighbors_pallas` on every shard and
-    merges the shards' top-k (parallel/collectives.py::sharded_knn_fused).
-    A row is certified where its merged k-th distance is within EVERY
-    shard's limit on what it hides; a row any shard refuses is answered by
-    the exact scan over the same placed arrays.
-    ``span`` is the caller's ``knn.search`` span: it gets ``shards``,
-    ``kernel_rows``, ``refused`` and ``refused_by_shard``."""
+def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
+                             span, mesh, test_tile: int
+                             ) -> Tuple[np.ndarray, np.ndarray]:
+    """The fused route, on one chip (``mesh`` None) or over the row-sharded
+    index placed on ``mesh``: ONE jitted dispatch runs query pack → pallas
+    candidate kernel → exact f32 re-rank + per-row exactness certificate
+    (ops/pallas_knn.py::search_fused), over a sharded index on every shard,
+    merged and certified against EVERY shard's limit on what it hides
+    (parallel/collectives.py::sharded_knn_fused).  Host work per batch is
+    the raw query transfer and the [M, k] result read-back; a row refused
+    is answered by the exact scan over the same resident rows.
+    ``span`` is the caller's ``knn.search`` span: it gets ``kernel_rows``
+    and ``refused``, and over shards ``shards`` and ``refused_by_shard``."""
     from avenir_tpu.ops import pallas_knn
-    from avenir_tpu.parallel import collectives
     tracer = tel.tracer()
     nb = int(model.n_bins.max()) if model.n_bins.size else 1
-    n, d_par = model.num_refs, mesh.shape["data"]
-    r_mat, codes_r, cont01_r, shard = model.device_sharded(mesh, nb)
     m, f = test.codes.shape
     fc = test.cont.shape[1]
-    statics = pallas_knn.fused_statics(m, f, fc, k)
-    # one kernel for every shard: the one the shortest (the last) can fill
-    tourney = pallas_knn.tourney_engages(
-        n - (d_par - 1) * shard, r_mat.shape[0] // d_par, k)
+    if mesh is None:
+        r_mat, n = model.device_packed(nb)
+        codes_r, cont01_r = model.device_rerank_arrays()
+
+        def launch(cont01_q):
+            return pallas_knn.search_fused(test.codes, cont01_q, r_mat,
+                                           codes_r, cont01_r, n, nb, k, f + fc)
+
+        def scan(sub):
+            return _nearest_neighbors_xla(model, sub, k)
+    else:
+        from avenir_tpu.parallel import collectives
+        r_mat, codes_r, cont01_r, shard = model.device_sharded(mesh, nb)
+
+        def launch(cont01_q):
+            return collectives.sharded_knn_fused(
+                mesh, shard, num_bins=nb, total_attrs=f + fc,
+                **pallas_knn.fused_statics(m, f, fc, k))(
+                    jnp.asarray(test.codes), jnp.asarray(cont01_q), r_mat,
+                    codes_r, cont01_r, jnp.int32(model.num_refs))
+
+        def scan(sub):
+            return _nearest_neighbors_sharded(model, sub, k, "euclidean",
+                                              mesh, test_tile)
     with tracer.span("knn.stage"):
         # normalise, upload the queries, enqueue the program
-        cont01_q = _normalize01(test.cont, model.cont_lo, model.cont_hi)
-        out = collectives.sharded_knn_fused(
-            mesh, shard, num_bins=nb, total_attrs=f + fc,
-            use_tourney=tourney, **statics)(
-                jnp.asarray(test.codes), jnp.asarray(cont01_q), r_mat,
-                codes_r, cont01_r, jnp.int32(n))
+        out = launch(_normalize01(test.cont, model.cont_lo, model.cont_hi))
     with tracer.span("knn.readback"):
-        d, idx, cert, by_shard = (np.asarray(a) for a in out)
-    _count_fused(model, int(cert.size), tourney, span, statics["rows"])
-    model.shard_fused_rows += int(cert.size)
-    span.set("shards", d_par)
-    span.set("refused_by_shard", by_shard.tolist())
-    return _rescan_refused(
-        model, test, k, d, idx, cert, span,
-        lambda sub: _nearest_neighbors_sharded(model, sub, k, "euclidean",
-                                               mesh, test_tile))
+        d, idx, cert, *by_shard = (np.asarray(a) for a in out)
+    # counted once each whatever the number of shards; the kernel sweeps
+    # whole TM-row query tiles, whatever it was handed
+    model.fused_rows += int(cert.size)
+    model.tourney_rows += int(cert.size)
+    span.set("kernel_rows", pallas_knn.query_rows(m))
+    if mesh is not None:
+        model.shard_fused_rows += int(cert.size)
+        span.set("shards", mesh.shape["data"])
+        span.set("refused_by_shard", by_shard[0].tolist())
+    return _rescan_refused(model, test, k, d, idx, cert, span, scan)
 
 
 def nearest_neighbors(
@@ -532,7 +507,8 @@ def nearest_neighbors(
     not a method — when the fused exact path applies it is BOTH faster and
     exact, so an approx request routes there (≥-quality results, like the
     sharded routes below); only configurations the kernel cannot serve
-    (manhattan metric, k > kernel slots, non-TPU backends) run the
+    (manhattan metric, k > kernel slots, an index too small to fill the
+    kernel's candidate pool, non-TPU backends) run the
     per-tile ``lax.approx_min_k`` + exact cross-tile merge (0.9988
     end-to-end recall at 1M refs, k=10 in the 2026-07 records; not measured
     on today's code) — a capability knob the reference has no analog for,
@@ -547,17 +523,19 @@ def nearest_neighbors(
     with tel.tracer().span("knn.search", {"rows": rows, "kernel_rows": rows,
                                           "refused": 0}) as span:
         route = sharded_route(mesh, metric, k, model.num_refs)
-        if route is not None:
-            span.set("path", route)
-            if route == "sharded_fused":
-                return _nearest_neighbors_sharded_fused(model, test, k, mesh,
-                                                        test_tile, span)
+        if route is None:
+            from avenir_tpu.ops import pallas_knn
+            fused = (_pallas_available(metric, k)
+                     and pallas_knn.fused_serves(model.num_refs, k))
+            route = "fused" if fused else "xla"
+        span.set("path", route)
+        if route in ("fused", "sharded_fused"):
+            return _nearest_neighbors_fused(
+                model, test, k, span,
+                mesh if route == "sharded_fused" else None, test_tile)
+        if route == "sharded_scan":
             return _nearest_neighbors_sharded(model, test, k, metric, mesh,
                                               test_tile, ref_tile)
-        if _pallas_available(metric, k) and min(k, model.num_refs) == k:
-            span.set("path", "fused")
-            return _nearest_neighbors_pallas(model, test, k, span)
-        span.set("path", "xla")
         return _nearest_neighbors_xla(model, test, k, metric, ref_tile,
                                       test_tile, approx=mode == "approx")
 

@@ -16,19 +16,19 @@ one bf16 pass per tile:
   slower than this), and the ‖x‖²/‖y‖² norm terms as limb-split side
   columns. Reference pad rows bake a huge finite norm term (never ±inf: a
   zero padding lane times inf is NaN, and NaN poisons every compare).
-- At scale the candidate kernel is the round-3 SEGMENT KEY-TOURNAMENT
-  sweep (see its section below): int32 packed sort keys + a tournament of
-  min/max merges, per-2048-ref-segment top-2 + truncated third-min bound.
-  The merge-loop kernel in this section remains the small-reference-set
-  path (too few segments to fill the candidate pool): a running per-row
-  top-k' lives in VMEM scratch across the ref-block grid axis, and only
-  blocks with an improving candidate run extract-min merge rounds (a
-  while_loop whose condition *is* the skip test).
-- The caller then re-ranks the k' candidates with exact f32 arithmetic and
-  checks an exactness certificate (k-th exact candidate distance vs the
-  kernel's k'-th value minus the limb error bound); rows that fail fall
-  back to the exact XLA scan. With the 2⁻²⁶ bound the certificate
-  essentially never fails, so results are exact top-k, not approximate.
+- The candidate kernel is the SEGMENT KEY-TOURNAMENT sweep (see its
+  section below): int32 packed sort keys + a tournament of min/max merges,
+  per-2048-ref-segment top-2 + truncated third-min bound, no
+  data-dependent control.  It is the only candidate kernel:
+  :func:`fused_serves` says which indexes it can serve (enough real
+  segments to fill the candidate pool: over ~16 k rows at k = 10), and
+  the routes of models/knn.py send every other index to the exact XLA
+  scan, where one scan tile covers the whole set.
+- The same program then re-ranks the k' candidates with exact f32
+  arithmetic and checks an exactness certificate (k-th exact candidate
+  distance vs the nearest thing the sweep can hide, minus the limb error
+  bound); rows that fail fall back to the exact XLA scan, so results are
+  exact top-k, not approximate.
 
 Replaces the O(N²) all-pairs distance job the reference outsources to
 sifarish ``SameTypeSimilarity`` (resource/knn.sh:47-60) and the secondary-
@@ -46,95 +46,32 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block shapes. TM query rows are resident per grid row; TN reference rows
-# stream through VMEM per grid step. Kept candidates live in SLOTS lanes so
-# the best-buffer is VPU-tile aligned; unused slots are pinned to -_BIG so
-# they are never chosen as the eviction victim.
+# Block shapes. TM query rows are resident per grid row; TB reference rows
+# stream through VMEM per grid step, SEG-row segment by segment.  The re-rank
+# keeps at most SLOTS candidates a row.
 TM = 512
-TN = 2048
+TB = 16384             # reference rows per grid step (one DMA, 8 segments)
+SEG = 2048             # certificate granularity: top-2 + third-min bound
 SLOTS = 128
 MARGIN = 8             # extra candidates kept beyond k for the exact re-rank
 # Large finite sentinels — true infinities must never reach the MXU.
-_BIG = 3.0e30          # "retired / empty slot" distance
+_BIG = 3.0e30          # distance of a candidate slot with no reference
 _PADC = 1.0e30         # reference pad-row norm term: dominates any real d²
 # Absolute d² error bound of the limb-split dot (see _limbs): each of the
 # ~20 contributing terms is reproduced to ~2^-26 relative, magnitudes ≤ ~32.
 D2_EPS = 1e-4
-_DEBUG_NO_MERGE = False   # trace-time knobs for perf bisection only
-_DEBUG_NO_ROWMIN = False
-_DEBUG_NO_D2WRITE = False
 
 
-def _knn_kernel(a_ref, b_ref, best_d_out, best_i_out,
-                d2_ref, rowmin_ref, best_d_ref, best_i_ref,
-                *, k: int, nblocks: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        slot = jax.lax.broadcasted_iota(jnp.int32, (TM, SLOTS), 1)
-        best_d_ref[:] = jnp.where(slot < k, _BIG, -_BIG)
-        best_i_ref[:] = jnp.full((TM, SLOTS), -1, jnp.int32)
-
-    # the single bf16 MXU pass: d² = A·Bᵀ (the −2 of the norm expansion is
-    # folded into the reference operand at pack time — a separate scale op
-    # on the [TM, TN] block measured ~35 ms over the full sweep)
-    d2v = jax.lax.dot_general(
-        a_ref[:], b_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if not _DEBUG_NO_D2WRITE:
-        d2_ref[:] = d2v
-    # fused per-row min: the block-skip test below never has to touch the
-    # full block again for blocks with no improving candidate
-    if not _DEBUG_NO_ROWMIN:
-        rowmin_ref[:] = jnp.min(d2v, axis=1)[:, None]
-
-    def any_below(_):
-        # [TM] vs [TM]: is any candidate closer than the worst kept?
-        wd = jnp.max(best_d_ref[:], axis=1)                      # k-th best
-        return jnp.max(jnp.where(rowmin_ref[:, 0] < wd, 1, 0)) > 0
-
-    def merge_round(_):
-        # iotas generated inside the (rarely-taken) merge path: hoisting
-        # them materializes [TM, TN] tensors on every block, measured ~2×
-        # the whole kernel's runtime
-        col = jax.lax.broadcasted_iota(jnp.int32, (TM, TN), 1)
-        slot = jax.lax.broadcasted_iota(jnp.int32, (TM, SLOTS), 1)
-        d2 = d2_ref[:]
-        bd = best_d_ref[:]
-        wd = jnp.max(bd, axis=1)                                 # [TM]
-        bmin = rowmin_ref[:, 0]                                  # [TM]
-        bcol = jnp.min(jnp.where(d2 == bmin[:, None], col, TN), axis=1)
-        improving = bmin < wd
-        # eviction victim = current worst real slot (pads are -_BIG and can
-        # never be the max, so wslot ∈ [0, k))
-        wslot = jnp.min(jnp.where(bd == wd[:, None], slot, SLOTS), axis=1)
-        upd = improving[:, None] & (slot == wslot[:, None])
-        best_d_ref[:] = jnp.where(upd, bmin[:, None], bd)
-        best_i_ref[:] = jnp.where(upd, (j * TN + bcol)[:, None], best_i_ref[:])
-        # retire the extracted candidate (only where it was taken) and
-        # refresh the row minima in the same pass
-        d2 = jnp.where(improving[:, None] & (col == bcol[:, None]), _BIG, d2)
-        d2_ref[:] = d2
-        rowmin_ref[:] = jnp.min(d2, axis=1)[:, None]
-        return 0
-
-    # while-loop with the skip test as its condition: blocks with no
-    # improving candidate fall through after one tiny compare
-    if not _DEBUG_NO_MERGE:
-        jax.lax.while_loop(any_below, merge_round, 0)
-
-    @pl.when(j == nblocks - 1)
-    def _flush():
-        best_d_out[:] = best_d_ref[:]
-        best_i_out[:] = best_i_ref[:]
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _topk_pallas(a_mat, b_mat, k: int):
-    """a_mat [Mpad, K] bf16 queries; b_mat [Npad, K] bf16 references.
-    Returns ([Mpad, k] approx d², [Mpad, k] ref indices), ascending."""
-    return _topk_pallas_traced(a_mat, b_mat, k)
+def fused_serves(n_real: int, k: int) -> bool:
+    """Can the fused search serve an index — or every shard of one, asked of
+    the shortest — of ``n_real`` real rows at ``k``?  The one place that
+    decides it: ``k`` and a row past it fit the re-rank's slots, the rows
+    hold ``k`` neighbours, and the real segments' top-2 fill the candidate
+    pool (a pool filled up with pad rows bounds nothing and every
+    certificate fails): over 16384 rows at k = 10.  What it refuses takes
+    the exact scan, which needs no certificate."""
+    return (k + 1 <= SLOTS and n_real >= k
+            and 2 * -(-n_real // SEG) >= min(k + MARGIN, SLOTS))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -219,37 +156,14 @@ def _pack(codes: np.ndarray, cont01: np.ndarray, num_bins: int,
 
 def prepare_refs(codes: np.ndarray, cont01: np.ndarray, num_bins: int
                  ) -> Tuple[jax.Array, int]:
-    """Packed device-resident reference operand [Npad, K] bf16.
-
-    Sets larger than one tournament block round up to TB (a multiple of
-    the merge kernel's TN tile, so both kernels accept the operand); small
-    sets — which can never fill the tournament's candidate pool and always
-    route to the merge kernel — round only to TN, avoiding up-to-8× padded
-    scan work on every query batch."""
+    """Packed device-resident reference operand [operand_rows(N), K] bf16,
+    built on the host, and N."""
     n = codes.shape[0]
-    npad = operand_rows(n)
-    return _pack(codes, cont01, num_bins, npad, True, _PADC), n
-
-
-def prepare_queries(codes: np.ndarray, cont01: np.ndarray, num_bins: int
-                    ) -> Tuple[jax.Array, int]:
-    """Packed query operand [Mpad, K] bf16. The query's constant distance
-    term is f (every categorical mismatch contributes ≤ f)."""
-    m, f = codes.shape
-    mpad = _round_up(max(m, TM), TM)
-    return _pack(codes, cont01, num_bins, mpad, False, float(f)), m
-
-
-def topk_candidates(q_mat, r_mat, k: int, margin: int = MARGIN
-                    ) -> Tuple[np.ndarray, np.ndarray]:
-    """[Mpad, k+margin] (approx d², ref indices), ascending by approx d²."""
-    kk = min(k + margin, SLOTS)
-    d2, idx = _topk_pallas(q_mat, r_mat, kk)
-    return np.asarray(d2), np.asarray(idx)
+    return _pack(codes, cont01, num_bins, operand_rows(n), True, _PADC), n
 
 
 # ---------------------------------------------------------------------------
-# segmented key-tournament sweep — the candidate kernel at scale
+# segmented key-tournament sweep — the candidate kernel
 # ---------------------------------------------------------------------------
 # Each distance becomes ONE int32 sort key,
 # (bitcast(max(d2,0)) & ~(SEG-1)) | col — positive-float bitcast is
@@ -283,8 +197,6 @@ def topk_candidates(q_mat, r_mat, k: int, margin: int = MARGIN
 # The outputs come out [refs/2048, M]; _tourney_keys transposes them, which
 # XLA turns into a layout of the consumers, not a copy.
 
-TB = 16384             # reference rows per grid step (one DMA, 8 segments)
-SEG = 2048             # certificate granularity: top-2 + third-min bound
 # pad-lane key: the int32 bit pattern of _BIG (finite; NEVER 0x7fffffff,
 # whose truncated bitcast is NaN and would poison every downstream min)
 _PAD_KEY = int(np.float32(_BIG).view(np.int32))
@@ -407,7 +319,8 @@ def _topk_tourney_traced(a_mat, b_mat, k: int):
     Returns ([Mpad, k] approx (truncated-key) d² ascending, [Mpad, k] ref
     indices, [Mpad] non-candidate lower bound = min over segments of the
     segment's truncated third-smallest distance).
-    Requires 2 * (n/SEG) >= k and n % TB == 0 (prepare_refs pads to TB)."""
+    Requires n % TB == 0 (operand_rows) and, for a bound worth having,
+    :func:`fused_serves`."""
     k1, k2, k3 = _tourney_keys(a_mat, b_mat)
     segmask = jnp.int32(~(SEG - 1))
     seg_base = jnp.arange(k1.shape[1], dtype=jnp.int32) * SEG
@@ -429,10 +342,7 @@ def _topk_tourney_traced(a_mat, b_mat, k: int):
 # ---------------------------------------------------------------------------
 # fused single-dispatch path: device-side query pack + kernel + exact re-rank
 # ---------------------------------------------------------------------------
-# The host-side path above costs ~115 ms of single-core numpy per 4096-query
-# batch (pack ~86 ms, re-rank ~28 ms) plus one extra device round-trip —
-# together several times the kernel's own amortized time. This path runs
-# pack → pallas → re-rank as ONE jitted program: per batch the host
+# pack → pallas → re-rank run as ONE jitted program: per batch the host
 # transfers only the raw codes/cont arrays (~120 KB) and receives [M,k]
 # results + a per-row certificate, so batches pipeline back-to-back and
 # the round-trip latency amortizes away.
@@ -484,22 +394,21 @@ def _pack_queries_dev(codes: jax.Array, cont01: jax.Array, num_bins: int,
 
 def fused_candidates(codes_q, cont01_q, r_mat, codes_r, cont01_r, n_real, *,
                      num_bins: int, rows: int, extra_norm: float, k: int,
-                     kk: int, eps: float, use_tourney: bool):
+                     kk: int, eps: float):
     """Pack queries, run the pallas kernel, re-rank its kk candidates in
     exact f32: (d2s [M, kk] d² ascending, idxs [M, kk], kth [M] = d2s's
-    k-th, limit [M], cand_idx [M, kk]).  No reference outside the candidates
-    is nearer than ``limit``: the kk-th approx candidate and (tournament)
-    every block's third-smallest, less 2·eps of limb error."""
+    k-th, limit [M]).  No reference outside the candidates is nearer than
+    ``limit``: the kk-th approx candidate and every segment's
+    third-smallest, less 2·eps of limb error — THE certificate's bound,
+    compared with ``kth`` by :func:`_search_fused` on one chip and with the
+    merged k-th by parallel/collectives.py::merge_shard_topk over shards."""
     m = codes_q.shape[0]
     q_mat = _pack_queries_dev(codes_q, cont01_q, num_bins, rows, extra_norm)
-    if use_tourney:
-        cand_d2, cand_idx, bound3 = _topk_tourney_traced(q_mat, r_mat, kk)
-    else:
-        cand_d2, cand_idx = _topk_pallas_traced(q_mat, r_mat, kk)
-        bound3 = cand_d2[:, -1]       # merge kernel: kk-th kept IS the bound
+    cand_d2, cand_idx, bound3 = _topk_tourney_traced(q_mat, r_mat, kk)
     cand_d2, cand_idx, bound3 = cand_d2[:m], cand_idx[:m], bound3[:m]
     # pad reference rows (index ≥ n_real) would gather out of bounds: mark
-    # unseen. A pad in the slots also implies every real ref is a candidate.
+    # unseen.  A pad among the candidates proves nothing: segments still
+    # hide non-candidates, limit decides.
     cand_idx = jnp.where(cand_idx >= n_real, -1, cand_idx)
     safe_idx = jnp.maximum(cand_idx, 0)
     mism = (codes_q[:, None, :] != codes_r[safe_idx]).sum(-1).astype(jnp.float32)
@@ -510,126 +419,30 @@ def fused_candidates(codes_q, cont01_q, r_mat, codes_r, cont01_r, n_real, *,
     d2s, idxs = -neg, jnp.take_along_axis(cand_idx, order, axis=1)
     kth = d2s[:, min(k, kk) - 1]
     limit = jnp.minimum(cand_d2[:, -1], bound3) - 2 * eps
-    return d2s, idxs, kth, limit, cand_idx
+    return d2s, idxs, kth, limit
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "rows", "extra_norm",
-                                             "k", "kk", "total_attrs", "eps",
-                                             "use_tourney"))
-def _search_fused(*operands, k: int, total_attrs: int, use_tourney: bool,
-                  **statics):
+                                             "k", "kk", "total_attrs", "eps"))
+def _search_fused(*operands, k: int, total_attrs: int, **statics):
     """One dispatch → ([M, k] distances in [0,1], [M, k] indices, [M] cert)."""
-    d2s, idxs, kth, limit, cand_idx = fused_candidates(
-        *operands, k=k, use_tourney=use_tourney, **statics)
+    d2s, idxs, kth, limit = fused_candidates(*operands, k=k, **statics)
     cert = kth <= limit     # nothing outside the candidates beats the k-th
-    if not use_tourney:
-        # merge kernel only: a pad in the last slot proves every real ref was
-        # kept. A tournament's blocks still hide non-candidates: limit decides
-        cert = cert | (cand_idx[:, -1] < 0)
     return unit_distances(d2s[:, :k], total_attrs), idxs[:, :k], cert
-
-
-def _topk_pallas_traced(a_mat, b_mat, k: int):
-    """The pallas call without the jit/top-k wrapper (for use inside
-    :func:`_search_fused`'s trace)."""
-    m, n = a_mat.shape[0], b_mat.shape[0]
-    grid = (m // TM, n // TN)
-    kern = functools.partial(_knn_kernel, k=k, nblocks=grid[1])
-    best_d2, best_i = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TM, a_mat.shape[1]), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((TN, b_mat.shape[1]), lambda i, j: (j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((TM, SLOTS), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((TM, SLOTS), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, SLOTS), jnp.float32),
-            jax.ShapeDtypeStruct((m, SLOTS), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((TM, TN), jnp.float32),
-            pltpu.VMEM((TM, 1), jnp.float32),
-            pltpu.VMEM((TM, SLOTS), jnp.float32),
-            pltpu.VMEM((TM, SLOTS), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(a_mat, b_mat)
-    neg, pos = jax.lax.top_k(-best_d2[:, :k], k)
-    return -neg, jnp.take_along_axis(best_i[:, :k], pos, axis=1)
-
-
-def tourney_engages(n_real: int, n_packed: int, k: int,
-                    margin: int = MARGIN) -> bool:
-    """Which candidate kernel :func:`search_fused` takes: the tournament
-    only when enough REAL segments exist to fill the candidate pool —
-    pad-dominated segments would produce a uselessly small bound and fail
-    every certificate — and the operand is TB-aligned (prepare_refs)."""
-    kk = min(k + margin, SLOTS)
-    return 2 * -(-n_real // SEG) >= kk and n_packed % TB == 0
 
 
 def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
                  codes_r_dev: jax.Array, cont01_r_dev: jax.Array, n_real: int,
-                 num_bins: int, k: int, total_attrs: int,
-                 margin: int = MARGIN):
-    """Single-dispatch exact search. Returns device arrays
-    ([M,k] dist, [M,k] idx, [M] cert) — the caller syncs (or pipelines)."""
+                 num_bins: int, k: int, total_attrs: int):
+    """Single-dispatch exact search over an index :func:`fused_serves`
+    admits (the route's business, not asked again here). Returns device
+    arrays ([M,k] dist, [M,k] idx, [M] cert) — the caller syncs (or
+    pipelines)."""
     return _search_fused(
         jnp.asarray(codes_q), jnp.asarray(cont01_q, jnp.float32), r_mat,
         codes_r_dev, cont01_r_dev, n_real,
         num_bins=num_bins, total_attrs=total_attrs,
-        use_tourney=tourney_engages(n_real, r_mat.shape[0], k, margin),
-        **fused_statics(*codes_q.shape, cont01_q.shape[1], k, margin))
-
-
-def exact_rerank(cand_idx: np.ndarray, cand_d2: np.ndarray,
-                 codes_q: np.ndarray, cont_q: np.ndarray,
-                 codes_r: np.ndarray, cont_r: np.ndarray,
-                 k: int, total_attrs: int, eps: float | None = None,
-                 n_real: int | None = None
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Exact f32 re-rank of the kernel's k' candidates.
-
-    Returns ([M, k] distances in [0,1], [M, k] indices, [M] certificate):
-    certificate[i] is True when the exact top-k of row i is guaranteed
-    (k-th exact candidate d² ≤ k'-th approx d² − 2·eps, so no non-candidate
-    can beat it). Rows with certificate False must fall back to the exact
-    scan path. With no continuous features the kernel's bf16 arithmetic is
-    exact — pass eps=0 so integer-distance ties still certify.
-    """
-    if eps is None:
-        eps = D2_EPS if cont_q.shape[1] else 0.0
-    if n_real is None:
-        n_real = codes_r.shape[0]
-    # pad rows (d² ≈ _PADC) can land in candidate slots when the reference
-    # set is barely larger than k' — their indices point past n_real and
-    # would index codes_r out of bounds; mark them unseen. A pad in the
-    # slots also means every real reference is already among the candidates
-    # (all real d² beat _PADC), which the certificate below relies on.
-    cand_idx = np.where(cand_idx >= n_real, -1, cand_idx)
-    m, kk = cand_idx.shape
-    safe_idx = np.maximum(cand_idx, 0)
-    mism = (codes_q[:, None, :] != codes_r[safe_idx]).sum(-1).astype(np.float32)
-    diff = cont_q[:, None, :] - cont_r[safe_idx]
-    d2 = mism + (diff * diff).sum(-1)
-    d2[cand_idx < 0] = _BIG
-    order = np.argsort(d2, axis=1, kind="stable")
-    d2s = np.take_along_axis(d2, order, axis=1)
-    idxs = np.take_along_axis(cand_idx, order, axis=1)
-    kth = d2s[:, min(k, kk) - 1]
-    cert = kth <= cand_d2[:, -1] - 2 * eps
-    cert |= cand_idx[:, -1] < 0          # fewer refs than k': all seen
-    d = np.sqrt(np.maximum(d2s[:, :k], 0.0) / max(total_attrs, 1))
-    return np.clip(d, 0.0, 1.0), idxs[:, :k], cert
+        **fused_statics(*codes_q.shape, cont01_q.shape[1], k))
 
 
 # ---------------------------------------------------------------------------
@@ -646,12 +459,11 @@ def query_rows(m: int) -> int:
     return _round_up(max(m, TM), TM)
 
 
-def fused_statics(m: int, f: int, fc: int, k: int, margin: int = MARGIN
-                  ) -> dict:
+def fused_statics(m: int, f: int, fc: int, k: int) -> dict:
     """The static arguments of ``_search_fused`` that follow from a query
     block's shape ([m, f] codes, [m, fc] continuous) and ``k``."""
     return dict(rows=query_rows(m), extra_norm=float(f), k=k,
-                kk=min(k + margin, SLOTS), eps=D2_EPS if fc else 0.0)
+                kk=min(k + MARGIN, SLOTS), eps=D2_EPS if fc else 0.0)
 
 
 def unit_distances(d2: jax.Array, total_attrs: int) -> jax.Array:
@@ -661,8 +473,9 @@ def unit_distances(d2: jax.Array, total_attrs: int) -> jax.Array:
 
 
 def operand_rows(n: int) -> int:
-    """Rows of the packed operand of ``n`` references (see prepare_refs)."""
-    return _round_up(n, TB) if n > TB else _round_up(max(n, TN), TN)
+    """Rows of the packed operand of ``n`` references: whole TB-row blocks,
+    the tournament's grid step."""
+    return _round_up(max(n, 1), TB)
 
 
 def pack_refs_dev(codes: jax.Array, cont01: jax.Array, norm: jax.Array,

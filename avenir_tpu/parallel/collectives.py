@@ -192,7 +192,7 @@ def merge_shard_topk(d2: jax.Array, i: jax.Array, limit: jax.Array, k: int,
 @functools.lru_cache(maxsize=32)
 def sharded_knn_fused(mesh: Mesh, shard_rows: int, k: int, kk: int,
                       num_bins: int, rows: int, extra_norm: float,
-                      total_attrs: int, eps: float, use_tourney: bool,
+                      total_attrs: int, eps: float,
                       data_axis: str = "data"):
     """The fused search's candidates (ops/pallas_knn.py::fused_candidates:
     query pack → candidate kernel → exact f32 re-rank, and the limit below
@@ -214,15 +214,10 @@ def sharded_knn_fused(mesh: Mesh, shard_rows: int, k: int, kk: int,
 
     def _shard_search(codes_q, cont01_q, r_mat, codes_r, cont01_r, n):
         base = jax.lax.axis_index(data_axis) * shard_rows
-        d2s, idxs, _kth, limit, cand_idx = pallas_knn.fused_candidates(
+        d2s, idxs, _kth, limit = pallas_knn.fused_candidates(
             codes_q, cont01_q, r_mat, codes_r, cont01_r,
             jnp.clip(n - base, 0, shard_rows), num_bins=num_bins, rows=rows,
-            extra_norm=extra_norm, k=k, kk=kk, eps=eps,
-            use_tourney=use_tourney)
-        if not use_tourney:
-            # merge kernel: a pad in its last slot proves the shard kept
-            # every real row, so it hides nothing (as in _search_fused)
-            limit = jnp.where(cand_idx[:, -1] < 0, jnp.inf, limit)
+            extra_norm=extra_norm, k=k, kk=kk, eps=eps)
         i = idxs[:, :k]
         d2, gi, cert, refused = merge_shard_topk(
             d2s[:, :k], jnp.where(i < 0, -1, i + base), limit, k, data_axis)

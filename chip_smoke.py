@@ -52,7 +52,7 @@ CHUNK_ROWS = 1 << 20
 HOSP_ROWS = 4 * CHUNK_ROWS          # 4 whole chunks: no ragged-tail program
 HOLDOUT_ROWS = 20_000
 TREE_ROWS = 1 << 18
-KNN_REFS = 1 << 17                  # > pallas_knn.TB: the tournament engages
+KNN_REFS = 1 << 17                  # pallas_knn.fused_serves: the pool fills
 KNN_QUERIES = 4096
 KNN_K = 10
 SERVE_BUCKETS = "1,16"
@@ -913,7 +913,7 @@ want = np.sqrt(np.sort(np.partition(d2, K, axis=1)[:, :K], axis=1) / ATTRS)
 gap = float(np.abs(dist[checked] - want).max())
 r_mat, codes_s, cont01_s, shard = model.sharded_index(mesh)
 step = collectives.sharded_knn_fused(
-    mesh, shard, num_bins=1, total_attrs=ATTRS, use_tourney=True,
+    mesh, shard, num_bins=1, total_attrs=ATTRS,
     **pallas_knn.fused_statics(QUERIES, 0, ATTRS, K))
 text = step.lower(jnp.asarray(queries.codes), jnp.asarray(q01), r_mat,
                   codes_s, cont01_s, jnp.int32(REFS)).compile().as_text()

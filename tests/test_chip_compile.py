@@ -96,28 +96,31 @@ def test_shared_scan_gram_moments_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n,use_tourney,f,bins,fc", [
-    (1_000_000, True, 0, 1, 9),
-    (1_000_000, False, 0, 1, 9),
-    (13 << 20, True, 0, 1, 9),      # the benchmark's cells (perfbench/configs)
-    (1 << 24, True, 0, 1, 9),       # refused until PR 28: 104.25M of scoped VMEM
+@pytest.mark.parametrize("n,m,f,bins,fc", [
+    (1_000_000, 4096, 0, 1, 9),
+    # the benchmark's cells (perfbench/configs): a bulk block, and the serve
+    # cell's largest bucket, 64 rows swept as one 512-row query tile
+    (13 << 20, 64, 0, 1, 9),
+    (13 << 20, 4096, 0, 1, 9),
+    (1 << 24, 4096, 0, 1, 9),       # refused until PR 28: 104.25M of scoped VMEM
     # a wide schema, packed width 256: two MXU passes a segment and a
     # reference block of 16384 x 256 bf16, which the kernel's
     # vmem_limit_bytes has to go on admitting
-    (1 << 20, True, 6, 32, 8),
-], ids=["tournament", "merge", "tournament-13Mi", "tournament-16Mi",
-        "tournament-wide256"])
-def test_knn_fused_search_compiles_for_v5e(one_chip, n, use_tourney, f, bins,
-                                           fc):
+    (1 << 20, 4096, 6, 32, 8),
+], ids=["tournament", "tournament-13Mi-serve", "tournament-13Mi",
+        "tournament-16Mi", "tournament-wide256"])
+def test_knn_fused_search_compiles_for_v5e(one_chip, n, m, f, bins, fc):
     """elearn-shaped references (9 continuous attributes, packed width 128),
-    and one schema with categorical attributes, x 4096 queries through the
-    whole fused search program."""
+    and one schema with categorical attributes, x a block of queries through
+    the whole fused search program."""
     from avenir_tpu.ops import pallas_knn as pk
 
-    m, k = 4096, 10
-    npad = pk._round_up(n, pk.TB)
+    k = 10
+    npad = pk.operand_rows(n)
     width = pk._width(f, bins, fc)
-    assert width == (256 if f else 128) and npad % pk.TN == 0
+    assert width == (256 if f else 128) and pk.fused_serves(n, k)
+    statics = pk.fused_statics(m, f, fc, k)
+    assert statics["rows"] == max(m, pk.TM)
     compiled = pk._search_fused.lower(
         _shape((m, f), jnp.int32, one_chip),
         _shape((m, fc), jnp.float32, one_chip),
@@ -125,8 +128,7 @@ def test_knn_fused_search_compiles_for_v5e(one_chip, n, use_tourney, f, bins,
         _shape((n, f), jnp.int32, one_chip),
         _shape((n, fc), jnp.float32, one_chip),
         _shape((), jnp.int32, one_chip),
-        num_bins=bins, rows=m, extra_norm=float(f), k=k, kk=k + pk.MARGIN,
-        total_attrs=f + fc, eps=pk.D2_EPS, use_tourney=use_tourney).compile()
+        num_bins=bins, total_attrs=f + fc, **statics).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the query pack's bf16 limb split must reach the chip as roundings the
@@ -171,7 +173,7 @@ def test_sharded_fused_knn_compiles_for_four_v5e_chips(topo):
     index = (_shape((n, 0), jnp.int32, rows),
              _shape((n, fc), jnp.float32, rows))
     compiled = collectives.sharded_knn_fused(
-        mesh, shard, num_bins=1, total_attrs=fc, use_tourney=True,
+        mesh, shard, num_bins=1, total_attrs=fc,
         **pk.fused_statics(m, 0, fc, k)).lower(
             _shape((m, 0), jnp.int32, whole),
             _shape((m, fc), jnp.float32, whole),

@@ -1,6 +1,7 @@
 """The row-sharded kNN index (models/knn.py::KNNModel.device_sharded) and the
 fused search over it (parallel/collectives.py::sharded_knn_fused) against a
-numpy brute force over the concatenated references.
+numpy brute force over the concatenated references; and the one-chip route
+through the same body (models/knn.py::_nearest_neighbors_fused).
 
 Four of the eight forced host devices, the Pallas kernels in Mosaic interpret
 mode; ``_pallas_available`` is patched to say what it says on a TPU."""
@@ -96,7 +97,7 @@ def recorder(tmp_path):
 # -- the operand --------------------------------------------------------------
 
 @pytest.mark.parametrize("n,n_real,f,fc", [
-    (3000, 3000, 3, 4),         # one chunk, the operand longer than the rows
+    (3000, 3000, 3, 4),         # one TB-row chunk, mostly pad rows
     (3000, 2500, 0, 9),         # pad rows among the rows given
     (40_000, 39_999, 2, 0),     # TB-rounded operand, three chunks of 16384
 ])
@@ -152,7 +153,11 @@ def test_sharded_route_off_a_tpu_is_the_scan(mesh):
     ("manhattan", 10, 70_001, "sharded_scan"),
     ("euclidean", pk.SLOTS, 70_001, "sharded_scan"),      # k + 1 > SLOTS
     ("euclidean", 10, 39, "sharded_scan"),                # last shard: 9 rows
-    ("euclidean", 10, 40, "sharded_fused"),
+    ("euclidean", 10, 40, "sharded_scan"),      # 10 rows: k fits, no pool
+    # shards of 16 385 rows, k + MARGIN = 18 candidates: the last shard's 8
+    # segments give 16, one row more opens the ninth
+    ("euclidean", 10, 65_539, "sharded_scan"),
+    ("euclidean", 10, 65_540, "sharded_fused"),
 ])
 def test_sharded_route_on_a_tpu(on_tpu, mesh, metric, k, refs, route):
     assert mknn.sharded_route(mesh, metric, k, refs) == route
@@ -199,7 +204,7 @@ def test_sharded_fused_search_matches_brute_force_and_one_chip(
     q01 = mknn._normalize01(test.cont, model.cont_lo, model.cont_hi)
     sd, si, scert, by_shard = (np.asarray(a) for a in
                                collectives.sharded_knn_fused(
-        mesh, shard, num_bins=nb, total_attrs=f + fc, use_tourney=True,
+        mesh, shard, num_bins=nb, total_attrs=f + fc,
         **pk.fused_statics(m, f, fc, k))(
             jnp.asarray(test.codes), jnp.asarray(q01), r_mat, codes_s,
             cont01_s, jnp.int32(n)))
@@ -332,6 +337,51 @@ def test_the_shards_merged_top_k_is_the_unsharded_top_k(rng, mesh, n, k):
     np.testing.assert_array_equal(refused, (~within).sum(axis=1))
 
 
+# -- one chip -----------------------------------------------------------------
+
+@pytest.mark.parametrize("n,path", [(12, "xla"), (16_384, "xla"),
+                                    (16_385, "fused")])
+def test_one_chip_route_by_index_size(rng, on_tpu, recorder, n, path):
+    """k = 10 keeps 18 candidates, two a 2048-row segment: 8 segments cannot
+    fill the pool, the ninth's first row can.  Below that the exact scan
+    answers, in one tile."""
+    k, m = 10, 6
+    model = mknn.fit_knn(_ds(*_random(rng, n, 2, 3)))
+    test = _ds(*_random(rng, m, 2, 3))
+    d, idx = mknn.nearest_neighbors(model, test, k)
+    span = _search_span(recorder())
+    assert span.attrs["path"] == path
+    fused = m if path == "fused" else 0
+    assert (model.fused_rows, model.tourney_rows) == (fused, fused)
+    assert model.shard_fused_rows == 0
+    want_d, want_idx, next_d = _brute(model, test, k)
+    np.testing.assert_allclose(d, want_d, atol=3e-6)
+    _same_neighbours(idx, want_idx, want_d, next_d)
+
+
+def test_row_refused_on_one_chip_is_rescanned_and_counted_once(
+        rng, on_tpu, recorder):
+    """The real kernel and certificate on one chip: a segment hiding three
+    of the top k is refused, rescanned by the exact scan, counted once."""
+    n, k = 40_000, 5
+    codes, cont, query = _planted(rng, n, n, {0: k})
+    cont[8:11] = cont[7]
+    model = mknn.fit_knn(_ds(codes, cont))
+    test = _ds(np.zeros((1, 0), np.int32), query)
+    d, idx = mknn.nearest_neighbors(model, test, k)
+    records = recorder()
+    span = _search_span(records)
+    assert span.attrs["path"] == "fused" and span.attrs["refused"] == 1
+    assert "shards" not in span.attrs
+    fallback = [r for r in records if r.name == "knn.fallback"]
+    assert len(fallback) == 1 and fallback[0].attrs["rows"] == 1
+    assert (model.fused_rows, model.tourney_rows, model.cert_fallback_rows,
+            model.shard_fused_rows) == (1, 1, 1, 0)
+    want_d, want_idx, next_d = _brute(model, test, k)
+    np.testing.assert_allclose(d, want_d, atol=3e-6)
+    _same_neighbours(idx, want_idx, want_d, next_d)
+
+
 # -- serving ------------------------------------------------------------------
 
 def test_warmup_places_the_sharded_index_and_requests_do_not(
@@ -345,7 +395,7 @@ def test_warmup_places_the_sharded_index_and_requests_do_not(
         {"name": "y", "ordinal": 4, "dataType": "categorical",
          "cardinality": ["P", "F"]}]})
     enc = DatasetEncoder(schema)
-    n = 20_000
+    n = 41_000          # 10 250 rows a shard: 6 segments fill k + MARGIN = 11
     train = EncodedDataset(
         codes=np.zeros((n, 0), np.int32),
         cont=rng.integers(0, 200, size=(n, 3)).astype(np.float32),

@@ -1,7 +1,8 @@
 """Fused pallas kNN kernel (ops/pallas_knn.py) vs a numpy oracle.
 
-Runs in Mosaic interpret mode on the CPU test mesh; the same code path is
-exercised compiled on real TPU by benchmarks/knn_qps.py."""
+Runs in Mosaic interpret mode on the CPU test mesh; the same code runs
+compiled on the chip in the benchmark's cells (BENCHMARK.json, perfbench/)
+and in chip_smoke.py's kNN phases."""
 
 import numpy as np
 import pytest
@@ -33,54 +34,6 @@ def _oracle(codes_q, cont_q, codes_r, cont_r, k):
     return d, idx
 
 
-@pytest.mark.parametrize("f,fc", [(6, 8), (4, 0), (0, 5)])
-@needs_tpu_interpret
-def test_pallas_topk_exact(rng, f, fc):
-    nb, k = 7, 5
-    n, m = 3000, 40
-    codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
-    cont_r = rng.random(size=(n, fc)).astype(np.float32)
-    codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
-    cont_q = rng.random(size=(m, fc)).astype(np.float32)
-
-    with pltpu.force_tpu_interpret_mode():
-        r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        q_mat, m_real = pk.prepare_queries(codes_q, cont_q, nb)
-        d2, idx = pk.topk_candidates(q_mat, r_mat, k)
-    d, i, cert = pk.exact_rerank(idx[:m_real], d2[:m_real], codes_q, cont_q,
-                                 codes_r, cont_r, k, f + fc)
-    od, oi = _oracle(codes_q, cont_q, codes_r, cont_r, k)
-    assert cert.all()
-    np.testing.assert_allclose(d, od, atol=2e-5)
-    if fc:  # continuous features break distance ties; indices are unique
-        assert (i == oi).mean() == 1.0
-    else:   # pure categorical: integer distances tie heavily — compare values
-        np.testing.assert_allclose(d, od, atol=1e-6)
-
-
-@needs_tpu_interpret
-def test_tiny_reference_set_pads_masked(rng):
-    # k <= n < k+MARGIN: pad rows land in candidate slots; their indices
-    # must be masked, not index codes_r out of bounds, and the certificate
-    # must still hold (a pad in the slots proves every real ref was seen)
-    f, fc, nb, k = 3, 2, 5, 10
-    n, m = 12, 8
-    codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
-    cont_r = rng.random(size=(n, fc)).astype(np.float32)
-    codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
-    cont_q = rng.random(size=(m, fc)).astype(np.float32)
-    with pltpu.force_tpu_interpret_mode():
-        r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        q_mat, m_real = pk.prepare_queries(codes_q, cont_q, nb)
-        d2, idx = pk.topk_candidates(q_mat, r_mat, k)
-    d, i, cert = pk.exact_rerank(idx[:m_real], d2[:m_real], codes_q, cont_q,
-                                 codes_r, cont_r, k, f + fc, n_real=n)
-    assert cert.all()
-    od, oi = _oracle(codes_q, cont_q, codes_r, cont_r, k)
-    np.testing.assert_allclose(d, od, atol=2e-5)
-    assert (i == oi).all()
-
-
 def test_device_limb_split_rounds_with_reduce_precision(rng):
     """The device-side pack splits with ``lax.reduce_precision``: under jit
     the TPU compiler may drop an ``astype(bf16).astype(f32)`` round trip as
@@ -98,105 +51,34 @@ def test_device_limb_split_rounds_with_reduce_precision(rng):
         np.testing.assert_array_equal(host, np.asarray(dev))
 
 
-def test_certificate_flags_close_calls():
-    # rows where the k-th and (k'+1)-th distances collide within the error
-    # bound must not be certified exact
-    cand_idx = np.array([[0, 1, 2]])
-    cand_d2 = np.array([[0.1, 0.2, 0.2 + 1e-6]])   # k'-th ≈ k-th: ambiguous
-    codes_q = np.zeros((1, 0), np.int32)
-    cont_q = np.array([[0.0]], np.float32)
-    codes_r = np.zeros((3, 0), np.int32)
-    cont_r = np.array([[0.32], [0.45], [0.45]], np.float32)
-    d, i, cert = pk.exact_rerank(cand_idx, cand_d2, codes_q, cont_q,
-                                 codes_r, cont_r, k=2, total_attrs=1)
-    assert not cert[0]
-
-
-@pytest.mark.parametrize("f,fc", [(6, 8), (4, 0), (0, 5)])
+@pytest.mark.parametrize("f,fc", [(5, 6), (6, 8), (4, 0), (0, 5)])
 @needs_tpu_interpret
-def test_search_fused_matches_oracle_and_host_path(rng, f, fc):
-    # the PRODUCTION path (models/knn.py): one jitted dispatch running
-    # device-side query pack -> kernel -> device-side exact re-rank; its
-    # results and certificate must match both the oracle and the host-side
-    # pack/re-rank pipeline it replaced
+def test_search_fused_matches_oracle(rng, f, fc):
+    # the PRODUCTION program (models/knn.py): one jitted dispatch running
+    # device-side query pack -> tournament kernel -> device-side exact
+    # re-rank, at a reference count the route sends here, with mixed,
+    # categorical-only and continuous-only attributes
     import jax.numpy as jnp
 
-    nb, k = 7, 5
-    n, m = 3000, 40
-    codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
-    cont_r = rng.random(size=(n, fc)).astype(np.float32)
-    codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
-    cont_q = rng.random(size=(m, fc)).astype(np.float32)
-    with pltpu.force_tpu_interpret_mode():
-        r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        d, i, cert = pk.search_fused(
-            codes_q, cont_q, r_mat, jnp.asarray(codes_r),
-            jnp.asarray(cont_r), n_real, nb, k, f + fc)
-        # host-side path on the same operands
-        q_mat, m_real = pk.prepare_queries(codes_q, cont_q, nb)
-        hd2, hidx = pk.topk_candidates(q_mat, r_mat, k)
-    hd, hi, hcert = pk.exact_rerank(hidx[:m_real], hd2[:m_real], codes_q,
-                                    cont_q, codes_r, cont_r, k, f + fc)
-    d, i, cert = np.asarray(d), np.asarray(i), np.asarray(cert)
-    assert cert.all() and hcert.all()
-    od, oi = _oracle(codes_q, cont_q, codes_r, cont_r, k)
-    np.testing.assert_allclose(d, od, atol=2e-5)
-    np.testing.assert_allclose(d, hd, atol=2e-5)
-    if fc:
-        assert (i == oi).mean() == 1.0
-        np.testing.assert_array_equal(i, hi)
-
-
-@needs_tpu_interpret
-def test_search_fused_tiny_reference_set(rng):
-    import jax.numpy as jnp
-
-    f, fc, nb, k = 3, 2, 5, 10
-    n, m = 12, 8
-    codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
-    cont_r = rng.random(size=(n, fc)).astype(np.float32)
-    codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
-    cont_q = rng.random(size=(m, fc)).astype(np.float32)
-    with pltpu.force_tpu_interpret_mode():
-        r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        d, i, cert = pk.search_fused(
-            codes_q, cont_q, r_mat, jnp.asarray(codes_r),
-            jnp.asarray(cont_r), n_real, nb, k, f + fc)
-    d, i, cert = np.asarray(d), np.asarray(i), np.asarray(cert)
-    assert cert.all()
-    assert (np.asarray(i) < n).all()
-    od, oi = _oracle(codes_q, cont_q, codes_r, cont_r, min(k, n))
-    np.testing.assert_allclose(d[:, :n], od[:, :n], atol=2e-5)
-
-
-@needs_tpu_interpret
-def test_search_fused_block2_path_matches_oracle(rng):
-    # enough reference blocks to engage the block top-2 sweep
-    # (2*nblocks >= k+margin) — the production path at scale; verify exact
-    # results + certificate against the oracle
-    import jax.numpy as jnp
-
-    f, fc, nb, k = 5, 6, 8, 5
+    nb, k = 8, 5
     n, m = 70_000, 24
     codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
     cont_r = rng.random(size=(n, fc)).astype(np.float32)
     codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
     cont_q = rng.random(size=(m, fc)).astype(np.float32)
+    assert pk.fused_serves(n, k)
     with pltpu.force_tpu_interpret_mode():
         r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        # pin the TOURNAMENT path: enough real segments for the pool and a
-        # TB-aligned operand (the round-3 engagement gate in search_fused)
-        assert 2 * -(-n_real // pk.SEG) >= k + pk.MARGIN
-        assert r_mat.shape[0] % pk.TB == 0
+        assert r_mat.shape[0] == pk.operand_rows(n) == 5 * pk.TB
         d, i, cert = pk.search_fused(
             codes_q, cont_q, r_mat, jnp.asarray(codes_r),
             jnp.asarray(cont_r), n_real, nb, k, f + fc)
     d, i, cert = np.asarray(d), np.asarray(i), np.asarray(cert)
     od, oi = _oracle(codes_q, cont_q, codes_r, cont_r, k)
-    ok = cert
-    assert ok.mean() > 0.9            # uniform data: failures are rare
-    np.testing.assert_allclose(d[ok], od[ok], atol=2e-5)
-    assert (i[ok] == oi[ok]).mean() == 1.0
+    assert cert.mean() > 0.9                # uniform data: failures are rare
+    np.testing.assert_allclose(d[cert], od[cert], atol=2e-5)
+    if fc:  # continuous features break distance ties; indices are unique
+        assert (i[cert] == oi[cert]).mean() == 1.0
 
 
 @pytest.mark.parametrize("nblocks", [3, 17, 33])
@@ -307,14 +189,15 @@ def test_tourney_keys_planted_segments(rng, tourney_keys_jit, case):
 
 @needs_tpu_interpret
 def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
-    # regression: n_real = 8*TN+1 puts one real ref in the last block, so a
-    # pad lands in the candidate pool; that must NOT certify rows (the
-    # merge-kernel "pad => all refs seen" invariant does not hold here —
-    # blocks still hide non-candidates). Exactness comes from the fallback.
+    # regression: n_real = TB+1 — the smallest index fused_serves admits at
+    # k = 10 — puts one real ref in the last block, so a pad lands in the
+    # candidate pool; that must NOT certify rows (a pad among the candidates
+    # proves nothing — blocks still hide non-candidates). Exactness comes
+    # from the fallback.
     import jax.numpy as jnp
 
     f, fc, nb, k = 4, 3, 6, 10
-    n = 8 * pk.TN + 1
+    n = pk.TB + 1
     m = 16
     codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
     cont_r = rng.random(size=(n, fc)).astype(np.float32)
@@ -322,8 +205,7 @@ def test_search_fused_block2_short_last_block_not_falsely_certified(rng):
     cont_q = rng.random(size=(m, fc)).astype(np.float32)
     with pltpu.force_tpu_interpret_mode():
         r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        assert 2 * -(-n_real // pk.SEG) >= k + pk.MARGIN   # tournament path
-        assert r_mat.shape[0] % pk.TB == 0
+        assert pk.fused_serves(n_real, k) and r_mat.shape[0] % pk.TB == 0
         d, i, cert = pk.search_fused(
             codes_q, cont_q, r_mat, jnp.asarray(codes_r),
             jnp.asarray(cont_r), n_real, nb, k, f + fc)
@@ -353,8 +235,7 @@ def test_search_fused_block2_heavy_ties_and_duplicates(rng):
     cont_q = rng.random(size=(m, fc)).astype(np.float32)
     with pltpu.force_tpu_interpret_mode():
         r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
-        assert 2 * -(-n_real // pk.SEG) >= k + pk.MARGIN   # tournament path
-        assert r_mat.shape[0] % pk.TB == 0
+        assert pk.fused_serves(n_real, k) and r_mat.shape[0] % pk.TB == 0
         d, i, cert = pk.search_fused(
             codes_q, cont_q, r_mat, jnp.asarray(codes_r),
             jnp.asarray(cont_r), n_real, nb, k, f + fc)

@@ -94,9 +94,10 @@ def _lines(rng, n, tag="u"):
 
 @pytest.fixture()
 def fused(monkeypatch):
-    """Route the search through ``_nearest_neighbors_pallas`` with a fake
+    """Route the search through ``_nearest_neighbors_fused`` with a fake
     ``search_fused``: exact answers, and the rows listed in ``refuse``
-    failing their certificate."""
+    failing their certificate.  The fake needs no candidate pool, so the
+    route's size predicate is answered for it."""
     refuse = []
 
     def fake(codes_q, cont01_q, r_mat, codes_r, cont01_r, n, nb, k, attrs):
@@ -110,6 +111,7 @@ def fused(monkeypatch):
         return d.astype(np.float32), idx.astype(np.int32), cert
 
     monkeypatch.setattr(mknn, "_pallas_available", lambda metric, k: True)
+    monkeypatch.setattr(pallas_knn, "fused_serves", lambda n_real, k: True)
     monkeypatch.setattr(pallas_knn, "search_fused", fake)
     return refuse
 
