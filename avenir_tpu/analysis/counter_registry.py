@@ -60,6 +60,6 @@ SPAN_SITES = {
     'serve.queue': 'docs/observability.md',
     'serve.reply': 'docs/observability.md',
     'serve.request': 'docs/architecture.md',
-    'serve.slot': 'docs/observability.md',
+    'serve.slot': 'docs/architecture.md',
     'stage.*': 'docs/observability.md',
 }

@@ -32,9 +32,10 @@ applied only in the serde view (a documented deliberate fix).
 from __future__ import annotations
 
 import functools
+import threading
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
-from typing import Iterable, List, Optional, Sequence, Tuple, Union
+from dataclasses import dataclass, field
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -69,11 +70,36 @@ class KNNModel:
     cont_hi: np.ndarray                 # [Fc] train max
     # search-route tally (the NearestNeighbor job prints it): query rows
     # the fused Pallas search answered, and how many of those failed the
-    # exactness certificate and were recomputed by the exact XLA scan
+    # exactness certificate and were recomputed by the exact XLA scan;
+    # moved together by :meth:`count_fused` under ``_lock``, which the
+    # device copies below are also made under (a first use from two
+    # threads must not pack and upload a copy twice)
     fused_rows: int = 0
     tourney_rows: int = 0               # == fused_rows: one candidate kernel
     cert_fallback_rows: int = 0
     shard_fused_rows: int = 0           # of fused_rows: row-sharded index
+    _lock: threading.Lock = field(
+        default_factory=threading.Lock, repr=False, compare=False)
+
+    def count_fused(self, rows: int, refused: int, sharded: bool) -> bool:
+        """One fused search on the tally: ``rows`` answered, ``refused`` of
+        them sent to the exact scan.  The counters move together under one
+        lock — a served model is searched from two dispatcher threads
+        (serving/batcher.py), and a reader that compares ``tourney_rows``
+        with ``fused_rows`` must never see a lost update.  Returns whether
+        this COUNT of refused rows is new to the model (the exact scan
+        compiles one program a count)."""
+        with self._lock:
+            self.fused_rows += rows
+            self.tourney_rows += rows
+            self.cert_fallback_rows += refused
+            if sharded:
+                self.shard_fused_rows += rows
+            # no rows refused is no program: 0 is seen from the start
+            seen = self.__dict__.setdefault("_fallback_counts", {0})
+            fresh = refused not in seen
+            seen.add(refused)
+        return fresh
 
     @property
     def num_refs(self) -> int:
@@ -91,22 +117,24 @@ class KNNModel:
         """Packed bf16 operand for the fused pallas kernel (cached: repeated
         queries must not re-pack or re-upload the reference set)."""
         from avenir_tpu.ops import pallas_knn
-        cache = self.__dict__.setdefault("_dev_packed", {})
-        if num_bins not in cache:
-            cache[num_bins] = pallas_knn.prepare_refs(
-                self.codes, self.cont01(), num_bins)
-        return cache[num_bins]
+        with self._lock:
+            cache = self.__dict__.setdefault("_dev_packed", {})
+            if num_bins not in cache:
+                cache[num_bins] = pallas_knn.prepare_refs(
+                    self.codes, self.cont01(), num_bins)
+            return cache[num_bins]
 
     def device_rerank_arrays(self):
         """Reference codes + normalized continuous columns resident on
         device (cached) — the fused search's exact re-rank gathers candidate
         rows from these instead of running single-core numpy per batch."""
         import jax.numpy as jnp
-        c = self.__dict__.get("_dev_rerank")
-        if c is None:
-            c = self.__dict__["_dev_rerank"] = (
-                jnp.asarray(self.codes), jnp.asarray(self.cont01()))
-        return c
+        with self._lock:
+            c = self.__dict__.get("_dev_rerank")
+            if c is None:
+                c = self.__dict__["_dev_rerank"] = (
+                    jnp.asarray(self.codes), jnp.asarray(self.cont01()))
+            return c
 
     def sharded_index(self, mesh):
         """The index placed over ``mesh`` by :meth:`device_sharded`, or None
@@ -126,40 +154,42 @@ class KNNModel:
         from avenir_tpu.parallel import collectives
         from avenir_tpu.parallel.mesh import data_sharding, pad_batch
 
-        cache = self.__dict__.setdefault("_dev_sharded_index", {})
-        if mesh not in cache:
-            n, d_par = self.num_refs, mesh.shape["data"]
-            shard = _index_shard_rows(n, d_par)
-            with tel.tracer().span("knn.place", {
-                    "refs": n, "shards": d_par, "shard_rows": shard,
-                    "operand_rows": pallas_knn.operand_rows(shard)}):
-                codes_s, cont01_s, norm_s = (
-                    jax.device_put(a, data_sharding(mesh, a.ndim))
-                    for a in pad_batch(shard * d_par, self.codes,
-                                       self.cont01(),
-                                       _row_norms(self.cont01())))
-                r_mat = collectives.sharded_knn_pack(mesh, num_bins)(
-                    codes_s, cont01_s, norm_s, jnp.int32(n))
-                cache[mesh] = (jax.block_until_ready(r_mat), codes_s,
-                               cont01_s, shard)
-        return cache[mesh]
+        with self._lock:
+            cache = self.__dict__.setdefault("_dev_sharded_index", {})
+            if mesh not in cache:
+                n, d_par = self.num_refs, mesh.shape["data"]
+                shard = _index_shard_rows(n, d_par)
+                with tel.tracer().span("knn.place", {
+                        "refs": n, "shards": d_par, "shard_rows": shard,
+                        "operand_rows": pallas_knn.operand_rows(shard)}):
+                    codes_s, cont01_s, norm_s = (
+                        jax.device_put(a, data_sharding(mesh, a.ndim))
+                        for a in pad_batch(shard * d_par, self.codes,
+                                           self.cont01(),
+                                           _row_norms(self.cont01())))
+                    r_mat = collectives.sharded_knn_pack(mesh, num_bins)(
+                        codes_s, cont01_s, norm_s, jnp.int32(n))
+                    cache[mesh] = (jax.block_until_ready(r_mat), codes_s,
+                                   cont01_s, shard)
+            return cache[mesh]
 
     def device_tiles(self, ref_tile: int):
         """Reference set as resident device arrays [T, ref_tile, ·], padded to
         a whole number of tiles (pad rows masked out by index in the scan).
         Cached per tile size: repeated queries must not re-upload the refs."""
-        cache = self.__dict__.setdefault("_dev_tiles", {})
-        if ref_tile not in cache:
-            n = self.num_refs
-            t = max(-(-n // ref_tile), 1)
-            pad = t * ref_tile - n
-            codes = np.pad(self.codes, ((0, pad), (0, 0)))
-            cont = np.pad(self.cont, ((0, pad), (0, 0)))
-            cache[ref_tile] = (
-                jnp.asarray(codes.reshape(t, ref_tile, -1)),
-                jnp.asarray(cont.reshape(t, ref_tile, -1)),
-            )
-        return cache[ref_tile]
+        with self._lock:
+            cache = self.__dict__.setdefault("_dev_tiles", {})
+            if ref_tile not in cache:
+                n = self.num_refs
+                t = max(-(-n // ref_tile), 1)
+                pad = t * ref_tile - n
+                codes = np.pad(self.codes, ((0, pad), (0, 0)))
+                cont = np.pad(self.cont, ((0, pad), (0, 0)))
+                cache[ref_tile] = (
+                    jnp.asarray(codes.reshape(t, ref_tile, -1)),
+                    jnp.asarray(cont.reshape(t, ref_tile, -1)),
+                )
+            return cache[ref_tile]
 
 
 def fit_knn(
@@ -299,27 +329,20 @@ def _pallas_available(metric: str, k: int) -> bool:
             and jax.default_backend() == "tpu")
 
 
-def _rescan_refused(model: KNNModel, test: EncodedDataset, k: int,
-                    d: np.ndarray, idx: np.ndarray, cert: np.ndarray, span,
-                    scan) -> Tuple[np.ndarray, np.ndarray]:
+def _rescan_refused(test: EncodedDataset, d: np.ndarray, idx: np.ndarray,
+                    cert: np.ndarray, new_program: bool, scan
+                    ) -> Tuple[np.ndarray, np.ndarray]:
     """The fused search's answers with every row whose certificate failed
     (the candidate set might miss a true neighbour) recomputed by the exact
-    scan ``scan(rows) -> (d, idx)``; counted on ``cert_fallback_rows`` and
-    the span's ``refused``."""
-    refused = int(cert.size - cert.sum())
-    model.cert_fallback_rows += refused
-    span.set("refused", refused)
-    if not refused:
+    scan ``scan(rows) -> (d, idx)``, under a ``knn.fallback`` span."""
+    rows = np.flatnonzero(~cert)
+    if not rows.size:
         return d, idx
-    # the exact scan compiles one program per count of refused rows
-    seen = model.__dict__.setdefault("_fallback_counts", set())
     with tel.tracer().span("knn.fallback", {
-            "rows": refused, "new_program": refused not in seen}):
-        seen.add(refused)
+            "rows": int(rows.size), "new_program": new_program}):
         # np.asarray of a device array is a read-only view; the fallback
         # writes row-wise
         d, idx = d.copy(), idx.copy()
-        rows = np.flatnonzero(~cert)
         d[rows], idx[rows] = scan(EncodedDataset(
             codes=test.codes[rows], cont=test.cont[rows],
             labels=None if test.labels is None else test.labels[rows],
@@ -431,7 +454,8 @@ def _nearest_neighbors_sharded(model: KNNModel, test: EncodedDataset, k: int,
 
 
 def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
-                             span, mesh, test_tile: int
+                             span, mesh, test_tile: int,
+                             counts: Optional[Dict[str, int]] = None
                              ) -> Tuple[np.ndarray, np.ndarray]:
     """The fused route, on one chip (``mesh`` None) or over the row-sharded
     index placed on ``mesh``: ONE jitted dispatch runs query pack → pallas
@@ -442,7 +466,8 @@ def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
     the raw query transfer and the [M, k] result read-back; a row refused
     is answered by the exact scan over the same resident rows.
     ``span`` is the caller's ``knn.search`` span: it gets ``kernel_rows``
-    and ``refused``, and over shards ``shards`` and ``refused_by_shard``."""
+    and ``refused``, and over shards ``shards`` and ``refused_by_shard``;
+    ``counts`` (where given) gets this search's own ``refused``."""
     from avenir_tpu.ops import pallas_knn
     tracer = tel.tracer()
     nb = int(model.n_bins.max()) if model.n_bins.size else 1
@@ -479,22 +504,28 @@ def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
         d, idx, cert, *by_shard = (np.asarray(a) for a in out)
     # counted once each whatever the number of shards; the kernel sweeps
     # whole TM-row query tiles, whatever it was handed
-    model.fused_rows += int(cert.size)
-    model.tourney_rows += int(cert.size)
-    span.set("kernel_rows", pallas_knn.query_rows(m))
+    refused = int(cert.size - cert.sum())
+    new_program = model.count_fused(int(cert.size), refused,
+                                    sharded=mesh is not None)
+    if counts is not None:
+        counts["refused"] = refused
+    span.set("kernel_rows", pallas_knn.query_rows(m)).set("refused", refused)
     if mesh is not None:
-        model.shard_fused_rows += int(cert.size)
         span.set("shards", mesh.shape["data"])
         span.set("refused_by_shard", by_shard[0].tolist())
-    return _rescan_refused(model, test, k, d, idx, cert, span, scan)
+    return _rescan_refused(test, d, idx, cert, new_program, scan)
 
 
 def nearest_neighbors(
     model: KNNModel, test: EncodedDataset, k: int,
     metric: str = "euclidean", ref_tile: int = SCAN_TILE,
     test_tile: int = 8192, mode: str = "exact", mesh=None,
+    counts: Optional[Dict[str, int]] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """([M, k] distances, [M, k] reference indices), ascending by distance.
+    ``counts``, where given, receives what THIS search counted
+    (``refused``: rows its certificate sent to the exact scan) — the model's
+    own counters are shared by every search in flight on it.
 
     ``mode="exact"`` (default): on TPU backends the euclidean metric
     dispatches to the fused Pallas search (segment key-tournament + exact
@@ -532,7 +563,8 @@ def nearest_neighbors(
         if route in ("fused", "sharded_fused"):
             return _nearest_neighbors_fused(
                 model, test, k, span,
-                mesh if route == "sharded_fused" else None, test_tile)
+                mesh if route == "sharded_fused" else None, test_tile,
+                counts)
         if route == "sharded_scan":
             return _nearest_neighbors_sharded(model, test, k, metric, mesh,
                                               test_tile, ref_tile)
@@ -595,6 +627,7 @@ class KNNResult:
     neighbor_dist: np.ndarray          # [M, k]
     confusion: Optional[ConfusionMatrix] = None
     counters: Optional[Counters] = None
+    refused: int = 0                   # rows answered by the exact scan
 
 
 class KNN:
@@ -649,9 +682,11 @@ class KNN:
     def _classify(self, model: KNNModel, test: EncodedDataset,
                   validate: bool) -> KNNResult:
         tracer = tel.tracer()
+        counts: Dict[str, int] = {}
         dists, idx = nearest_neighbors(model, test, self.k, self.metric,
                                        self.ref_tile, self.test_tile,
-                                       mode=self.search_mode, mesh=self.mesh)
+                                       mode=self.search_mode, mesh=self.mesh,
+                                       counts=counts)
         with tracer.span("knn.weights"):
             w = kernel_weights(dists, self.kernel, self.kernel_sigma, self.inverse_distance)
             neigh_labels = model.labels[idx]                        # [M, k]
@@ -680,7 +715,8 @@ class KNN:
             else:
                 predicted = np.argmax(shares, axis=1).astype(np.int32)
         result = KNNResult(predicted=predicted, class_scores=shares,
-                           neighbor_idx=idx, neighbor_dist=dists)
+                           neighbor_idx=idx, neighbor_dist=dists,
+                           refused=counts.get("refused", 0))
         if validate:
             if test.labels is None:
                 raise ValueError("validation requires test labels")

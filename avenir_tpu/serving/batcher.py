@@ -22,22 +22,45 @@ Latency/throughput policy:
 - a request that ages past ``serve.request.timeout.ms`` before a batch
   picks it up fails with :class:`RequestTimeout`.
 
-One dispatcher thread owns every device call: the accelerator serializes
-batches anyway, and a single submitter keeps the jit cache and the CUDA/TPU
-stream free of cross-thread interleaving.  ``submit`` may be called from any
-number of frontend threads.
+Two dispatches in flight (PR 33): two dispatcher threads run the same
+``_loop``, each taking a batch and running the WHOLE dispatch for it (slot →
+``score_lines`` → replies).  While one dispatch is between its take and its
+last reply the other thread may take a second one.  Of a model that HAS a
+batch in flight it takes only a FULL ``max(bucket)``: that model's short
+bucket flushes by the deadline rule above only when no dispatch in flight
+carries a batch of the same model, which is when a single dispatcher would
+have looked at it.  The rule is per model — a quiet model's short bucket
+past its deadline is taken by the next free thread whatever a saturated
+neighbour has in flight, at most one dispatch later, as with one dispatcher.
+So a model under light load sees no request go out earlier or in a shorter
+bucket than with one dispatcher, and under load the second dispatch's parse,
+encode, upload and launch run while the first program is on the chip, its
+program queues behind the first on the device's stream, and the first
+dispatch's read-back, vote and replies run beside it.  Depth is two and
+fixed: one program on the chip, one prepared behind it.
+``Serving.<model>::overlapped`` counts, of ``batches``, those taken while
+another dispatch was in flight.  Requests are independent: replies of
+different buckets may complete in either order and nothing promises an order
+across buckets.  What two dispatcher threads on one interpreter lock cost a
+family whose device time is microseconds, or callers below two buckets, no
+cell of the benchmark measures: its one serve cell (kNN, 128 callers on a
+64-row bucket) sits on the always-overlapped side of the rule, and the one
+reading from outside it leaves Naive Bayes unresolved (PERF.md §6–§7).
+``submit`` may be called from any number of frontend threads.
 
 FleetServe (round 17): a batcher is now one REPLICA of a
 :class:`~avenir_tpu.serving.pool.ReplicaPool` — ``name`` labels its spans,
 errors and journal events; ``counters``/``latency`` may be shared across
-the pool so ``/metrics`` aggregates for free; the dispatcher maintains a
-``heartbeat`` the pool's deadline detection reads (:meth:`stalled`); and a
+the pool so ``/metrics`` aggregates for free; every dispatch in flight keeps a
+heartbeat the pool's deadline detection reads (:meth:`stalled`: the OLDEST
+one, so a wedged dispatch shows while the other beats); and a
 conf-armed :class:`~avenir_tpu.utils.retry.FaultPlan` can kill it through
 two sites — ``serve.dispatch`` (replica dies mid-batch: every unfinished
-request fails with the retryable :class:`ReplicaDownError`, the pool's
-failover cue) and ``serve.heartbeat`` (the dispatcher wedges silently:
-pending requests stay stranded until the pool's heartbeat deadline reaps
-them) — so chaos drills arm replica loss from configuration alone.
+request of both dispatches and the queue fails with the retryable
+:class:`ReplicaDownError`, the pool's failover cue) and ``serve.heartbeat``
+(the dispatchers wedge silently: pending requests stay stranded until the
+pool's heartbeat deadline reaps them) — so chaos drills arm replica loss
+from configuration alone.
 """
 
 from __future__ import annotations
@@ -87,7 +110,7 @@ class PendingRequest:
     tenant's requests out of a merged fleet journal."""
 
     __slots__ = ("model", "line", "enqueued", "queued", "result", "error",
-                 "_done", "trace_ctx", "rid", "probe", "tenant")
+                 "_done", "_lock", "trace_ctx", "rid", "probe", "tenant")
 
     def __init__(self, model: str, line: str, rid: Optional[str] = None,
                  probe: bool = False, tenant: Optional[str] = None):
@@ -100,6 +123,9 @@ class PendingRequest:
         self.result: Optional[str] = None
         self.error: Optional[ServingError] = None
         self._done = threading.Event()
+        # ``finish`` may be raced by a reply and a replica's death, from two
+        # threads: exactly one may win
+        self._lock = threading.Lock()
         self.trace_ctx = tel.tracer().current()
         self.rid = rid
         self.probe = probe
@@ -107,15 +133,19 @@ class PendingRequest:
             else tel.current_label("tenant")
 
     def finish(self, result: Optional[str] = None,
-               error: Optional[ServingError] = None) -> None:
-        # idempotent: a request that already scored must NEVER be
-        # re-finished with a replica-death error (the at-most-once pillar
-        # of pool failover — a done request is done)
-        if self._done.is_set():
-            return
-        self.result = result
-        self.error = error
-        self._done.set()
+               error: Optional[ServingError] = None) -> bool:
+        """True where THIS call finished the request.  Idempotent: a
+        request that already scored must NEVER be re-finished with a
+        replica-death error, and one a dying replica already failed over
+        is never also reported scored (the at-most-once pillar of pool
+        failover — a done request is done)."""
+        with self._lock:
+            if self._done.is_set():
+                return False
+            self.result = result
+            self.error = error
+            self._done.set()
+            return True
 
     def wait(self, timeout_s: Optional[float] = None) -> str:
         if not self._done.wait(timeout_s):
@@ -127,7 +157,32 @@ class PendingRequest:
         return self.result  # type: ignore[return-value]
 
 
+class _Flight:
+    """One dispatch in flight: the batches it took (one a ready model), how
+    many dispatches were already in flight at its take, and its own
+    heartbeat (a float store is atomic under the GIL, and
+    :meth:`BucketedMicrobatcher.stalled` only compares staleness)."""
+
+    __slots__ = ("batches", "ahead", "beat")
+
+    def __init__(self, batches: List[Tuple[str, List[PendingRequest]]],
+                 ahead: int):
+        self.batches = batches
+        self.ahead = ahead
+        self.beat = time.monotonic()
+
+    def tick(self) -> None:
+        self.beat = time.monotonic()
+
+    def requests(self) -> List[PendingRequest]:
+        return [r for _, reqs in self.batches for r in reqs]
+
+
 class BucketedMicrobatcher:
+    # dispatches in flight at once, fixed: one program on the chip, one
+    # prepared behind it — a third could add nothing but queueing
+    DEPTH = 2
+
     def __init__(self, registry: ModelRegistry,
                  bucket_sizes: Sequence[int] = (1, 2, 4, 8, 16, 32, 64),
                  flush_deadline_ms: float = 5.0,
@@ -164,8 +219,9 @@ class BucketedMicrobatcher:
         # (shared across a pool so site counts are pool-wide); ``device``
         # pins this replica's dispatches (the dispatcher thread enters
         # jax.default_device(device) — one replica per local chip);
-        # ``heartbeat`` is the dispatcher's liveness signal, updated every
-        # loop wake and read by ReplicaPool.stalled-based deadline checks
+        # ``heartbeat`` is the idle dispatchers' liveness signal, updated
+        # every loop wake; a dispatch in flight beats on its own _Flight
+        # (ReplicaPool's deadline checks read both through ``stalled``)
         self.name = name
         # GraftPool (round 18): the tenant this serving plane belongs to
         # (``tenant.id``).  The dispatcher runs under the tenant's label
@@ -181,7 +237,6 @@ class BucketedMicrobatcher:
         self.on_batch_error = on_batch_error
         self.heartbeat = time.monotonic()
         self.failed = False
-        self._dispatching = False
         # per-model EWMA of batch dispatch seconds — the queue drain
         # estimate behind a shed's Retry-After (satellite: a 429 tells
         # the client WHEN to come back, not just "go away")
@@ -197,11 +252,15 @@ class BucketedMicrobatcher:
             for name in registry.names()}
         self._cond = threading.Condition()
         self._stop = False
-        # GraftBox: requests popped from their queues but not yet
-        # scored — with the queues, the in-flight table a forensics
+        # set by ``_die`` and by the wedge drill: every dispatcher thread
+        # ends at its next look, taking nothing more
+        self._halt = False
+        # the dispatches in flight (at most DEPTH, under ``_cond``): what
+        # ``stalled`` reads the oldest heartbeat of, what ``_die`` fails,
+        # and — GraftBox — with the queues the in-flight table a forensics
         # bundle snapshots (rid + tenant + queue age of everything this
         # replica would strand if it died right now)
-        self._active: List[PendingRequest] = []
+        self._flights: List[_Flight] = []
         self._bb_name = f"batcher-{name}" if name else \
             f"batcher-{id(self):x}"
         blackbox.register_provider(self._bb_name, self._blackbox_inflight,
@@ -216,10 +275,15 @@ class BucketedMicrobatcher:
         self.ready = False
         if warmup:
             self.warm()
-        self._thread = threading.Thread(
-            target=self._loop, daemon=True,
-            name=f"serve-dispatch-{name}" if name else "serve-dispatch")
-        self._thread.start()
+        with self._cond:
+            self._wake()
+        stem = f"serve-dispatch-{name}" if name else "serve-dispatch"
+        self._threads = [
+            threading.Thread(target=self._loop, daemon=True,
+                             name=f"{stem}-{i}")
+            for i in range(self.DEPTH)]
+        for thread in self._threads:
+            thread.start()
 
     @classmethod
     def from_conf(cls, registry: ModelRegistry, conf: JobConfig,
@@ -267,8 +331,7 @@ class BucketedMicrobatcher:
         just between swaps.  In-flight batches hold the old entry object
         they resolved at dispatch and finish on the old params; every
         batch dispatched after the publish resolves the new entry.
-        Documented exception to the one-dispatcher-thread rule: the
-        warmup compiles run on the CALLER's thread concurrently with live
+        The warmup compiles run on the CALLER's thread concurrently with live
         dispatches (JAX is thread-safe; routing them through the
         dispatcher would stall the same batches behind the same compiles)
         — expect a p99 bump for the duration of a swap either way.
@@ -313,7 +376,15 @@ class BucketedMicrobatcher:
                 queue.append(req)
                 req.queued = time.perf_counter()
                 depth = len(queue)
-                self._cond.notify()
+                if depth >= self.max_bucket or (
+                        depth == 1 and not self._in_flight(model)):
+                    # only the request that fills a bucket or starts a
+                    # queue's deadline gives a sleeper something to act on
+                    # (with two sleepers, a wake a submit is two threads
+                    # contending with the submitters for nothing); a short
+                    # bucket behind a dispatch of its own model is nobody's
+                    # to take (``_ready``): that dispatch's return notifies
+                    self._cond.notify()
         if shed_depth is None:
             # GraftBox: the submit door records straight to the flight
             # ring (trace.on or not, and outside the lock) — a SIGKILLed
@@ -355,37 +426,106 @@ class BucketedMicrobatcher:
             timeout_s = self.request_timeout_s + 30.0
         return self.submit_nowait(model, line).wait(timeout_s)
 
-    # -- dispatch loop (one thread) ------------------------------------------
+    # -- dispatch loop (DEPTH threads) ----------------------------------------
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
             if b >= n:
                 return b
         return self.max_bucket
 
+    @property
+    def _dispatching(self) -> bool:
+        # the parent's flag, by its name: tests/test_tenancy.py polls it
+        return bool(self._flights)
+
+    def _in_flight(self, model: str) -> bool:
+        """Whether a dispatch in flight carries a batch of ``model`` (under
+        ``_cond``)."""
+        return any(name == model
+                   for flight in self._flights for name, _ in flight.batches)
+
     def _ready(self, now: float) -> List[str]:
+        """The models a dispatcher may take a batch of now (under
+        ``_cond``).  A full ``max(bucket)`` always; a short one — past the
+        flush deadline, or whatever is left at close — only when no
+        dispatch in flight carries a batch of the SAME model, which is when
+        a single dispatcher would have looked at it.  A deadline that
+        cannot cut while the model has something in flight lets a straggler
+        rejoin its group, so callers of twice a bucket settle into full
+        buckets, not into fragments (PERF.md §6, PR 26 finding 2, is what a
+        deadline that may cut did); a quiet model beside a saturated one is
+        not held back by the neighbour's flights."""
         out = []
         for name, queue in self._queues.items():
             if not queue:
                 continue
-            if (len(queue) >= self.max_bucket
-                    or now - queue[0].enqueued >= self.flush_deadline_s):
+            if len(queue) >= self.max_bucket or (
+                    (self._stop
+                     or now - queue[0].enqueued >= self.flush_deadline_s)
+                    and not self._in_flight(name)):
                 out.append(name)
         return out
 
     def _next_wait(self, now: float) -> Optional[float]:
+        # a short bucket's deadline is not looked at before its model's
+        # dispatch in flight returns, and that return notifies
         deadlines = [queue[0].enqueued + self.flush_deadline_s - now
-                     for queue in self._queues.values() if queue]
+                     for name, queue in self._queues.items()
+                     if queue and not self._in_flight(name)]
         if not deadlines:
             return None                   # sleep until a submit notifies
         return max(min(deadlines), 0.0)
 
+    def _wake(self) -> None:
+        """One pass of the ``serve.heartbeat`` drill site (under ``_cond``):
+        once at start and once after every dispatch — the loop wakes of the
+        single dispatcher the site was counted on.  The drill wedges the
+        batcher: BOTH threads exit WITHOUT finishing pending work, the
+        heartbeat goes stale and the pool's deadline detection is what has
+        to reap the stranded queue."""
+        self.heartbeat = time.monotonic()
+        if self.fault is not None and not self._halt:
+            try:
+                self.fault.hit("serve.heartbeat")
+            except InjectedFault:
+                self._halt = True
+                self._cond.notify_all()
+
+    def _take(self) -> Optional[_Flight]:
+        """Block until this thread may take a dispatch; pop one batch of
+        every ready model and return them registered in flight.  None when
+        the thread is to end: closed and drained, died, or wedged."""
+        with self._cond:
+            while True:
+                self.heartbeat = time.monotonic()
+                if self._halt:
+                    return None
+                ready = self._ready(time.monotonic())
+                if ready:
+                    break
+                if self._stop and not any(self._queues.values()):
+                    return None
+                self._cond.wait(timeout=self._next_wait(time.monotonic()))
+            batches = []
+            for name in ready:
+                queue = self._queues[name]
+                take = min(len(queue), self.max_bucket)
+                batches.append((name, [queue.popleft() for _ in range(take)]))
+            flight = _Flight(batches, ahead=len(self._flights))
+            self._flights.append(flight)
+            if any(self._queues.values()):
+                # what is left is the other thread's to time: it may be
+                # asleep with no deadline, or with this model's
+                self._cond.notify()
+            return flight
+
     def _loop(self) -> None:
         with contextlib.ExitStack() as stack:
             if self.tenant:
-                # the dispatcher works AS the tenant: every span, gauge
+                # a dispatcher works AS the tenant: every span, gauge
                 # and recompile event it journals carries the label, so
                 # one merged fleet view attributes this plane's serving
-                # cost to its owner
+                # cost to its owner (the label is the thread's own)
                 stack.enter_context(tel.label_scope(tenant=self.tenant))
             if self.device is not None:
                 import jax
@@ -395,70 +535,44 @@ class BucketedMicrobatcher:
                 # committed elsewhere still win — jax array placement)
                 stack.enter_context(jax.default_device(self.device))
             while True:
-                with self._cond:
-                    self.heartbeat = time.monotonic()
-                    if self.fault is not None:
-                        try:
-                            self.fault.hit("serve.heartbeat")
-                        except InjectedFault:
-                            # the wedged-dispatcher drill: exit WITHOUT
-                            # finishing pending work — the heartbeat goes
-                            # stale and the pool's deadline detection is
-                            # what has to reap the stranded queue
-                            return
-                    while not self._stop and \
-                            not self._ready(time.monotonic()):
-                        self._cond.wait(
-                            timeout=self._next_wait(time.monotonic()))
-                        self.heartbeat = time.monotonic()
-                    if self._stop and not any(self._queues.values()):
-                        return
-                    ready = ([name for name, q in self._queues.items() if q]
-                             if self._stop
-                             else self._ready(time.monotonic()))
-                    batches: List[Tuple[str, List[PendingRequest]]] = []
-                    for name in ready:
-                        queue = self._queues[name]
-                        take = min(len(queue), self.max_bucket)
-                        batches.append((name,
-                                        [queue.popleft()
-                                         for _ in range(take)]))
-                    self._active = [r for _, rs in batches for r in rs]
-                    self._dispatching = True
+                flight = self._take()
+                if flight is None:
+                    return
                 try:
-                    for i, (name, reqs) in enumerate(batches):
-                        # refreshed PER BATCH (lock-free: a float store
-                        # is atomic under the GIL, and the monitor only
-                        # compares staleness) so a dispatcher working
+                    for name, reqs in flight.batches:
+                        # refreshed PER BATCH so a dispatcher working
                         # through several slow batches reads as busy,
                         # not wedged — only true silence past the
                         # deadline is a miss
-                        self.heartbeat = time.monotonic()
+                        flight.tick()
                         try:
                             # GraftBox: a dispatch that wedges (stuck
                             # device call, deadlocked arbiter) trips the
                             # progress watchdog and captures a bundle
                             with blackbox.watchdog_guard("serve.dispatch"):
-                                self._dispatch(name, reqs)
+                                self._dispatch(name, reqs, flight)
                         except Exception:  # noqa: BLE001
                             # replica-fatal, injected (serve.dispatch
                             # kill) or real: every unfinished request
-                            # (this batch + everything queued) fails
-                            # RETRYABLE so the pool can re-enqueue it on
-                            # a survivor — waiting for the heartbeat
-                            # deadline to reap a silently-dead loop
-                            # would stall them for seconds instead
-                            self._die([r for _, rs in batches[i:]
-                                       for r in rs])
+                            # (both dispatches in flight + everything
+                            # queued) fails RETRYABLE so the pool can
+                            # re-enqueue it on a survivor — waiting for
+                            # the heartbeat deadline to reap a
+                            # silently-dead loop would stall them for
+                            # seconds instead
+                            self._die()
                             return
                 finally:
                     with self._cond:
-                        self._dispatching = False
-                        self._active = []
-                        self.heartbeat = time.monotonic()
+                        self._flights.remove(flight)
+                        self._wake()
+                        # the other thread may now look at a short bucket
+                        self._cond.notify_all()
 
-    def _dispatch(self, model: str, reqs: List[PendingRequest]) -> None:
-        """One batch under its ``serve.dispatch`` span, then one
+    def _dispatch(self, model: str, reqs: List[PendingRequest],
+                  flight: _Flight) -> None:
+        """One batch under its ``serve.dispatch`` span (``inflight``: the
+        dispatches already in flight when it was taken, 0 or 1), then one
         retroactive ``serve.queue`` span a request: appended to the queue →
         taken by this dispatch (the span's start — batches popped together
         wait their turn in the queue span), linked by ``dispatch``."""
@@ -467,9 +581,10 @@ class BucketedMicrobatcher:
         # (under a ScoringPlane stage: the stage's own trace)
         ctx = next((r.trace_ctx for r in reqs if r.trace_ctx is not None),
                    None)
-        with tracer.span("serve.dispatch", {"model": model},
+        with tracer.span("serve.dispatch",
+                         {"model": model, "inflight": flight.ahead},
                          parent=ctx) as span:
-            self._dispatch_batch(model, reqs, span)
+            self._dispatch_batch(model, reqs, span, flight)
         if span.enabled:
             for req in reqs:
                 if req.probe:
@@ -482,7 +597,7 @@ class BucketedMicrobatcher:
                                  start=req.queued)
 
     def _dispatch_batch(self, model: str, reqs: List[PendingRequest],
-                        span) -> None:
+                        span, flight: _Flight) -> None:
         scorable = [r for r in reqs if not r.probe]
         for req in reqs:
             if req.probe:
@@ -495,7 +610,10 @@ class BucketedMicrobatcher:
         if self.fault is not None:
             # the replica-kill site: fires BEFORE any request of the
             # batch scores (InjectedFault propagates to _loop → _die),
-            # so an injected death can never double-score a request
+            # so an injected death can never double-score a request of
+            # THIS batch; the other dispatch in flight is failed over with
+            # it and its late replies are dropped (``finish`` is idempotent
+            # and ``_reply`` reports only what it finished)
             self.fault.hit("serve.dispatch")
         group = f"Serving.{model}"
         now = time.monotonic()
@@ -522,15 +640,18 @@ class BucketedMicrobatcher:
             # fair-queued pool.  Un-tenanted batchers pass through (the
             # shared null context).  The slot wait is bounded by the
             # request timeout (a tenant paced past it sheds typed rather
-            # than stranding requests) and ticks the heartbeat while
-            # queued — being PACED is not being WEDGED, and the pool's
-            # deadline watch must not reap a merely-contended replica.
+            # than stranding requests) and ticks the dispatch's heartbeat
+            # while queued — being PACED is not being WEDGED, and the
+            # pool's deadline watch must not reap a merely-contended
+            # replica.  Where the contract grants one slot the second
+            # dispatch in flight waits here and the batcher runs as with
+            # one dispatcher.
             with contextlib.ExitStack() as held:
                 with tel.tracer().span("serve.slot"):
                     held.enter_context(tenancy.pool().slot(
                         tenant=self.tenant or None,
                         timeout_s=self.request_timeout_s,
-                        on_wait=self._beat))
+                        on_wait=flight.tick))
                 t0 = time.monotonic()
                 outs = entry.score_lines([r.line for r in live], bucket)
                 dispatch_s = time.monotonic() - t0
@@ -553,7 +674,7 @@ class BucketedMicrobatcher:
             # re-score each request alone (smallest bucket — warmed, so no
             # recompile) so only the genuinely bad ones fail typed
             if len(live) > 1:
-                self._dispatch_isolated(entry, group, live)
+                self._dispatch_isolated(entry, group, live, flight)
                 return
             self.counters.increment(group, "errors")
             err = (exc if isinstance(exc, ServingError)
@@ -561,16 +682,19 @@ class BucketedMicrobatcher:
             live[0].finish(error=self._attribute(
                 err, wait_s=time.monotonic() - live[0].enqueued))
             return
-        prev = self._dispatch_ewma.get(model)
-        self._dispatch_ewma[model] = (
-            dispatch_s if prev is None else 0.8 * prev + 0.2 * dispatch_s)
+        with self._cond:
+            prev = self._dispatch_ewma.get(model)
+            self._dispatch_ewma[model] = (
+                dispatch_s if prev is None
+                else 0.8 * prev + 0.2 * dispatch_s)
         if self.on_batch_ok is not None:
             self.on_batch_ok()
-        self._finish_scored(entry, group, model, live, outs, bucket,
+        self._finish_scored(entry, group, model, live, outs, bucket, flight,
                             dispatch_s)
 
     def _dispatch_isolated(self, entry, group: str,
-                           reqs: List[PendingRequest]) -> None:
+                           reqs: List[PendingRequest],
+                           flight: _Flight) -> None:
         """Failure-isolation path: score each request of a failed batch
         alone; good rows still succeed, bad rows carry their own error."""
         model = reqs[0].model
@@ -579,7 +703,7 @@ class BucketedMicrobatcher:
             try:
                 with tenancy.pool().slot(tenant=self.tenant or None,
                                          timeout_s=self.request_timeout_s,
-                                         on_wait=self._beat):
+                                         on_wait=flight.tick):
                     outs = entry.score_lines([req.line], bucket)
             except TenantShedError as exc:
                 self.counters.increment(group, "shed")
@@ -597,18 +721,20 @@ class BucketedMicrobatcher:
                 continue
             if self.on_batch_ok is not None:
                 self.on_batch_ok()
-            self._finish_scored(entry, group, model, [req], outs, bucket)
+            self._finish_scored(entry, group, model, [req], outs, bucket,
+                                flight)
 
     def _finish_scored(self, entry, group: str, model: str,
                        live: List[PendingRequest], outs: List[str],
-                       bucket: int,
+                       bucket: int, flight: _Flight,
                        dispatch_s: Optional[float] = None) -> None:
         with tel.tracer().span("serve.reply"):
-            self._reply(entry, group, model, live, outs, bucket, dispatch_s)
+            self._reply(entry, group, model, live, outs, bucket, flight,
+                        dispatch_s)
 
     def _reply(self, entry, group: str, model: str,
                live: List[PendingRequest], outs: List[str], bucket: int,
-               dispatch_s: Optional[float]) -> None:
+               flight: _Flight, dispatch_s: Optional[float]) -> None:
         # a shape outside the warmed set means this batch paid a compile
         # on the hot path — the invariant violation the counter exposes
         # (the monitor's key feed also registers each key as a GraftProf
@@ -621,14 +747,20 @@ class BucketedMicrobatcher:
         if prof.enabled:
             # the program this batch dispatched: the entry's compile key
             # for this bucket (every entry keys on (bucket, ...))
-            pkey = next((k for k in entry.compile_keys
+            # (a snapshot: the other dispatch in flight may add a key)
+            pkey = next((k for k in tuple(entry.compile_keys)
                          if k and k[0] == bucket), (bucket,))
             pid = prof_mod.program_id(model, pkey)
             if dispatch_s is not None:
                 prof.sample(pkey, model, dispatch_s)
         tracker = self.latency[model]
+        answered = 0
         for req, out in zip(live, outs):
-            req.finish(result=out)
+            if not req.finish(result=out):
+                # failed over by ``_die`` while this batch scored: it is
+                # another replica's request now, never reported here too
+                continue
+            answered += 1
             wait_s = done - req.enqueued
             tracker.record(wait_s)
             if tracer.enabled:
@@ -648,18 +780,16 @@ class BucketedMicrobatcher:
                     attrs["program"] = pid
                 tracer.emit_span("serve.request", wait_s,
                                  parent=req.trace_ctx, attrs=attrs)
-        self.counters.increment(group, "requests", len(live))
+        if not answered:
+            return
+        self.counters.increment(group, "requests", answered)
         self.counters.increment(group, "batches")
+        if flight.ahead:
+            # counted where ``batches`` is, so the two are one population
+            self.counters.increment(group, "overlapped")
         self.counters.increment(group, f"bucket.{bucket}")
         if tracer.enabled:
             tracer.gauge(f"serve.queue.{model}", len(self._queues[model]))
-
-    def _beat(self) -> None:
-        """Heartbeat tick while queued on the tenant arbiter (a float
-        store is atomic under the GIL — same contract as the per-batch
-        refresh in ``_loop``): a paced dispatcher reads as busy, never
-        as wedged, so only true silence past the deadline is a miss."""
-        self.heartbeat = time.monotonic()
 
     # -- replica failure machinery (FleetServe, round 17) --------------------
     def _attribute(self, err: ServingError,
@@ -699,15 +829,18 @@ class BucketedMicrobatcher:
             err, wait_s=(time.monotonic() - req.enqueued)
             if req is not None else None)
 
-    def _die(self, stranded: List[PendingRequest]) -> None:
+    def _die(self) -> None:
         """serve.dispatch kill: mark the replica failed (new submissions
-        are refused at the door) and fail every unfinished request —
-        ``stranded`` (popped but unscored) plus everything still queued —
-        with the RETRYABLE :class:`ReplicaDownError`, the pool's cue to
-        re-enqueue them on survivors.  ``finish`` is idempotent, so a
-        request that already scored can never be re-failed here."""
+        are refused at the door), end both dispatcher threads and fail
+        every unfinished request — those of EVERY dispatch in flight
+        (popped but unanswered) plus everything still queued — with the
+        RETRYABLE :class:`ReplicaDownError`, the pool's cue to re-enqueue
+        them on survivors.  ``finish`` is idempotent, so a request that
+        already scored can never be re-failed here."""
         with self._cond:
             self.failed = True
+            self._halt = True
+            stranded = [r for f in self._flights for r in f.requests()]
             queued = [r for q in self._queues.values() for r in q]
             for q in self._queues.values():
                 q.clear()
@@ -735,21 +868,30 @@ class BucketedMicrobatcher:
         return len(reqs)
 
     def stalled(self, deadline_s: float) -> bool:
-        """True when the dispatcher has WORK but its heartbeat is older
-        than ``deadline_s`` — a wedged (or silently dead) dispatcher.
-        An idle batcher is never stalled: with nothing to dispatch a
-        stale heartbeat is just sleep."""
+        """True when the batcher has WORK but a heartbeat older than
+        ``deadline_s`` — a wedged (or silently dead) dispatcher.  With
+        dispatches in flight it is the OLDEST of their heartbeats that
+        counts, so one wedged dispatch shows while the other beats; with
+        none, the idle threads' last wake.  An idle batcher is never
+        stalled: with nothing to dispatch a stale heartbeat is just
+        sleep."""
         with self._cond:
             busy = self._dispatching or any(self._queues.values())
+            beat = min((f.beat for f in self._flights),
+                       default=self.heartbeat)
             return busy and \
-                (time.monotonic() - self.heartbeat) > float(deadline_s)
+                (time.monotonic() - beat) > float(deadline_s)
 
     def probe(self, timeout_s: float = 5.0) -> bool:
         """Breaker half-open liveness probe: push a no-op request through
-        the REAL dispatch queue and wait for the dispatcher to answer it.
-        True = the dispatch thread is alive and draining (the breaker may
+        the REAL dispatch queue and wait for a dispatcher to answer it
+        (a short bucket of the first model: it rides in that model's next
+        full bucket, or goes out alone once the model has nothing in flight
+        and a thread is free; a dispatch in flight that has not beaten for
+        ``timeout_s`` fails the probe whichever thread answered it).  True
+        = the dispatch threads are alive and draining (the breaker may
         close); False = dead, wedged, or closed (stay open)."""
-        if self.failed or not self._thread.is_alive():
+        if self.failed or not all(t.is_alive() for t in self._threads):
             return False
         model = next(iter(self._queues), None)
         if model is None:
@@ -762,9 +904,11 @@ class BucketedMicrobatcher:
             self._cond.notify()
         try:
             req.wait(timeout_s)
-            return True
         except ServingError:
             return False
+        # answered by one thread: the other's dispatch, silent for longer
+        # than the probe would have waited, is a wedged dispatcher still
+        return not self.stalled(timeout_s)
 
     def health(self) -> Dict[str, object]:
         """The ``/healthz`` body: readiness (warmed AND not failed),
@@ -809,21 +953,24 @@ class BucketedMicrobatcher:
                     "age_ms": round((now - req.enqueued) * 1e3, 1)}
 
         with self._cond:
-            rows = [row(r, "dispatching") for r in self._active]
+            rows = [row(r, "dispatching")
+                    for f in self._flights for r in f.requests()]
             for q in self._queues.values():
                 rows.extend(row(r, "queued") for r in q)
         return rows[:512]
 
     def close(self) -> None:
-        """Flush every pending request, then stop the dispatcher.  A
-        dead/wedged dispatcher cannot flush — its leftovers fail typed
+        """Flush every pending request, then stop the dispatchers.  Dead
+        or wedged dispatchers cannot flush — their leftovers fail typed
         (:class:`ReplicaDownError`) instead of hanging their callers."""
         with self._cond:
             if self._stop:
                 return
             self._stop = True
             self._cond.notify_all()
-        self._thread.join(timeout=60.0)
+        deadline = time.monotonic() + 60.0
+        for thread in self._threads:
+            thread.join(timeout=max(deadline - time.monotonic(), 0.0))
         if self.fail_pending("batcher closed with a dead dispatcher"):
             self.failed = True
         blackbox.unregister_provider(self._bb_name)

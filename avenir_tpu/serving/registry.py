@@ -400,14 +400,14 @@ class KNNServable(ServableModel):
         with tracer.span("servable.encode"):
             ds = _pad_ds(self.enc.transform(rows, with_labels=False), pad_to)
         self.compile_keys.add((pad_to,))
-        refused = self.model.cert_fallback_rows
         result = self.est.predict(self.model, ds)
-        refused = self.model.cert_fallback_rows - refused
-        if refused:
+        if result.refused:
             # the exact scan that answers refused rows is one compiled
             # program per COUNT of them: a count first met on the hot path
-            # is a recompile the batcher's monitor has to see
-            self.compile_keys.add(("fallback", refused))
+            # is a recompile the batcher's monitor has to see.  The count
+            # is this call's own (``KNNResult.refused``): the model's
+            # counters also move with the other dispatch in flight
+            self.compile_keys.add(("fallback", result.refused))
         with tracer.span("servable.format"):
             return [
                 f"{line}{self.delim}"

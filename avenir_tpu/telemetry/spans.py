@@ -71,8 +71,8 @@ _CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
     "avenir_tpu_current_span", default=None)
 
 # the in-memory recorder keeps this many closed spans and drops the oldest
-# beyond it (a 20 s serving window at 800 requests/s makes ~25 k)
-RECORDER_CAPACITY = 1 << 17
+# beyond it (a 20 s serving window at 6 000 requests/s makes ~146 k: PR 33)
+RECORDER_CAPACITY = 1 << 19
 
 
 class SpanRecord(NamedTuple):
@@ -651,11 +651,15 @@ class CompileKeyMonitor:
         self.auto_prime = auto_prime
         self._known: set = set()
         self._primed = False
+        # the serving batcher observes from two dispatcher threads: a key
+        # is fresh to exactly one of them
+        self._lock = threading.Lock()
 
     def prime(self, keys: Iterable) -> None:
         keys = set(keys)
-        self._known |= keys
-        self._primed = True
+        with self._lock:
+            self._known |= keys
+            self._primed = True
         self._register_programs(keys)
 
     def _register_programs(self, keys) -> None:
@@ -679,13 +683,15 @@ class CompileKeyMonitor:
     def observe(self, keys: Iterable) -> int:
         """Fold ``keys`` into the known set; returns (and accounts) how
         many were fresh."""
-        fresh = set(keys) - self._known
-        if not fresh:
-            return 0
-        self._known |= fresh
-        self._register_programs(fresh)
-        if self.auto_prime and not self._primed:
+        with self._lock:
+            fresh = set(keys) - self._known
+            if not fresh:
+                return 0
+            self._known |= fresh
+            first = self.auto_prime and not self._primed
             self._primed = True
+        self._register_programs(fresh)
+        if first:
             return 0
         if self.counters is not None:
             self.counters.increment(self.group, "recompiles", len(fresh))

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import logging
 import random
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
@@ -217,11 +218,13 @@ class FaultPlan:
     - ``fault.serve.dispatch.crash.after`` — raise on the N-th serving
       batch dispatch, BEFORE any request of the batch scores (FleetServe
       round 17: the batcher treats it as replica-fatal — the whole
-      replica dies mid-batch and its in-flight requests fail over);
+      replica dies mid-batch and the requests of every dispatch in
+      flight fail over);
     - ``fault.serve.heartbeat.crash.after`` — wedge the serving
-      dispatcher on its N-th loop wake: the thread exits WITHOUT
-      finishing pending work, so the replica's heartbeat goes stale and
-      the pool's deadline detection is what has to catch it;
+      dispatcher on its N-th loop wake (one at start, one after every
+      dispatch): both dispatcher threads exit WITHOUT finishing pending
+      work, so the replica's heartbeat goes stale and the pool's deadline
+      detection is what has to catch it;
     - ``fault.tenant.flood.after`` — the GraftPool noisy-tenant drill
       (round 18): fire on a tenant workload's N-th pacing boundary.  The
       workload driver (``benchmarks/tenancy_soak.py``) treats the raise
@@ -248,6 +251,9 @@ class FaultPlan:
                          if int(n) > 0}
         self.hits = {site: 0 for site in self.SITES}
         self.faults_fired = 0
+        # a site is passed by several threads (a batcher's two
+        # dispatchers, every replica of a pool): the N-th pass is one pass
+        self._lock = threading.Lock()
 
     @classmethod
     def from_conf(cls, conf) -> Optional["FaultPlan"]:
@@ -275,16 +281,19 @@ class FaultPlan:
         if site not in self.hits:
             raise ValueError(f"unknown fault site {site!r}; "
                              f"known: {self.SITES}")
-        self.hits[site] += 1
-        if self.hits[site] == self.schedule.get(site, 0):
-            self.faults_fired += 1
+        with self._lock:
+            self.hits[site] += 1
+            hit = self.hits[site]
+            fire = hit == self.schedule.get(site, 0)
+            if fire:
+                self.faults_fired += 1
+        if fire:
             from avenir_tpu.telemetry import spans as tel
 
-            tel.tracer().event("fault.injected", site=site,
-                               hit=self.hits[site])
+            tel.tracer().event("fault.injected", site=site, hit=hit)
             raise InjectedFault(
-                f"fault.{site}.crash.after={self.hits[site]}: injected "
-                f"crash at {site} boundary {self.hits[site]}")
+                f"fault.{site}.crash.after={hit}: injected "
+                f"crash at {site} boundary {hit}")
 
 
 @dataclass

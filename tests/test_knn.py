@@ -248,3 +248,123 @@ def test_nearest_neighbors_mesh_matches_local(rng):
     np.testing.assert_allclose(d_t, d_loc, rtol=1e-5, atol=1e-6)
     for q in range(m):
         assert set(i_t[q]) == set(i_loc[q]), q
+
+
+# ---------------------------------------------------------------------------
+# one model searched from two threads (the serving batcher keeps two
+# dispatches in flight): the tally and a call's own refused count
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def stub_fused(monkeypatch):
+    """The fused route with a stubbed launch: exact answers by brute force,
+    the first ``refuse(rows)`` rows of a call failing their certificate."""
+    from avenir_tpu.ops import pallas_knn
+
+    state = {"refuse": lambda rows: 0, "enter": lambda rows: None}
+
+    def fake(codes_q, cont01_q, r_mat, codes_r, cont01_r, n, nb, k, attrs):
+        sub, refs = np.asarray(cont01_q), np.asarray(cont01_r)
+        state["enter"](sub.shape[0])
+        d2 = ((sub[:, None, :] - refs[None, :, :]) ** 2).sum(-1)
+        idx = np.argsort(d2, axis=1, kind="stable")[:, :k]
+        d = np.sqrt(np.take_along_axis(d2, idx, 1) / max(attrs, 1))
+        cert = np.ones(sub.shape[0], bool)
+        cert[:state["refuse"](sub.shape[0])] = False
+        return d.astype(np.float32), idx.astype(np.int32), cert
+
+    monkeypatch.setattr(knn_mod, "_pallas_available", lambda metric, k: True)
+    monkeypatch.setattr(pallas_knn, "fused_serves", lambda n_real, k: True)
+    monkeypatch.setattr(pallas_knn, "prepare_refs",
+                        lambda codes, cont01, nb: (None, cont01.shape[0]))
+    monkeypatch.setattr(pallas_knn, "search_fused", fake)
+    return state
+
+
+def test_fused_tally_loses_no_update_under_threads(elearn, stub_fused,
+                                                   monkeypatch):
+    """N threads through the fused route's counting: the four counters move
+    together under the model's lock, so ``tourney_rows == fused_rows`` (the
+    benchmark's ``cert_fallback_pct`` reader raises otherwise) and every
+    total is exact."""
+    import sys
+    import threading
+
+    train, test = elearn
+    model = knn_mod.fit_knn(train.slice(0, 64))
+    stub_fused["refuse"] = lambda rows: 1
+    # the exact scan of the refused row is not under test: answer at once
+    monkeypatch.setattr(
+        knn_mod, "_nearest_neighbors_xla",
+        lambda m, sub, k: (np.zeros((sub.num_rows, k), np.float32),
+                           np.zeros((sub.num_rows, k), np.int32)))
+    threads, searches, rows = 8, 150, 3
+    batch = test.slice(0, rows)
+    errors = []
+
+    def worker():
+        try:
+            for _ in range(searches):
+                counts = {}
+                knn_mod.nearest_neighbors(model, batch, 3, counts=counts)
+                assert counts == {"refused": 1}
+        except Exception as exc:          # noqa: BLE001 — read after join
+            errors.append(exc)
+
+    pool = [threading.Thread(target=worker) for _ in range(threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(120.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    total = threads * searches * rows
+    assert (model.fused_rows, model.tourney_rows) == (total, total)
+    assert model.cert_fallback_rows == threads * searches
+    assert model.shard_fused_rows == 0
+
+
+def test_servable_adds_the_fallback_key_of_its_own_call(elearn, stub_fused):
+    """Two ``score_lines`` calls of one KNNServable interleave (both inside
+    the search at once): each adds ``("fallback", n)`` for the rows ITS
+    search refused — not a difference of the model's shared counter, which
+    holds the other call's rows too and would name a program that never
+    compiled (a false ``recompiles``)."""
+    import threading
+
+    from avenir_tpu.datagen.elearn import generate_elearn
+    from avenir_tpu.serving.registry import KNNServable
+
+    schema = FeatureSchema.from_json(ELEARN_SCHEMA_JSON)
+    rows = generate_elearn(80, seed=11)
+    enc = DatasetEncoder(schema)
+    est = KNN(k=3, kernel="gaussian")
+    servable = KNNServable(est, est.fit(enc.fit_transform(rows[:64])), enc)
+    both_inside = threading.Barrier(2, timeout=20.0)
+    stub_fused["enter"] = lambda n: both_inside.wait()
+    stub_fused["refuse"] = lambda n: {8: 2, 4: 1}[n]
+    lines = [",".join(r[:-1]) for r in rows[64:]]
+    outs, errors = {}, []
+
+    def call(n):
+        try:
+            outs[n] = servable.score_lines(lines[:n], n)
+        except Exception as exc:          # noqa: BLE001 — read after join
+            errors.append(exc)
+
+    pool = [threading.Thread(target=call, args=(n,)) for n in (8, 4)]
+    for t in pool:
+        t.start()
+    for t in pool:
+        t.join(60.0)
+        assert not t.is_alive()
+    assert not errors, errors
+    assert len(outs[8]) == 8 and len(outs[4]) == 4
+    assert servable.compile_keys == {(8,), (4,), ("fallback", 2),
+                                     ("fallback", 1)}
+    assert servable.model.cert_fallback_rows == 3

@@ -13,6 +13,7 @@ stage, and the shared RL-loop metrics schema.
 import json
 import os
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -29,11 +30,16 @@ from avenir_tpu.serving import (
     ModelRegistry,
     QueueScoreFrontend,
     RequestError,
+    ReplicaDownError,
     RequestTimeout,
     ScoreHTTPServer,
+    ServableModel,
     ShedError,
     UnknownModelError,
 )
+from avenir_tpu.telemetry import spans as tel
+from avenir_tpu.telemetry.journal import read_events
+from avenir_tpu.utils.retry import FaultPlan
 
 
 # ---------------------------------------------------------------------------
@@ -547,3 +553,322 @@ def test_latency_tracker_ring():
     assert 0.092 <= tr.percentile(50) <= 0.099
     snap = tr.snapshot()
     assert snap["latency_samples"] == 100 and snap["p99_ms"] >= snap["p50_ms"]
+
+
+# ---------------------------------------------------------------------------
+# two dispatches in flight (PR 33)
+# ---------------------------------------------------------------------------
+
+class _GatedServable(ServableModel):
+    """``score_lines`` blocks on an event of its own: a test sees each call
+    enter (``entered``), holds it in flight for as long as it likes and
+    releases it by its index (``release``)."""
+
+    family = "gated"
+
+    def __init__(self):
+        super().__init__()
+        self.calls = []                   # the lines of each call, by entry
+        self.gates = []
+        self.entered = threading.Semaphore(0)
+        self._lock = threading.Lock()
+
+    def score_lines(self, lines, pad_to):
+        gate = threading.Event()
+        with self._lock:
+            self.calls.append(list(lines))
+            self.gates.append(gate)
+        self.entered.release()
+        assert gate.wait(20.0), "the test never released this call"
+        self.compile_keys.add((pad_to,))
+        return [f"{line},ok" for line in lines]
+
+    def warmup(self, pad_to):
+        self.compile_keys.add((pad_to,))
+
+    def wait_entered(self, timeout=5.0):
+        return self.entered.acquire(timeout=timeout)
+
+    def release(self, call):
+        self.gates[call].set()
+
+
+GATED_BUCKET = 4
+GATED_DEADLINE_S = 0.005
+
+
+def _gated(**kwargs):
+    servable = _GatedServable()
+    batcher = BucketedMicrobatcher(
+        ModelRegistry().add("m", servable), bucket_sizes=(1, 2, GATED_BUCKET),
+        flush_deadline_ms=GATED_DEADLINE_S * 1e3, request_timeout_ms=20_000.0,
+        **kwargs)
+    return servable, batcher
+
+
+def _submit(batcher, tag, n):
+    return [batcher.submit_nowait("m", f"{tag}{i}") for i in range(n)]
+
+
+def _finish_all(servable, batcher, reqs):
+    """Release everything still gated and collect every reply."""
+    closer = threading.Thread(target=batcher.close)
+    closer.start()
+    deadline = time.monotonic() + 20.0
+    while closer.is_alive() and time.monotonic() < deadline:
+        for gate in list(servable.gates):
+            gate.set()
+        time.sleep(0.005)
+    closer.join(5.0)
+    assert not closer.is_alive()
+    return [r.wait(5.0) for r in reqs]
+
+
+def _dispatch_spans(journal_path):
+    return [e["attrs"] for e in read_events(journal_path)
+            if e.get("ev") == "span.close" and e.get("name") == "serve.dispatch"]
+
+
+@pytest.fixture
+def traced(tmp_path):
+    tracer = tel.tracer().enable(str(tmp_path))
+    try:
+        yield tracer
+    finally:
+        tel.tracer().disable()
+
+
+@pytest.mark.parametrize("second,overlaps", [(GATED_BUCKET, True),
+                                             (GATED_BUCKET - 1, False)],
+                         ids=["full-bucket-overlaps", "short-bucket-waits"])
+def test_second_dispatch_in_flight_takes_only_a_full_bucket(
+        traced, second, overlaps):
+    """While one dispatch is in flight a second FULL bucket is taken at once
+    (counter ``overlapped``, span attr ``inflight`` 1); a SHORT bucket past
+    its flush deadline is not — it goes out the moment the dispatch in
+    flight returns, which is when one dispatcher would have looked at it."""
+    servable, b = _gated()
+    reqs = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()
+    reqs += _submit(b, "b", second)
+    time.sleep(10 * GATED_DEADLINE_S)             # far past the deadline
+    assert servable.wait_entered(timeout=0.2) is overlaps
+    if not overlaps:
+        assert b.queue_depths() == {"m": second}
+        assert len(servable.calls) == 1
+        servable.release(0)
+        assert servable.wait_entered()            # taken as call 0 returns
+    assert servable.calls[1] == [f"b{i}" for i in range(second)]
+    outs = _finish_all(servable, b, reqs)
+    assert outs == [f"{r.line},ok" for r in reqs]
+    assert b.counters.get("Serving.m", "overlapped") == int(overlaps)
+    assert b.counters.get("Serving.m", "batches") == 2
+    spans = _dispatch_spans(traced.journal_path)
+    assert sorted(s["inflight"] for s in spans) == [0, int(overlaps)]
+
+
+def test_third_full_bucket_waits_for_a_dispatch_to_return():
+    """Depth is two: with two dispatches in flight a third full bucket
+    stays queued until one of them returns."""
+    servable, b = _gated()
+    reqs = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()
+    reqs += _submit(b, "b", GATED_BUCKET)
+    assert servable.wait_entered()
+    reqs += _submit(b, "c", GATED_BUCKET)
+    assert not servable.wait_entered(timeout=0.2)
+    assert b.queue_depths() == {"m": GATED_BUCKET}
+    assert len(b._blackbox_inflight()) == 3 * GATED_BUCKET
+    servable.release(1)                           # either may return first
+    assert servable.wait_entered()
+    assert servable.calls[2] == [f"c{i}" for i in range(GATED_BUCKET)]
+    outs = _finish_all(servable, b, reqs)
+    assert outs == [f"{r.line},ok" for r in reqs]
+    assert b.counters.get("Serving.m", "overlapped") == 2
+    assert b.counters.get("Serving.m", "requests") == 3 * GATED_BUCKET
+
+
+def test_straggler_rejoins_its_group_of_closed_loop_callers():
+    """PERF.md §6, PR 26 finding 2, with 2 x bucket callers: one caller of
+    the second group is late.  The three that wait are NOT cut at their
+    deadline while the first group is in flight, so the straggler completes
+    their bucket; and where a group was cut short (the straggler came after
+    everything had returned) the next full bucket re-forms within two
+    cycles, because a short bucket never goes out beside a dispatch in
+    flight."""
+    servable, b = _gated()
+    first = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()                # group a in flight
+    rest = _submit(b, "b", GATED_BUCKET - 1)
+    time.sleep(10 * GATED_DEADLINE_S)
+    assert len(servable.calls) == 1               # not cut at the deadline
+    late = _submit(b, "late", 1)
+    assert servable.wait_entered()
+    assert servable.calls[1] == ["b0", "b1", "b2", "late0"]
+    servable.release(0)
+    servable.release(1)
+    assert [r.wait(5.0) for r in first + rest + late]
+    # cycle 2, the straggler later still: nothing is in flight when the
+    # three pass their deadline, so they go out short (as with one
+    # dispatcher) ...
+    rest = _submit(b, "d", GATED_BUCKET - 1)
+    assert servable.wait_entered()
+    assert servable.calls[2] == ["d0", "d1", "d2"]
+    first = _submit(b, "c", GATED_BUCKET)         # ... beside a full bucket,
+    assert servable.wait_entered()
+    late = _submit(b, "late", 1)                  # and the straggler waits
+    time.sleep(10 * GATED_DEADLINE_S)
+    assert len(servable.calls) == 4
+    servable.release(2)                           # the three come back ...
+    assert [r.wait(5.0) for r in rest]
+    rest = _submit(b, "d", GATED_BUCKET - 1)      # ... and re-submit:
+    assert servable.wait_entered()
+    assert servable.calls[4] == ["late0", "d0", "d1", "d2"]   # full again
+    outs = _finish_all(servable, b, first + late + rest)
+    assert len(outs) == 2 * GATED_BUCKET
+
+
+class _EchoServable(ServableModel):
+    """Answers at once: the quiet model beside a gated, saturated one."""
+
+    family = "echo"
+
+    def score_lines(self, lines, pad_to):
+        self.compile_keys.add((pad_to,))
+        return [f"{line},ok" for line in lines]
+
+    def warmup(self, pad_to):
+        self.compile_keys.add((pad_to,))
+
+
+def _gated_beside_quiet(quiet_first=False):
+    """Two models in one batcher: ``x`` gated (the saturated one) with two
+    dispatches in flight, and one answering at once (the quiet one): ``y``,
+    or ``a`` where it is to be the first of the batcher's models (they are
+    held by name)."""
+    servable = _GatedServable()
+    registry = ModelRegistry().add("x", servable).add(
+        "a" if quiet_first else "y", _EchoServable())
+    b = BucketedMicrobatcher(
+        registry, bucket_sizes=(1, 2, GATED_BUCKET),
+        flush_deadline_ms=GATED_DEADLINE_S * 1e3, request_timeout_ms=20_000.0)
+    reqs = []
+    for tag in "ab":
+        reqs += [b.submit_nowait("x", f"{tag}{i}")
+                 for i in range(GATED_BUCKET)]
+        assert servable.wait_entered()
+    return servable, b, reqs
+
+
+@pytest.mark.parametrize("backlog", [0, 1],
+                         ids=["x-in-flight", "x-in-flight-and-waiting"])
+def test_quiet_models_short_bucket_is_not_held_by_a_saturated_neighbour(
+        backlog):
+    """The 'not while in flight' rule is per model.  ``x`` has a dispatch
+    in flight from before ``y``'s request arrived until after it is
+    answered (call 1 is never released meanwhile), with or without a third
+    full bucket of ``x`` waiting: ``y``'s single request, past its deadline,
+    is popped by the first thread that comes free — with ``x``'s waiting
+    bucket, as one dispatcher pops one batch of every ready model — and
+    answered at most one dispatch later; it gets no RequestTimeout and its
+    caller does not hang."""
+    servable, b, reqs = _gated_beside_quiet()
+    reqs += [b.submit_nowait("x", f"c{i}")
+             for i in range(backlog * GATED_BUCKET)]
+    lone = b.submit_nowait("y", "q")
+    time.sleep(10 * GATED_DEADLINE_S)
+    assert b.queue_depths() == {"x": backlog * GATED_BUCKET, "y": 1}
+    servable.release(0)                           # one thread comes free
+    if backlog:
+        assert servable.wait_entered()            # x's bucket c, popped with
+        assert b.queue_depths() == {"x": 0, "y": 0}     # y's in one take
+        servable.release(2)
+    assert lone.wait(5.0) == "q,ok"
+    assert not servable.gates[1].is_set()         # x was in flight all along
+    assert b.counters.get("Serving.y", "overlapped") == 1
+    assert b.counters.get("Serving.y", "batches") == 1
+    outs = _finish_all(servable, b, reqs)
+    assert outs == [f"{r.line},ok" for r in reqs]
+
+
+@pytest.mark.parametrize("models", [2, 1],
+                         ids=["quiet-model-first", "one-saturated-model"])
+def test_probe_answers_on_a_healthy_saturated_replica(models):
+    """``probe()`` queues a short bucket on the first model.  With two
+    dispatches of ``x`` in flight it is answered as soon as a thread is
+    free: alone where the first model is a quiet one, in ``x``'s next full
+    bucket where ``x`` is the only model — a dispatch of ``x`` still in
+    flight either way."""
+    if models == 2:
+        servable, b, reqs = _gated_beside_quiet(quiet_first=True)
+    else:
+        servable, b = _gated()
+        reqs = []
+        for tag in "ab":
+            reqs += _submit(b, tag, GATED_BUCKET)
+            assert servable.wait_entered()
+    answer = []
+    prober = threading.Thread(target=lambda: answer.append(b.probe(5.0)))
+    prober.start()
+    while not any(r["rid"] == "probe" for r in b._blackbox_inflight()):
+        time.sleep(0.001)
+    if models == 1:
+        reqs += _submit(b, "c", GATED_BUCKET - 1)   # the probe's bucket fills
+    servable.release(0)
+    prober.join(5.0)
+    assert answer == [True]
+    assert not servable.gates[1].is_set()
+    outs = _finish_all(servable, b, reqs)
+    assert outs == [f"{r.line},ok" for r in reqs]
+    assert b.counters.get("Serving.x" if models == 2 else "Serving.m",
+                          "requests") == len(reqs)
+
+
+def test_dispatch_fault_with_two_in_flight_fails_both_and_scores_once():
+    """The ``serve.dispatch`` kill on the SECOND dispatch while the first is
+    in flight: every unfinished request of both, and the queue, fails with
+    the retryable ReplicaDownError; the fault fires before a row of its
+    batch scores; the first dispatch's late replies are dropped (no request
+    is reported scored after it was failed over); both threads end."""
+    servable, b = _gated(fault=FaultPlan({"serve.dispatch": 2}))
+    reqs = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()
+    with b._cond:                                 # six at once: 4 + 2 queued
+        reqs += _submit(b, "b", GATED_BUCKET + 2)
+    for req in reqs:
+        with pytest.raises(ReplicaDownError):
+            req.wait(5.0)
+    assert b.failed and b.queue_depths() == {"m": 0}
+    with pytest.raises(ReplicaDownError):
+        b.submit_nowait("m", "x")
+    servable.release(0)                           # the survivor returns late
+    for thread in b._threads:
+        thread.join(5.0)
+        assert not thread.is_alive()
+    scored = [line for call in servable.calls for line in call]
+    assert scored == [f"a{i}" for i in range(GATED_BUCKET)]
+    assert b.counters.get("Serving.m", "requests") == 0
+    assert b.counters.get("Serving.m", "batches") == 0
+    assert not b.probe(0.2)
+    b.close()
+
+
+def test_stalled_reads_the_oldest_dispatch_in_flight():
+    """One dispatch wedged, the other dispatcher beating: the batcher reads
+    as stalled (the OLDEST heartbeat in flight counts, not the newest)."""
+    servable, b = _gated()
+    reqs = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()                # call 0: wedged
+    time.sleep(0.3)
+    more = _submit(b, "b", GATED_BUCKET)          # the other thread works on
+    assert servable.wait_entered()
+    servable.release(1)
+    assert [r.wait(5.0) for r in more]
+    assert time.monotonic() - b.heartbeat < 0.25  # it beat just now
+    assert b.stalled(0.25)
+    assert not b.stalled(30.0)
+    assert not b.probe(0.25)                      # nor does its probe pass
+    servable.release(0)
+    assert [r.wait(5.0) for r in reqs]
+    assert not b.stalled(0.0)                     # idle is never stalled
+    b.close()
