@@ -56,6 +56,8 @@ SPAN_SITES = {
     'servable.format': 'docs/observability.md',
     'servable.parse': 'docs/observability.md',
     'servable.score': 'docs/observability.md',
+    'serve.backfill.block': 'docs/observability.md',
+    'serve.backfill.queue': 'docs/observability.md',
     'serve.dispatch': 'docs/architecture.md',
     'serve.queue': 'docs/observability.md',
     'serve.reply': 'docs/observability.md',

@@ -502,11 +502,18 @@ def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
         out = launch(_normalize01(test.cont, model.cont_lo, model.cont_hi))
     with tracer.span("knn.readback"):
         d, idx, cert, *by_shard = (np.asarray(a) for a in out)
+    real = m if test.valid_rows is None else int(test.valid_rows)
+    if real < m:
+        # rows past ``valid_rows`` are shape ballast whose answers nobody
+        # reads (serving pads a batch to its bucket or tile with zero rows):
+        # a certificate they fail sends nothing to the exact scan, and they
+        # are no rows of the search's counters
+        cert = cert.copy()
+        cert[real:] = True
     # counted once each whatever the number of shards; the kernel sweeps
     # whole TM-row query tiles, whatever it was handed
     refused = int(cert.size - cert.sum())
-    new_program = model.count_fused(int(cert.size), refused,
-                                    sharded=mesh is not None)
+    new_program = model.count_fused(real, refused, sharded=mesh is not None)
     if counts is not None:
         counts["refused"] = refused
     span.set("kernel_rows", pallas_knn.query_rows(m)).set("refused", refused)

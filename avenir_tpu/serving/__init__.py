@@ -8,7 +8,11 @@ RESP-list front ends expose it; ``ScoringPlane`` replays files through it
 as a pipeline stage.
 """
 
-from avenir_tpu.serving.batcher import BucketedMicrobatcher, PendingRequest
+from avenir_tpu.serving.batcher import (
+    BucketedMicrobatcher,
+    PendingBlock,
+    PendingRequest,
+)
 from avenir_tpu.serving.errors import (
     ReplicaDownError,
     RequestError,
@@ -27,7 +31,7 @@ from avenir_tpu.serving.registry import FAMILIES, ModelRegistry, ServableModel
 from avenir_tpu.serving.replay import ScoringPlane
 
 __all__ = [
-    "BucketedMicrobatcher", "PendingRequest",
+    "BucketedMicrobatcher", "PendingRequest", "PendingBlock",
     "ServingError", "UnknownModelError", "ShedError", "RequestTimeout",
     "RequestError", "ReplicaDownError",
     "QueueScoreFrontend", "ScoreHTTPServer", "redis_score_frontend",

@@ -48,6 +48,25 @@ cell of the benchmark measures: its one serve cell (kNN, 128 callers on a
 reading from outside it leaves Naive Bayes unresolved (PERF.md §6–§7).
 ``submit`` may be called from any number of frontend threads.
 
+Two classes of request on one model (PR 34): beside single rows (``submit``)
+a model whose kernel sweeps a tile larger than the largest bucket
+(``ServableModel.tile_rows``: the fused kNN search pads every dispatch to 512
+query rows) takes whole BLOCKS through :meth:`BucketedMicrobatcher.submit_block`
+— a day's file handed in by its class (a ``tenant.<id>`` contract, the
+``backfill`` of docs/multitenancy.md).  A block waits in a queue of its own,
+bounded in blocks by its class's ``queue.depth``: its rows never count against
+``serve.queue.depth``, are never timed out by ``serve.request.timeout.ms``
+and an online shed never touches them.  Every take puts the waiting online
+rows first (at most ``max(bucket)``, by the rules above, unchanged) and fills
+what is left of the tile with block rows, so the backfill advances under
+always-full online buckets in padding the kernel sweeps anyway.  Block rows
+go alone — a whole tile of them — only when the model has nothing in flight
+(the short bucket's own rule): at most one such dispatch at a time, so an
+online row never waits for more than the dispatch already on the chip, and a
+block never holds the device for longer than one tile.  Rows of a block may
+ride in both dispatches in flight and come back in either order; a block is
+replied whole, and blocks of a model in the order handed in.
+
 FleetServe (round 17): a batcher is now one REPLICA of a
 :class:`~avenir_tpu.serving.pool.ReplicaPool` — ``name`` labels its spans,
 errors and journal events; ``counters``/``latency`` may be shared across
@@ -69,7 +88,8 @@ import contextlib
 import threading
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Deque, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from avenir_tpu import tenancy
 from avenir_tpu.core.config import ConfigError, JobConfig
@@ -157,6 +177,72 @@ class PendingRequest:
         return self.result  # type: ignore[return-value]
 
 
+class PendingBlock:
+    """A block of rows handed in whole through the bulk entry
+    (:meth:`BucketedMicrobatcher.submit_block`); ``wait`` blocks until every
+    row is answered and returns the reply lines in the order given.
+
+    ``tenant`` is the block's CLASS: the ``tenant.<id>`` contract it was
+    handed in under (``backfill``), which bounds the blocks waiting and
+    owns the slot of a dispatch that carries block rows alone.  ``seq``
+    numbers the model's blocks in the order handed in — the order they are
+    replied in.  ``queued`` / ``finished`` are ``time.perf_counter()`` at
+    hand-in and at the last row's reply (the ``serve.backfill.block`` span).
+    ``taken`` and ``answered`` count rows popped by a take and rows replied;
+    they and ``results`` change under the batcher's lock only."""
+
+    __slots__ = ("model", "lines", "tenant", "rid", "seq", "queued",
+                 "finished", "results", "taken", "answered", "error",
+                 "_done", "trace_ctx")
+
+    def __init__(self, model: str, lines: Sequence[str], tenant: str,
+                 rid: Optional[str] = None):
+        self.model = model
+        self.lines = list(lines)
+        self.tenant = tenant
+        self.rid = rid
+        self.seq = 0
+        self.queued = 0.0
+        self.finished = 0.0
+        self.results: List[Optional[str]] = [None] * len(self.lines)
+        self.taken = 0
+        self.answered = 0
+        self.error: Optional[ServingError] = None
+        self._done = threading.Event()
+        self.trace_ctx = tel.tracer().current()
+
+    def done(self) -> bool:
+        return self._done.is_set()
+
+    def wait(self, timeout_s: Optional[float] = None) -> List[str]:
+        if not self._done.wait(timeout_s):
+            raise RequestTimeout(
+                f"no reply for a block of {len(self.lines)} {self.model!r} "
+                f"rows within {timeout_s}s (dispatcher wedged or closed?)")
+        if self.error is not None:
+            raise self.error
+        return self.results  # type: ignore[return-value]
+
+
+def _typed(exc: BaseException) -> ServingError:
+    """A scoring failure as the typed error its request (or block) carries."""
+    return (exc if isinstance(exc, ServingError)
+            else RequestError(f"{type(exc).__name__}: {exc}"))
+
+
+# a run of one block's rows in one dispatch: (block, first row, past last)
+_Fill = Tuple[PendingBlock, int, int]
+
+
+class _Batch(NamedTuple):
+    """What one take popped of one model: the online requests (first in the
+    dispatch) and the block rows that fill the rest of the tile."""
+
+    model: str
+    reqs: List[PendingRequest]
+    fill: List[_Fill]
+
+
 class _Flight:
     """One dispatch in flight: the batches it took (one a ready model), how
     many dispatches were already in flight at its take, and its own
@@ -165,8 +251,7 @@ class _Flight:
 
     __slots__ = ("batches", "ahead", "beat")
 
-    def __init__(self, batches: List[Tuple[str, List[PendingRequest]]],
-                 ahead: int):
+    def __init__(self, batches: List[_Batch], ahead: int):
         self.batches = batches
         self.ahead = ahead
         self.beat = time.monotonic()
@@ -175,7 +260,7 @@ class _Flight:
         self.beat = time.monotonic()
 
     def requests(self) -> List[PendingRequest]:
-        return [r for _, reqs in self.batches for r in reqs]
+        return [r for batch in self.batches for r in batch.reqs]
 
 
 class BucketedMicrobatcher:
@@ -243,6 +328,17 @@ class BucketedMicrobatcher:
         self._dispatch_ewma: Dict[str, float] = {}
         self._queues: Dict[str, Deque[PendingRequest]] = {
             name: deque() for name in registry.names()}
+        # the second class of request (``submit_block``), all under
+        # ``_cond``: per model the blocks with rows still to take (the head
+        # may be partly taken), the blocks handed in and not yet replied
+        # (in the order they will be), how many were handed in, and the
+        # tile a dispatch that carries block rows is padded to
+        self._blocks: Dict[str, Deque[PendingBlock]] = {
+            name: deque() for name in registry.names()}
+        self._open: Dict[str, Deque[PendingBlock]] = {
+            name: deque() for name in registry.names()}
+        self._block_seq: Dict[str, int] = {}
+        self._tiles: Dict[str, int] = {}
         # recompile accounting: the shared compile-key diff (telemetry,
         # generalized out of this file in round 10) — warmup primes it,
         # any fresh key afterwards counts under Serving.<name>::recompiles
@@ -343,6 +439,9 @@ class BucketedMicrobatcher:
         if warm:
             for bucket in self.buckets:
                 entry.warmup(int(bucket))
+            if model in self._tiles:
+                # the model has taken blocks: their tile is a shape too
+                entry.warmup(self._tiles[model])
             self._monitors[model].prime(entry.compile_keys)
         version = self.registry.swap(model, entry)
         self.counters.increment(f"Serving.{model}", "swaps")
@@ -426,6 +525,73 @@ class BucketedMicrobatcher:
             timeout_s = self.request_timeout_s + 30.0
         return self.submit_nowait(model, line).wait(timeout_s)
 
+    # -- the bulk entry (any thread) -------------------------------------------
+    def submit_block(self, model: str, lines: Sequence[str],
+                     klass: Optional[str] = None,
+                     rid: Optional[str] = None) -> PendingBlock:
+        """Hand in a block of rows WHOLE, as the class ``klass`` (a
+        ``tenant.<id>`` contract; default: the submitter's ambient tenant
+        label): the second class of request on a model, see the module
+        docstring.  Returns at once; ``PendingBlock.wait`` gives the reply
+        lines in the order of ``lines`` once the last row is answered.
+
+        Bounded in BLOCKS waiting by the class's ``queue.depth`` (the
+        grammar's default where the class has no contract): a block over
+        the bound is shed at the door, typed — whatever the online queue
+        holds, and an online shed never touches a block.  The first block
+        of a model compiles the tile shape here, on the caller's thread
+        (as ``swap`` warms), so no dispatch pays for it."""
+        entry = self.registry.get(model)            # raises UnknownModelError
+        tile = int(entry.tile_rows)
+        if tile <= self.max_bucket:
+            raise RequestError(
+                f"{model!r} has no bulk entry: its dispatches sweep no tile "
+                f"beyond the largest bucket ({self.max_bucket} rows) for "
+                f"block rows to ride in")
+        if not lines:
+            raise RequestError("an empty block is not servable")
+        klass = klass or tel.current_label("tenant") or ""
+        contract = tenancy.pool().contract(klass)
+        bound = (contract.queue_depth if contract is not None
+                 else tenancy.DEFAULT_QUEUE_DEPTH)
+        if not any(k and k[0] == tile for k in tuple(entry.compile_keys)):
+            entry.warmup(tile)
+            self._monitors[model].prime(entry.compile_keys)
+        block = PendingBlock(model, lines, klass, rid=rid)
+        with self._cond:
+            if self.failed:
+                raise self._down_error("replica is down")
+            if self._stop:
+                raise ServingError("batcher is closed")
+            waiting = len(self._blocks[model])
+            if waiting < bound:
+                self._tiles[model] = tile
+                block.seq = self._block_seq.get(model, 0)
+                self._block_seq[model] = block.seq + 1
+                block.queued = time.perf_counter()
+                self._blocks[model].append(block)
+                self._open[model].append(block)
+                if not self._in_flight(model):
+                    # otherwise the rows ride with the next online take,
+                    # or that dispatch's return notifies
+                    self._cond.notify()
+                return block
+            self.counters.increment(f"Serving.{model}", "backfill_shed")
+            if klass:
+                self.counters.increment(f"Tenant.{klass}", "shed")
+        retry_after = self.drain_estimate_s(model)
+        if klass:
+            tel.tracer().event(
+                "tenant.shed", tenant=klass, quota="queue.depth",
+                waiting=waiting, inflight=0,
+                retry_after_ms=round(retry_after * 1e3, 1))
+        raise self._attribute(TenantShedError(
+            f"{waiting} blocks of {model!r} waiting for class {klass!r} "
+            f"(queue.depth {bound}) — block shed (backpressure); retry "
+            f"after ~{retry_after:.2f}s",
+            tenant=klass, quota="queue.depth", retry_after_s=retry_after),
+            wait_s=0.0)
+
     # -- dispatch loop (DEPTH threads) ----------------------------------------
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
@@ -441,8 +607,8 @@ class BucketedMicrobatcher:
     def _in_flight(self, model: str) -> bool:
         """Whether a dispatch in flight carries a batch of ``model`` (under
         ``_cond``)."""
-        return any(name == model
-                   for flight in self._flights for name, _ in flight.batches)
+        return any(batch.model == model
+                   for flight in self._flights for batch in flight.batches)
 
     def _ready(self, now: float) -> List[str]:
         """The models a dispatcher may take a batch of now (under
@@ -454,15 +620,17 @@ class BucketedMicrobatcher:
         rejoin its group, so callers of twice a bucket settle into full
         buckets, not into fragments (PERF.md §6, PR 26 finding 2, is what a
         deadline that may cut did); a quiet model beside a saturated one is
-        not held back by the neighbour's flights."""
+        not held back by the neighbour's flights.  Block rows alone are one
+        more thing that only goes with nothing of the model in flight — and
+        then at once, with whatever online rows wait, deadline or not."""
         out = []
         for name, queue in self._queues.items():
-            if not queue:
-                continue
-            if len(queue) >= self.max_bucket or (
-                    (self._stop
-                     or now - queue[0].enqueued >= self.flush_deadline_s)
-                    and not self._in_flight(name)):
+            if len(queue) >= self.max_bucket:
+                out.append(name)
+            elif (queue or self._blocks[name]) and (
+                    self._blocks[name] or self._stop
+                    or now - queue[0].enqueued >= self.flush_deadline_s
+                    ) and not self._in_flight(name):
                 out.append(name)
         return out
 
@@ -503,14 +671,16 @@ class BucketedMicrobatcher:
                 ready = self._ready(time.monotonic())
                 if ready:
                     break
-                if self._stop and not any(self._queues.values()):
+                if self._stop and not any(self._queues.values()) \
+                        and not any(self._blocks.values()):
                     return None
                 self._cond.wait(timeout=self._next_wait(time.monotonic()))
             batches = []
             for name in ready:
                 queue = self._queues[name]
                 take = min(len(queue), self.max_bucket)
-                batches.append((name, [queue.popleft() for _ in range(take)]))
+                reqs = [queue.popleft() for _ in range(take)]
+                batches.append(_Batch(name, reqs, self._take_fill(name, take)))
             flight = _Flight(batches, ahead=len(self._flights))
             self._flights.append(flight)
             if any(self._queues.values()):
@@ -518,6 +688,22 @@ class BucketedMicrobatcher:
                 # asleep with no deadline, or with this model's
                 self._cond.notify()
             return flight
+
+    def _take_fill(self, model: str, online: int) -> List[_Fill]:
+        """Pop block rows of ``model`` into what a dispatch of ``online``
+        rows leaves of the tile, oldest block first (under ``_cond``)."""
+        blocks = self._blocks[model]
+        fill: List[_Fill] = []
+        room = self._tiles.get(model, 0) - online
+        while blocks and room > 0:
+            block = blocks[0]
+            n = min(room, len(block.lines) - block.taken)
+            fill.append((block, block.taken, block.taken + n))
+            block.taken += n
+            room -= n
+            if block.taken == len(block.lines):
+                blocks.popleft()
+        return fill
 
     def _loop(self) -> None:
         with contextlib.ExitStack() as stack:
@@ -539,7 +725,7 @@ class BucketedMicrobatcher:
                 if flight is None:
                     return
                 try:
-                    for name, reqs in flight.batches:
+                    for batch in flight.batches:
                         # refreshed PER BATCH so a dispatcher working
                         # through several slow batches reads as busy,
                         # not wedged — only true silence past the
@@ -550,7 +736,7 @@ class BucketedMicrobatcher:
                             # device call, deadlocked arbiter) trips the
                             # progress watchdog and captures a bundle
                             with blackbox.watchdog_guard("serve.dispatch"):
-                                self._dispatch(name, reqs, flight)
+                                self._dispatch(batch, flight)
                         except Exception:  # noqa: BLE001
                             # replica-fatal, injected (serve.dispatch
                             # kill) or real: every unfinished request
@@ -569,14 +755,16 @@ class BucketedMicrobatcher:
                         # the other thread may now look at a short bucket
                         self._cond.notify_all()
 
-    def _dispatch(self, model: str, reqs: List[PendingRequest],
-                  flight: _Flight) -> None:
+    def _dispatch(self, batch: _Batch, flight: _Flight) -> None:
         """One batch under its ``serve.dispatch`` span (``inflight``: the
         dispatches already in flight when it was taken, 0 or 1), then one
         retroactive ``serve.queue`` span a request: appended to the queue →
         taken by this dispatch (the span's start — batches popped together
-        wait their turn in the queue span), linked by ``dispatch``."""
+        wait their turn in the queue span), linked by ``dispatch``; and one
+        ``serve.backfill.queue`` span a run of block rows, handed in → this
+        dispatch, so that ``serve.queue`` keeps meaning online rows."""
         tracer = tel.tracer()
+        model, reqs = batch.model, batch.reqs
         # a batch joins the trace of the first request that carries one
         # (under a ScoringPlane stage: the stage's own trace)
         ctx = next((r.trace_ctx for r in reqs if r.trace_ctx is not None),
@@ -584,7 +772,7 @@ class BucketedMicrobatcher:
         with tracer.span("serve.dispatch",
                          {"model": model, "inflight": flight.ahead},
                          parent=ctx) as span:
-            self._dispatch_batch(model, reqs, span, flight)
+            self._dispatch_batch(batch, span, flight)
         if span.enabled:
             for req in reqs:
                 if req.probe:
@@ -595,9 +783,15 @@ class BucketedMicrobatcher:
                 tracer.emit_span("serve.queue", span.start - req.queued,
                                  parent=req.trace_ctx, attrs=attrs,
                                  start=req.queued)
+            for block, lo, hi in batch.fill:
+                tracer.emit_span(
+                    "serve.backfill.queue", span.start - block.queued,
+                    parent=block.trace_ctx, start=block.queued,
+                    attrs={"model": model, "dispatch": span.span_id,
+                           "block": block.seq, "rows": hi - lo})
 
-    def _dispatch_batch(self, model: str, reqs: List[PendingRequest],
-                        span, flight: _Flight) -> None:
+    def _dispatch_batch(self, batch: _Batch, span, flight: _Flight) -> None:
+        model, reqs, fill = batch.model, batch.reqs, batch.fill
         scorable = [r for r in reqs if not r.probe]
         for req in reqs:
             if req.probe:
@@ -605,7 +799,7 @@ class BucketedMicrobatcher:
                 # dispatcher without scoring (and without counters) — it
                 # proves THIS thread is alive and draining its queue
                 req.finish(result="pong")
-        if not scorable:
+        if not scorable and not fill:
             return
         if self.fault is not None:
             # the replica-kill site: fires BEFORE any request of the
@@ -628,11 +822,21 @@ class BucketedMicrobatcher:
                     wait_s=now - req.enqueued))
             else:
                 live.append(req)
-        if not live:
+        if not live and not fill:
             return
         entry = self.registry.get(model)
-        bucket = self._bucket_for(len(live))
+        # block rows ride behind the online rows, in one shape: the tile
+        lines = [r.line for r in live]
+        for block, lo, hi in fill:
+            lines.extend(block.lines[lo:hi])
+        bucket = self._tiles[model] if fill else self._bucket_for(len(live))
         span.set("rows", len(live)).set("bucket", bucket)
+        span.set("online_rows", len(live))
+        span.set("backfill_rows", len(lines) - len(live))
+        # a dispatch that carries an online row draws its slot as the plane
+        # and bounds the wait by the request timeout; block rows alone draw
+        # it as their own class, under that contract's deadline if any
+        tenant = self.tenant if live or not fill else fill[0][0].tenant
         try:
             # GraftPool (round 18): the batch draws an arbitrated device
             # slot under this plane's tenant contract before it scores —
@@ -649,11 +853,11 @@ class BucketedMicrobatcher:
             with contextlib.ExitStack() as held:
                 with tel.tracer().span("serve.slot"):
                     held.enter_context(tenancy.pool().slot(
-                        tenant=self.tenant or None,
-                        timeout_s=self.request_timeout_s,
+                        tenant=tenant or None,
+                        timeout_s=self.request_timeout_s if live else None,
                         on_wait=flight.tick))
                 t0 = time.monotonic()
-                outs = entry.score_lines([r.line for r in live], bucket)
+                outs = entry.score_lines(lines, bucket)
                 dispatch_s = time.monotonic() - t0
         except TenantShedError as exc:
             # the tenant's pool share refused this batch before any row
@@ -663,6 +867,7 @@ class BucketedMicrobatcher:
             self._attribute(exc)
             for req in live:
                 req.finish(error=exc)
+            self._fail_blocks(model, [block for block, _, _ in fill], exc)
             return
         except Exception as exc:
             # typed ServingErrors are REQUEST faults (bad rows); anything
@@ -672,15 +877,15 @@ class BucketedMicrobatcher:
                 self.on_batch_error(exc)
             # one bad row must not poison its coalesced batch neighbors:
             # re-score each request alone (smallest bucket — warmed, so no
-            # recompile) so only the genuinely bad ones fail typed
-            if len(live) > 1:
-                self._dispatch_isolated(entry, group, live, flight)
+            # recompile) so only the genuinely bad ones fail typed; each
+            # block's rows are re-scored apart from everybody else's, and
+            # a block with a bad row fails whole
+            if len(live) > 1 or fill:
+                self._dispatch_isolated(entry, group, live, fill, flight)
                 return
             self.counters.increment(group, "errors")
-            err = (exc if isinstance(exc, ServingError)
-                   else RequestError(f"{type(exc).__name__}: {exc}"))
             live[0].finish(error=self._attribute(
-                err, wait_s=time.monotonic() - live[0].enqueued))
+                _typed(exc), wait_s=time.monotonic() - live[0].enqueued))
             return
         with self._cond:
             prev = self._dispatch_ewma.get(model)
@@ -690,14 +895,16 @@ class BucketedMicrobatcher:
         if self.on_batch_ok is not None:
             self.on_batch_ok()
         self._finish_scored(entry, group, model, live, outs, bucket, flight,
-                            dispatch_s)
+                            dispatch_s, fill)
 
     def _dispatch_isolated(self, entry, group: str,
-                           reqs: List[PendingRequest],
+                           reqs: List[PendingRequest], fill: List[_Fill],
                            flight: _Flight) -> None:
         """Failure-isolation path: score each request of a failed batch
-        alone; good rows still succeed, bad rows carry their own error."""
-        model = reqs[0].model
+        alone, and each run of block rows alone; good rows still succeed,
+        bad rows carry their own error, a block with a bad row fails
+        whole."""
+        model = reqs[0].model if reqs else fill[0][0].model
         bucket = self._bucket_for(1)
         for req in reqs:
             try:
@@ -714,27 +921,46 @@ class BucketedMicrobatcher:
                         not isinstance(exc, ServingError):
                     self.on_batch_error(exc)
                 self.counters.increment(group, "errors")
-                err = (exc if isinstance(exc, ServingError)
-                       else RequestError(f"{type(exc).__name__}: {exc}"))
                 req.finish(error=self._attribute(
-                    err, wait_s=time.monotonic() - req.enqueued))
+                    _typed(exc), wait_s=time.monotonic() - req.enqueued))
                 continue
             if self.on_batch_ok is not None:
                 self.on_batch_ok()
             self._finish_scored(entry, group, model, [req], outs, bucket,
                                 flight)
+        for run in fill:
+            block, lo, hi = run
+            if block.error is not None:
+                continue                  # an earlier run already failed it
+            try:
+                with tenancy.pool().slot(tenant=block.tenant or None,
+                                         on_wait=flight.tick):
+                    outs = entry.score_lines(block.lines[lo:hi],
+                                             self._tiles[model])
+            except Exception as exc:
+                if self.on_batch_error is not None and \
+                        not isinstance(exc, ServingError):
+                    self.on_batch_error(exc)
+                self.counters.increment(group, "errors")
+                self._fail_blocks(model, [block],
+                                  self._attribute(_typed(exc)))
+                continue
+            self._finish_scored(entry, group, model, [], outs,
+                                self._tiles[model], flight, fill=[run])
 
     def _finish_scored(self, entry, group: str, model: str,
                        live: List[PendingRequest], outs: List[str],
                        bucket: int, flight: _Flight,
-                       dispatch_s: Optional[float] = None) -> None:
+                       dispatch_s: Optional[float] = None,
+                       fill: Sequence[_Fill] = ()) -> None:
         with tel.tracer().span("serve.reply"):
             self._reply(entry, group, model, live, outs, bucket, flight,
-                        dispatch_s)
+                        dispatch_s, fill)
 
     def _reply(self, entry, group: str, model: str,
                live: List[PendingRequest], outs: List[str], bucket: int,
-               flight: _Flight, dispatch_s: Optional[float]) -> None:
+               flight: _Flight, dispatch_s: Optional[float],
+               fill: Sequence[_Fill]) -> None:
         # a shape outside the warmed set means this batch paid a compile
         # on the hot path — the invariant violation the counter exposes
         # (the monitor's key feed also registers each key as a GraftProf
@@ -780,16 +1006,95 @@ class BucketedMicrobatcher:
                     attrs["program"] = pid
                 tracer.emit_span("serve.request", wait_s,
                                  parent=req.trace_ctx, attrs=attrs)
-        if not answered:
+        backfill = self._deliver(model, fill, outs[len(live):]) if fill else 0
+        if not answered and not backfill:
             return
-        self.counters.increment(group, "requests", answered)
+        if answered:
+            self.counters.increment(group, "requests", answered)
+            if self.tenant:
+                self.counters.increment(f"Tenant.{self.tenant}", "rows",
+                                        answered)
         self.counters.increment(group, "batches")
         if flight.ahead:
             # counted where ``batches`` is, so the two are one population
             self.counters.increment(group, "overlapped")
         self.counters.increment(group, f"bucket.{bucket}")
+        if backfill:
+            self.counters.increment(group, "backfill_rows", backfill)
+            if not live:
+                # device time the blocks took from nobody's online rows
+                self.counters.increment(group, "backfill_only")
         if tracer.enabled:
             tracer.gauge(f"serve.queue.{model}", len(self._queues[model]))
+
+    # -- blocks: replies, in order, whole -------------------------------------
+    def _deliver(self, model: str, fill: Sequence[_Fill],
+                 outs: Sequence[str]) -> int:
+        """Put a dispatch's reply lines into their blocks and release every
+        block that is now whole AND has no earlier block of the model still
+        open; returns the rows delivered."""
+        at, delivered = 0, 0
+        with self._cond:
+            for block, lo, hi in fill:
+                if block.error is None:
+                    block.results[lo:hi] = outs[at:at + hi - lo]
+                    block.answered += hi - lo
+                    delivered += hi - lo
+                at += hi - lo
+            released = self._release(model)
+        self._closed_blocks(model, released)
+        return delivered
+
+    def _release(self, model: str) -> List[PendingBlock]:
+        """Pop and signal, oldest first, the model's open blocks that are
+        answered whole or failed (under ``_cond``, so that no later block
+        is ever signalled before an earlier one)."""
+        open_, released = self._open[model], []
+        while open_ and (open_[0].error is not None
+                         or open_[0].answered == len(open_[0].lines)):
+            block = open_.popleft()
+            block.finished = time.perf_counter()
+            block._done.set()
+            released.append(block)
+        return released
+
+    def _closed_blocks(self, model: str,
+                       released: Sequence[PendingBlock]) -> None:
+        """Counters and the ``serve.backfill.block`` span of blocks just
+        released (outside the lock)."""
+        tracer = tel.tracer()
+        for block in released:
+            ok = block.error is None
+            self.counters.increment(
+                f"Serving.{model}",
+                "backfill_blocks" if ok else "backfill_failed")
+            if ok and block.tenant:
+                self.counters.increment(f"Tenant.{block.tenant}", "rows",
+                                        len(block.lines))
+            attrs = {"model": model, "block": block.seq,
+                     "rows": len(block.lines)}
+            if block.tenant:
+                attrs["tenant"] = block.tenant
+            if block.rid is not None:
+                attrs["rid"] = block.rid
+            tracer.emit_span("serve.backfill.block",
+                             block.finished - block.queued,
+                             parent=block.trace_ctx, attrs=attrs,
+                             status="ok" if ok else "error",
+                             start=block.queued)
+
+    def _fail_blocks(self, model: str, blocks: Sequence[PendingBlock],
+                     err: ServingError) -> None:
+        """Fail ``blocks`` whole: rows not yet taken are dropped, replies
+        still in flight are discarded when they come."""
+        with self._cond:
+            for block in blocks:
+                if block.error is None and not block.done():
+                    block.error = err
+                    if block in self._blocks[model]:
+                        self._blocks[model].remove(block)
+            released = self._release(model)
+        self._closed_blocks(model, released)
 
     # -- replica failure machinery (FleetServe, round 17) --------------------
     def _attribute(self, err: ServingError,
@@ -817,6 +1122,11 @@ class BucketedMicrobatcher:
 
         depth = len(self._queues[model])
         batches = max((depth + self.max_bucket - 1) // self.max_bucket, 1)
+        room = self._tiles.get(model, 0) - self.max_bucket
+        if room > 0:
+            # block rows ride in what the online rows leave of the tile
+            rows = sum(len(b.lines) - b.taken for b in self._blocks[model])
+            batches = max(batches, -(-rows // room))
         est = batches * (self._dispatch_ewma.get(model, 0.05)
                          + self.flush_deadline_s)
         return min(max(est, RETRY_AFTER_MIN_S), RETRY_AFTER_MAX_S)
@@ -844,9 +1154,14 @@ class BucketedMicrobatcher:
             queued = [r for q in self._queues.values() for r in q]
             for q in self._queues.values():
                 q.clear()
+            blocks = {m: list(open_) for m, open_ in self._open.items()}
             self._cond.notify_all()
         for req in stranded + queued:
             req.finish(error=self._down_error("died mid-batch", req))
+        for model, open_ in blocks.items():
+            # every block not yet replied, taken or not
+            self._fail_blocks(model, open_,
+                              self._down_error("died mid-batch"))
 
     def mark_failed(self) -> None:
         """Pool-side declaration that this replica is dead (missed
@@ -863,9 +1178,14 @@ class BucketedMicrobatcher:
             reqs = [r for q in self._queues.values() for r in q]
             for q in self._queues.values():
                 q.clear()
+            blocks = {m: list(q) for m, q in self._blocks.items() if q}
         for req in reqs:
             req.finish(error=self._down_error(reason, req))
-        return len(reqs)
+        for model, waiting in blocks.items():
+            # a block with rows still to take: what was taken of it is in
+            # a dispatch nobody will finish
+            self._fail_blocks(model, waiting, self._down_error(reason))
+        return len(reqs) + sum(map(len, blocks.values()))
 
     def stalled(self, deadline_s: float) -> bool:
         """True when the batcher has WORK but a heartbeat older than
@@ -876,7 +1196,8 @@ class BucketedMicrobatcher:
         stalled: with nothing to dispatch a stale heartbeat is just
         sleep."""
         with self._cond:
-            busy = self._dispatching or any(self._queues.values())
+            busy = self._dispatching or any(self._queues.values()) \
+                or any(self._blocks.values())
             beat = min((f.beat for f in self._flights),
                        default=self.heartbeat)
             return busy and \
@@ -957,6 +1278,11 @@ class BucketedMicrobatcher:
                     for f in self._flights for r in f.requests()]
             for q in self._queues.values():
                 rows.extend(row(r, "queued") for r in q)
+            for open_ in self._open.values():
+                rows.extend(
+                    {"rid": b.rid, "model": b.model, "tenant": b.tenant,
+                     "state": "block", "rows": len(b.lines),
+                     "answered": b.answered} for b in open_)
         return rows[:512]
 
     def close(self) -> None:

@@ -3,7 +3,9 @@
 Two transports, both stdlib-only:
 
 - :class:`ScoreHTTPServer` — a ``http.server`` JSON endpoint
-  (``POST /score`` with ``{"model": ..., "rows": [...]}``) plus health and
+  (``POST /score`` with ``{"model": ..., "rows": [...]}``; with a
+  ``"class"`` the rows are handed in WHOLE as one block of that class —
+  the bulk entry, ``BucketedMicrobatcher.submit_block``) plus health and
   stats endpoints.  Typed serving errors map to distinct HTTP statuses so a
   load balancer can tell shed (429) from overload timeout (504) from a bad
   request (400).
@@ -225,6 +227,11 @@ class ScoreHTTPServer:
                     # worker's DRR arbitration + span attribution)
                     rids = req.get("rids")
                     tenant = req.get("tenant")
+                    # the bulk entry: the rows are one block of this class
+                    # (a ``tenant.<id>`` contract, e.g. ``backfill``)
+                    klass = req.get("class")
+                    if klass is not None and not isinstance(klass, str):
+                        raise ValueError("class must be a tenant id")
                     if rids is not None and (
                             not isinstance(rids, list)
                             or len(rids) != len(rows)):
@@ -239,7 +246,7 @@ class ScoreHTTPServer:
                     return
                 try:
                     results = outer.score_rows(model, rows, rids=rids,
-                                               tenant=tenant)
+                                               tenant=tenant, klass=klass)
                 except ServingError as err:
                     self._send(_status_for(err), _error_body(err),
                                headers=_retry_after_header(err))
@@ -279,8 +286,13 @@ class ScoreHTTPServer:
 
     def score_rows(self, model: str, rows: List[str],
                    rids: Optional[List[str]] = None,
-                   tenant: Optional[str] = None) -> List[str]:
-        """Submit all rows (they microbatch together), wait for all.  The
+                   tenant: Optional[str] = None,
+                   klass: Optional[str] = None) -> List[str]:
+        """With a ``klass`` the rows are ONE block of that class through the
+        plane's bulk entry (``submit_block``: replied whole, never shed for
+        the online queue's depth nor timed out by the request timeout; the
+        wait is the caller's connection's).  Otherwise:
+        submit all rows (they microbatch together), wait for all.  The
         first typed error aborts the call; rows already queued behind it
         still score and are discarded — shed/timeout accounting stays
         truthful either way.  ``rids`` (GlobalServe) pins each row's
@@ -295,6 +307,13 @@ class ScoreHTTPServer:
         if rids is not None and len(rids) != len(rows):
             raise RequestError(
                 f"rids must pair 1:1 with rows ({len(rids)} != {len(rows)})")
+        if klass:
+            bulk = getattr(self.batcher, "submit_block", None)
+            if bulk is None:
+                raise RequestError(
+                    f"{type(self.batcher).__name__} has no bulk entry")
+            return bulk(model, rows, klass=klass,
+                        rid=rids[0] if rids else None).wait()
         scope = (_tel.label_scope(tenant=tenant) if tenant
                  else contextlib.nullcontext())
         with scope:

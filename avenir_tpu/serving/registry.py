@@ -110,6 +110,12 @@ class ServableModel:
     """
 
     family: str = ""
+    # query rows every dispatch of this entry sweeps on the device whatever
+    # it is handed (0: no more than it is handed).  Where it exceeds the
+    # batcher's largest bucket the rest of the tile is padding that block
+    # rows may ride in (``BucketedMicrobatcher.submit_block``): a dispatch
+    # that carries them is padded to this many rows, one more warmed shape
+    tile_rows: int = 0
 
     def __init__(self) -> None:
         self.compile_keys: Set[Tuple] = set()
@@ -341,7 +347,12 @@ class KNNServable(ServableModel):
     family = "knn"
 
     def __init__(self, est, model, encoder: DatasetEncoder, delim: str = ","):
+        from avenir_tpu.ops import pallas_knn
+
         super().__init__()
+        # the fused search sweeps whole TM-row query tiles: a 64-row bucket
+        # costs what a 512-row dispatch of online and block rows costs
+        self.tile_rows = pallas_knn.TM
         self.est = est
         self.model = model
         self.enc = encoder

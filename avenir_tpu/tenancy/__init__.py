@@ -27,6 +27,7 @@ from avenir_tpu.tenancy.arbiter import (  # noqa: F401
     tenant_scope,
 )
 from avenir_tpu.tenancy.contract import (  # noqa: F401
+    DEFAULT_QUEUE_DEPTH,
     TenantContract,
     contracts_from_conf,
     tenant_slo_rules,

@@ -131,6 +131,14 @@ class GraftPool:
     def contracts(self) -> Dict[str, TenantContract]:
         return {t: st.contract for t, st in self._states.items()}
 
+    def contract(self, tenant: Optional[str]) -> Optional[TenantContract]:
+        """The contract of ``tenant``, or None where it has none.  A tenant
+        id names a whole workload or — PR 34 — one CLASS of requests of a
+        serving plane that takes two (``serving/batcher.py::submit_block``:
+        the ``backfill`` blocks beside the plane's own ``serving`` rows)."""
+        state = self._states.get(tenant) if tenant else None
+        return state.contract if state is not None else None
+
     # -- the dispatch slot (any thread) --------------------------------------
     def slot(self, tenant: Optional[str] = None, cost: float = 1.0,
              timeout_s: Optional[float] = None, on_wait=None):
@@ -418,6 +426,9 @@ class _DisabledPool:
     def slot(self, tenant: Optional[str] = None, cost: float = 1.0,
              timeout_s: Optional[float] = None, on_wait=None):
         return _NULL
+
+    def contract(self, tenant: Optional[str]) -> None:
+        return None
 
     def queue_depths(self) -> Dict[str, int]:
         return {}

@@ -48,6 +48,10 @@ _POOL_WIDE_RE = re.compile(
 # with one would make the grammar ambiguous (tenant.queue.depth is the
 # DEFAULT depth, not tenant "queue"'s), so it is refused loudly
 RESERVED_IDS = frozenset({"id", "pool", "queue"})
+# ``tenant.queue.depth``'s default: what a tenant with no bound of its own
+# may keep waiting — dispatches at the arbiter, blocks at a serving plane's
+# bulk entry
+DEFAULT_QUEUE_DEPTH = 64
 
 
 @dataclass(frozen=True)
@@ -57,7 +61,7 @@ class TenantContract:
     tenant: str
     share: float                     # DRR weight (queue share)
     max_inflight: int = 0            # 0 = unbounded (pool capacity bounds)
-    queue_depth: int = 64            # waiting dispatches before shedding
+    queue_depth: int = DEFAULT_QUEUE_DEPTH   # waiting before shedding
     priority: int = 0                # strict tiers, higher first
     queue_timeout_s: Optional[float] = None   # deadline while queued
 
@@ -99,7 +103,7 @@ def contracts_from_conf(conf) -> Dict[str, TenantContract]:
                 f"{bare!r} names tenant {m.group(1)!r} which has no "
                 f"tenant.{m.group(1)}.share contract — a quota without "
                 f"a share arbitrates nothing")
-    default_depth = conf.get_int("tenant.queue.depth", 64)
+    default_depth = conf.get_int("tenant.queue.depth", DEFAULT_QUEUE_DEPTH)
     default_timeout = conf.get_float("tenant.queue.timeout.ms")
     out: Dict[str, TenantContract] = {}
     for name in sorted(names):
