@@ -511,7 +511,8 @@ def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
         cert = cert.copy()
         cert[real:] = True
     # counted once each whatever the number of shards; the kernel sweeps
-    # whole TM-row query tiles, whatever it was handed
+    # whole query tiles of the block's own height (pallas_knn.query_tile:
+    # 128, 256 or TM rows), whatever it was handed
     refused = int(cert.size - cert.sum())
     new_program = model.count_fused(real, refused, sharded=mesh is not None)
     if counts is not None:

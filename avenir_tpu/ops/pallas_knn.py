@@ -46,10 +46,20 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block shapes. TM query rows are resident per grid row; TB reference rows
-# stream through VMEM per grid step, SEG-row segment by segment.  The re-rank
-# keeps at most SLOTS candidates a row.
+# Block shapes. One query tile is resident per grid row — query_tile(m) rows,
+# the smallest of 128, 256 and TM that holds the block, so a 64-row serve
+# dispatch sweeps 128 query rows and not 512; TM is the LARGEST tile, and
+# every block above it takes whole TM-row tiles.  TB reference rows stream
+# through VMEM per grid step, SEG-row segment by segment.  The re-rank keeps
+# at most SLOTS candidates a row.
 TM = 512
+# What a caller that packs rows behind a short block should pad to, cheapest
+# first: up to 256 query rows a grid step is bound by the DMA of its TB-row
+# reference block, so rows ride for the one read of the index the block pays
+# anyway; beyond that the step is the dot's and grows with the tile, and a
+# full TM-row tile is the cheapest ROW the kernel sells (PERF.md §5 has the
+# step at each tile).  Each is a tile of query_tile's ladder.
+FILL_TILES = (256, TM)
 TB = 16384             # reference rows per grid step (one DMA, 8 segments)
 SEG = 2048             # certificate granularity: top-2 + third-min bound
 SLOTS = 128
@@ -176,11 +186,16 @@ def prepare_refs(codes: np.ndarray, cont01: np.ndarray, num_bins: int
 # Exact: true top-k ⊆ candidates unless a segment hides ≥3 of it; key
 # truncation only LOWERS a segment's bound (≤ 2⁻¹² relative): sound.
 #
-# A grid step is bound by its dot: 8192 result vregs popped from four MXUs,
-# ~16.4k cycles on a v5e.  The tournament hides beside it (a step is ~16.9k
-# bundles by the compiler's own count; PERF.md and docs/architecture.md
-# have the measured decomposition) because of three choices, each of which
-# costs the overlap if undone:
+# Dot and tournament both scale with the tile's height; the step's DMA of
+# one TB-row reference block (4.2 MB at width 128) does not.  Under 256 query
+# rows a step is bound by reading the index, ~5.8 us on a v5e: a 128-row
+# tile's weights are latched in all four MXUs and a segment's rows dealt
+# over them, 512 pops a unit and ~4.3k bundles a step by the compiler's own
+# count (PERF.md §5-§6, PR 35).  At a TM-row tile a step is bound by its
+# dot: 8192 result vregs popped from four MXUs, ~16.4k cycles.  The
+# tournament hides beside it (a step is ~16.9k bundles; PERF.md and
+# docs/architecture.md have the measured decomposition) because of three
+# choices, each of which costs the overlap if undone:
 #   - the keys are merged as FLOATS.  The v5e vector unit has no int32
 #     min/max (a compare AND a select), vmin/vmax.f32 are one operation.
 #     key + 2^23 (one exponent step, folded into the column constant) is a
@@ -188,9 +203,10 @@ def prepare_refs(codes: np.ndarray, cont01: np.ndarray, num_bins: int
 #     the 8 x 3 results of a step;
 #   - the QUERY tile is the stationary MXU operand and the references
 #     stream, so a result vreg is [8 refs, 128 queries] and a query group's
-#     running triple is three vregs.  The other way round a [512, 128]
-#     column block is 64 vregs, the whole register file, and every tree
-#     level goes through VMEM on the one store slot;
+#     running triple is three vregs (a shorter tile has fewer groups, each
+#     the same).  The other way round a [512, 128] column block is 64
+#     vregs, the whole register file, and every tree level goes through
+#     VMEM on the one store slot;
 #   - the tree pairs 8-row groups that leave the MXU next to each other, so
 #     a result is merged out of registers while the MXU delivers the next
 #     (pairing row v with row v + SEG/2 keeps half a segment in flight).
@@ -271,8 +287,9 @@ def _sublanes_top3(tri):
 def _knn_tourney_kernel(a_ref, b_ref, k1_out, k2_out, k3_out):
     nseg = TB // SEG
     a = a_ref[:]
-    seg_row = jax.lax.broadcasted_iota(jnp.int32, (nseg, TM), 0)
-    outs = [jnp.zeros((nseg, TM), jnp.float32)] * 3
+    tile = a_ref.shape[0]       # query_tile of the block: 128, 256 or TM
+    seg_row = jax.lax.broadcasted_iota(jnp.int32, (nseg, tile), 0)
+    outs = [jnp.zeros((nseg, tile), jnp.float32)] * 3
     for s in range(nseg):
         # d2ᵀ of one segment, [refs, queries]: the one bf16 MXU pass (the −2
         # of the norm expansion is folded into the reference operand)
@@ -291,13 +308,14 @@ def _tourney_keys(a_mat, b_mat):
     up to 128s): its three smallest keys, ``_PAD_KEY`` past the last one."""
     m, n = a_mat.shape[0], b_mat.shape[0]
     nseg = n // SEG
-    spec = pl.BlockSpec((TB // SEG, TM), lambda i, j: (j, i),
+    tile = query_tile(m)        # m is query_rows of the block: whole tiles
+    spec = pl.BlockSpec((TB // SEG, tile), lambda i, j: (j, i),
                         memory_space=pltpu.VMEM)
     keys = pl.pallas_call(
         _knn_tourney_kernel,
-        grid=(m // TM, n // TB),
+        grid=(m // tile, n // TB),
         in_specs=[
-            pl.BlockSpec((TM, a_mat.shape[1]), lambda i, j: (i, 0),
+            pl.BlockSpec((tile, a_mat.shape[1]), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((TB, b_mat.shape[1]), lambda i, j: (j, 0),
                          memory_space=pltpu.VMEM),
@@ -454,9 +472,18 @@ def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
 # host pack's bit for bit (tests/test_knn_sharded.py), so a shard's search is
 # the one-chip search over the same rows.
 
+def query_tile(m: int) -> int:
+    """Height of the kernel's query tile for a block of ``m`` rows: the
+    smallest of 128, 256 and TM that holds the block, TM for every larger
+    one.  The one place that decides it: the one-chip and the sharded search
+    both take their ``rows`` from :func:`fused_statics`."""
+    return next((t for t in (128, 256) if m <= t), TM)
+
+
 def query_rows(m: int) -> int:
-    """Query rows the kernels sweep for a block of ``m``: whole TM-row tiles."""
-    return _round_up(max(m, TM), TM)
+    """Query rows the kernel sweeps for a block of ``m``: whole tiles of the
+    block's :func:`query_tile`."""
+    return _round_up(max(m, 1), query_tile(m))
 
 
 def fused_statics(m: int, f: int, fc: int, k: int) -> dict:

@@ -49,17 +49,21 @@ reading from outside it leaves Naive Bayes unresolved (PERF.md §6–§7).
 ``submit`` may be called from any number of frontend threads.
 
 Two classes of request on one model (PR 34): beside single rows (``submit``)
-a model whose kernel sweeps a tile larger than the largest bucket
-(``ServableModel.tile_rows``: the fused kNN search pads every dispatch to 512
-query rows) takes whole BLOCKS through :meth:`BucketedMicrobatcher.submit_block`
+a model whose kernel has tiles larger than the largest bucket
+(``ServableModel.tile_rows``: the fused kNN search's 256 and 512 query rows)
+takes whole BLOCKS through :meth:`BucketedMicrobatcher.submit_block`
 — a day's file handed in by its class (a ``tenant.<id>`` contract, the
 ``backfill`` of docs/multitenancy.md).  A block waits in a queue of its own,
 bounded in blocks by its class's ``queue.depth``: its rows never count against
 ``serve.queue.depth``, are never timed out by ``serve.request.timeout.ms``
 and an online shed never touches them.  Every take puts the waiting online
 rows first (at most ``max(bucket)``, by the rules above, unchanged) and fills
-what is left of the tile with block rows, so the backfill advances under
-always-full online buckets in padding the kernel sweeps anyway.  Block rows
+what is left of a tile with block rows, so the backfill advances under
+always-full online buckets.  WHICH tile follows what waits: while one block
+waits — the submitter's pace is kept — the servable's first, the cheapest
+ride, which costs the online rows next to nothing; while a backlog of blocks
+waits, its last, the cheapest row, which the online rows of that dispatch
+wait for (PERF.md §5 has what each costs the kNN search).  Block rows
 go alone — a whole tile of them — only when the model has nothing in flight
 (the short bucket's own rule): at most one such dispatch at a time, so an
 online row never waits for more than the dispatch already on the chip, and a
@@ -332,13 +336,13 @@ class BucketedMicrobatcher:
         # ``_cond``: per model the blocks with rows still to take (the head
         # may be partly taken), the blocks handed in and not yet replied
         # (in the order they will be), how many were handed in, and the
-        # tile a dispatch that carries block rows is padded to
+        # tiles a dispatch that carries block rows may be padded to
         self._blocks: Dict[str, Deque[PendingBlock]] = {
             name: deque() for name in registry.names()}
         self._open: Dict[str, Deque[PendingBlock]] = {
             name: deque() for name in registry.names()}
         self._block_seq: Dict[str, int] = {}
-        self._tiles: Dict[str, int] = {}
+        self._tiles: Dict[str, Tuple[int, ...]] = {}
         # recompile accounting: the shared compile-key diff (telemetry,
         # generalized out of this file in round 10) — warmup primes it,
         # any fresh key afterwards counts under Serving.<name>::recompiles
@@ -439,9 +443,9 @@ class BucketedMicrobatcher:
         if warm:
             for bucket in self.buckets:
                 entry.warmup(int(bucket))
-            if model in self._tiles:
-                # the model has taken blocks: their tile is a shape too
-                entry.warmup(self._tiles[model])
+            for tile in self._tiles.get(model, ()):
+                # the model has taken blocks: their tiles are shapes too
+                entry.warmup(tile)
             self._monitors[model].prime(entry.compile_keys)
         version = self.registry.swap(model, entry)
         self.counters.increment(f"Serving.{model}", "swaps")
@@ -539,11 +543,11 @@ class BucketedMicrobatcher:
         grammar's default where the class has no contract): a block over
         the bound is shed at the door, typed — whatever the online queue
         holds, and an online shed never touches a block.  The first block
-        of a model compiles the tile shape here, on the caller's thread
-        (as ``swap`` warms), so no dispatch pays for it."""
+        of a model compiles the tiles' shapes here, on the caller's thread
+        (as ``swap`` warms), so no dispatch pays for one."""
         entry = self.registry.get(model)            # raises UnknownModelError
-        tile = int(entry.tile_rows)
-        if tile <= self.max_bucket:
+        tiles = tuple(int(t) for t in entry.tile_rows)
+        if not tiles or tiles[0] <= self.max_bucket:
             raise RequestError(
                 f"{model!r} has no bulk entry: its dispatches sweep no tile "
                 f"beyond the largest bucket ({self.max_bucket} rows) for "
@@ -554,9 +558,11 @@ class BucketedMicrobatcher:
         contract = tenancy.pool().contract(klass)
         bound = (contract.queue_depth if contract is not None
                  else tenancy.DEFAULT_QUEUE_DEPTH)
-        if not any(k and k[0] == tile for k in tuple(entry.compile_keys)):
-            entry.warmup(tile)
-            self._monitors[model].prime(entry.compile_keys)
+        warm = {k[0] for k in tuple(entry.compile_keys) if k}
+        for tile in tiles:
+            if tile not in warm:
+                entry.warmup(tile)
+                self._monitors[model].prime(entry.compile_keys)
         block = PendingBlock(model, lines, klass, rid=rid)
         with self._cond:
             if self.failed:
@@ -565,7 +571,7 @@ class BucketedMicrobatcher:
                 raise ServingError("batcher is closed")
             waiting = len(self._blocks[model])
             if waiting < bound:
-                self._tiles[model] = tile
+                self._tiles[model] = tiles
                 block.seq = self._block_seq.get(model, 0)
                 self._block_seq[model] = block.seq + 1
                 block.queued = time.perf_counter()
@@ -598,6 +604,10 @@ class BucketedMicrobatcher:
             if b >= n:
                 return b
         return self.max_bucket
+
+    def _tile_for(self, model: str, n: int) -> int:
+        """The smallest of the model's tiles that holds ``n`` rows."""
+        return next(t for t in self._tiles[model] if t >= n)
 
     @property
     def _dispatching(self) -> bool:
@@ -691,10 +701,15 @@ class BucketedMicrobatcher:
 
     def _take_fill(self, model: str, online: int) -> List[_Fill]:
         """Pop block rows of ``model`` into what a dispatch of ``online``
-        rows leaves of the tile, oldest block first (under ``_cond``)."""
+        rows leaves of a tile, oldest block first (under ``_cond``): of the
+        cheapest tile while the blocks keep up, of the widest while more
+        than the one being taken wait."""
         blocks = self._blocks[model]
         fill: List[_Fill] = []
-        room = self._tiles.get(model, 0) - online
+        if not blocks:
+            return fill
+        tiles = self._tiles[model]
+        room = (tiles[-1] if len(blocks) > 1 else tiles[0]) - online
         while blocks and room > 0:
             block = blocks[0]
             n = min(room, len(block.lines) - block.taken)
@@ -829,7 +844,8 @@ class BucketedMicrobatcher:
         lines = [r.line for r in live]
         for block, lo, hi in fill:
             lines.extend(block.lines[lo:hi])
-        bucket = self._tiles[model] if fill else self._bucket_for(len(live))
+        bucket = (self._tile_for(model, len(lines)) if fill
+                  else self._bucket_for(len(live)))
         span.set("rows", len(live)).set("bucket", bucket)
         span.set("online_rows", len(live))
         span.set("backfill_rows", len(lines) - len(live))
@@ -932,11 +948,11 @@ class BucketedMicrobatcher:
             block, lo, hi = run
             if block.error is not None:
                 continue                  # an earlier run already failed it
+            tile = self._tile_for(model, hi - lo)
             try:
                 with tenancy.pool().slot(tenant=block.tenant or None,
                                          on_wait=flight.tick):
-                    outs = entry.score_lines(block.lines[lo:hi],
-                                             self._tiles[model])
+                    outs = entry.score_lines(block.lines[lo:hi], tile)
             except Exception as exc:
                 if self.on_batch_error is not None and \
                         not isinstance(exc, ServingError):
@@ -945,8 +961,8 @@ class BucketedMicrobatcher:
                 self._fail_blocks(model, [block],
                                   self._attribute(_typed(exc)))
                 continue
-            self._finish_scored(entry, group, model, [], outs,
-                                self._tiles[model], flight, fill=[run])
+            self._finish_scored(entry, group, model, [], outs, tile, flight,
+                                fill=[run])
 
     def _finish_scored(self, entry, group: str, model: str,
                        live: List[PendingRequest], outs: List[str],
@@ -1122,9 +1138,10 @@ class BucketedMicrobatcher:
 
         depth = len(self._queues[model])
         batches = max((depth + self.max_bucket - 1) // self.max_bucket, 1)
-        room = self._tiles.get(model, 0) - self.max_bucket
+        room = self._tiles.get(model, (0,))[-1] - self.max_bucket
         if room > 0:
-            # block rows ride in what the online rows leave of the tile
+            # block rows ride in what the online rows leave of the widest
+            # tile: a backlog is what there is to drain
             rows = sum(len(b.lines) - b.taken for b in self._blocks[model])
             batches = max(batches, -(-rows // room))
         est = batches * (self._dispatch_ewma.get(model, 0.05)

@@ -110,12 +110,14 @@ class ServableModel:
     """
 
     family: str = ""
-    # query rows every dispatch of this entry sweeps on the device whatever
-    # it is handed (0: no more than it is handed).  Where it exceeds the
-    # batcher's largest bucket the rest of the tile is padding that block
-    # rows may ride in (``BucketedMicrobatcher.submit_block``): a dispatch
-    # that carries them is padded to this many rows, one more warmed shape
-    tile_rows: int = 0
+    # the row counts a dispatch that carries block rows behind its online
+    # rows is padded to (``BucketedMicrobatcher.submit_block``), ascending
+    # and each beyond the batcher's largest bucket; empty: no bulk entry.
+    # The first is the cheapest RIDE (what the online rows leave of it costs
+    # the device next to nothing): the fill target while the blocks keep up.
+    # The last is the cheapest ROW: the target while a backlog of blocks
+    # waits.  Each is one more warmed shape
+    tile_rows: Tuple[int, ...] = ()
 
     def __init__(self) -> None:
         self.compile_keys: Set[Tuple] = set()
@@ -350,9 +352,9 @@ class KNNServable(ServableModel):
         from avenir_tpu.ops import pallas_knn
 
         super().__init__()
-        # the fused search sweeps whole TM-row query tiles: a 64-row bucket
-        # costs what a 512-row dispatch of online and block rows costs
-        self.tile_rows = pallas_knn.TM
+        # the kernel's own fill targets: the largest query tile that still
+        # costs one read of the index, and its largest tile (PERF.md §5)
+        self.tile_rows = pallas_knn.FILL_TILES
         self.est = est
         self.model = model
         self.enc = encoder

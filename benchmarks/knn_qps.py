@@ -176,7 +176,7 @@ def measure(verify: bool = False, n_queries: int | None = None,
 
     # roofline: candidate-kernel matmul work per batch
     width = r_mat.shape[1]
-    m_pad = pallas_knn._round_up(max(n_queries, pallas_knn.TM), pallas_knn.TM)
+    m_pad = pallas_knn.query_rows(n_queries)
     flops_per_batch = 2.0 * r_mat.shape[0] * m_pad * width
     batch_dt = n_queries / pipelined
     line.update(mfu_fields(flops=flops_per_batch, dt=batch_dt,

@@ -96,20 +96,21 @@ def test_shared_scan_gram_moments_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n,m,f,bins,fc", [
-    (1_000_000, 4096, 0, 1, 9),
+@pytest.mark.parametrize("n,m,rows,f,bins,fc", [
+    (1_000_000, 4096, 4096, 0, 1, 9),
     # the benchmark's cells (perfbench/configs): a bulk block, and the serve
-    # cell's largest bucket, 64 rows swept as one 512-row query tile
-    (13 << 20, 64, 0, 1, 9),
-    (13 << 20, 4096, 0, 1, 9),
-    (1 << 24, 4096, 0, 1, 9),       # refused until PR 28: 104.25M of scoped VMEM
+    # cell's largest bucket, 64 rows swept as one 128-row query tile
+    (13 << 20, 64, 128, 0, 1, 9),
+    (13 << 20, 200, 256, 0, 1, 9),  # the 256-row tile
+    (13 << 20, 4096, 4096, 0, 1, 9),
+    (1 << 24, 4096, 4096, 0, 1, 9), # refused until PR 28: 104.25M of scoped VMEM
     # a wide schema, packed width 256: two MXU passes a segment and a
     # reference block of 16384 x 256 bf16, which the kernel's
     # vmem_limit_bytes has to go on admitting
-    (1 << 20, 4096, 6, 32, 8),
-], ids=["tournament", "tournament-13Mi-serve", "tournament-13Mi",
-        "tournament-16Mi", "tournament-wide256"])
-def test_knn_fused_search_compiles_for_v5e(one_chip, n, m, f, bins, fc):
+    (1 << 20, 4096, 4096, 6, 32, 8),
+], ids=["tournament", "tournament-13Mi-serve", "tournament-13Mi-tile256",
+        "tournament-13Mi", "tournament-16Mi", "tournament-wide256"])
+def test_knn_fused_search_compiles_for_v5e(one_chip, n, m, rows, f, bins, fc):
     """elearn-shaped references (9 continuous attributes, packed width 128),
     and one schema with categorical attributes, x a block of queries through
     the whole fused search program."""
@@ -120,7 +121,7 @@ def test_knn_fused_search_compiles_for_v5e(one_chip, n, m, f, bins, fc):
     width = pk._width(f, bins, fc)
     assert width == (256 if f else 128) and pk.fused_serves(n, k)
     statics = pk.fused_statics(m, f, fc, k)
-    assert statics["rows"] == max(m, pk.TM)
+    assert statics["rows"] == pk.query_rows(m) == rows
     compiled = pk._search_fused.lower(
         _shape((m, f), jnp.int32, one_chip),
         _shape((m, fc), jnp.float32, one_chip),
@@ -131,6 +132,9 @@ def test_knn_fused_search_compiles_for_v5e(one_chip, n, m, f, bins, fc):
         num_bins=bins, total_attrs=f + fc, **statics).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # the kernel's outputs are [segments, rows]: a block of 64 is swept as
+    # one 128-row tile and written as 128-row output blocks, not 512
+    assert f"s32[{npad // pk.SEG},{rows}]" in text
     # the query pack's bf16 limb split must reach the chip as roundings the
     # compiler cannot drop: 3 limbs each of the coordinates and the norm
     assert text.count("reduce-precision(") >= 6

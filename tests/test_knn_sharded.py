@@ -185,7 +185,8 @@ def test_sharded_fused_search_matches_brute_force_and_one_chip(
     span = _search_span(recorder())
     assert span.attrs["path"] == "sharded_fused"
     assert span.attrs["shards"] == SHARDS
-    assert span.attrs["kernel_rows"] == pk.TM and span.attrs["rows"] == m
+    assert span.attrs["kernel_rows"] == pk.query_rows(m) == 128
+    assert span.attrs["rows"] == m
     assert len(span.attrs["refused_by_shard"]) == SHARDS
     assert span.attrs["refused"] <= sum(span.attrs["refused_by_shard"])
     want_d, want_idx, next_d = _brute(model, test, k)

@@ -25,13 +25,34 @@ needs_tpu_interpret = pytest.mark.skipif(
 
 
 def _oracle(codes_q, cont_q, codes_r, cont_r, k):
-    mism = (codes_q[:, None, :] != codes_r[None, :, :]).sum(-1).astype(np.float64)
-    sq = ((cont_q[:, None, :] - cont_r[None, :, :]) ** 2).sum(-1)
-    d2 = mism + sq
-    idx = np.argsort(d2, axis=1, kind="stable")[:, :k]
     f = codes_q.shape[1] + cont_q.shape[1]
-    d = np.sqrt(np.take_along_axis(d2, idx, axis=1) / f)
-    return d, idx
+    ds, idxs = [], []
+    for at in range(0, len(codes_q), 32):       # [32, N, f] at a time
+        cq, xq = codes_q[at:at + 32], cont_q[at:at + 32]
+        mism = (cq[:, None, :] != codes_r[None, :, :]).sum(-1).astype(np.float64)
+        d2 = mism + ((xq[:, None, :] - cont_r[None, :, :]) ** 2).sum(-1)
+        idx = np.argsort(d2, axis=1, kind="stable")[:, :k]
+        ds.append(np.sqrt(np.take_along_axis(d2, idx, axis=1) / f))
+        idxs.append(idx)
+    return np.concatenate(ds), np.concatenate(idxs)
+
+
+# blocks that take each of the kernel's three query tiles: 128 rows, 256 rows,
+# and two tiles of pk.TM
+_TILE_WALK = [(24, 128), (200, 256), (600, 2 * pk.TM)]
+
+
+def test_query_tile_follows_the_block():
+    assert [pk.query_tile(m) for m in (1, 64, 128, 129, 256, 257, 512, 513,
+                                       4096)] == \
+        [128, 128, 128, 256, 256, pk.TM, pk.TM, pk.TM, pk.TM]
+    assert [pk.query_rows(m) for m, _ in _TILE_WALK] == \
+        [rows for _, rows in _TILE_WALK]
+    assert pk.query_rows(4096) == 4096 and pk.query_rows(4097) == 9 * pk.TM
+    # the kernel is handed whole tiles and takes its tile from them
+    for m in (1, 64, 129, 257, 600, 4096):
+        assert pk.query_tile(pk.query_rows(m)) == pk.query_tile(m)
+        assert pk.fused_statics(m, 0, 9, 10)["rows"] == pk.query_rows(m)
 
 
 def test_device_limb_split_rounds_with_reduce_precision(rng):
@@ -51,17 +72,22 @@ def test_device_limb_split_rounds_with_reduce_precision(rng):
         np.testing.assert_array_equal(host, np.asarray(dev))
 
 
-@pytest.mark.parametrize("f,fc", [(5, 6), (6, 8), (4, 0), (0, 5)])
+# every schema at the 128-row tile, the other two tiles on the mixed one
+@pytest.mark.parametrize("f,fc,m,rows", [
+    (f, fc, *_TILE_WALK[0]) for f, fc in [(5, 6), (6, 8), (4, 0), (0, 5)]
+] + [(5, 6, *walk) for walk in _TILE_WALK[1:]])
 @needs_tpu_interpret
-def test_search_fused_matches_oracle(rng, f, fc):
+def test_search_fused_matches_oracle(rng, f, fc, m, rows):
     # the PRODUCTION program (models/knn.py): one jitted dispatch running
     # device-side query pack -> tournament kernel -> device-side exact
     # re-rank, at a reference count the route sends here, with mixed,
-    # categorical-only and continuous-only attributes
+    # categorical-only and continuous-only attributes, and over blocks that
+    # take each of the kernel's query tiles
     import jax.numpy as jnp
 
     nb, k = 8, 5
-    n, m = 70_000, 24
+    n = 70_000
+    assert pk.query_rows(m) == rows
     codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
     cont_r = rng.random(size=(n, fc)).astype(np.float32)
     codes_q = rng.integers(0, nb, size=(m, f)).astype(np.int32)
@@ -81,18 +107,21 @@ def test_search_fused_matches_oracle(rng, f, fc):
         assert (i[cert] == oi[cert]).mean() == 1.0
 
 
-@pytest.mark.parametrize("nblocks", [3, 17, 33])
+# every column-block count under two 512-row tiles, the short tiles at one
+@pytest.mark.parametrize("nblocks,m", [
+    (nblocks, _TILE_WALK[-1][1]) for nblocks in (3, 17, 33)
+] + [(3, rows) for _, rows in _TILE_WALK[:-1]])
 @needs_tpu_interpret
-def test_tourney_keys_match_sorted_segments(rng, nblocks):
+def test_tourney_keys_match_sorted_segments(rng, nblocks, m):
     # the raw kernel outputs, before the XLA assembly, against a sort of
     # every 2048-reference segment's keys: 1, 2 and 3 column blocks of 128
-    # segments (the last two end in a partly filled block) and two query
-    # tiles. Operands are multiples of 1/8 below 2, so every partial sum of
-    # the dot is exact in f32 whatever the order, and the key's truncation
-    # (1/16 at these magnitudes) still bites.
+    # segments (the last two end in a partly filled block); one query tile
+    # of 128 rows, one of 256 and two of 512. Operands are multiples of 1/8
+    # below 2, so every partial sum of the dot is exact in f32 whatever the
+    # order, and the key's truncation (1/16 at these magnitudes) still bites.
     import jax.numpy as jnp
 
-    m, n, width = 2 * pk.TM, nblocks * pk.TB, 128
+    n, width = nblocks * pk.TB, 128
     a = rng.integers(0, 16, size=(m, width)).astype(np.float32) / 8
     b = rng.integers(0, 16, size=(n, width)).astype(np.float32) / 8
     with pltpu.force_tpu_interpret_mode():
@@ -109,6 +138,60 @@ def test_tourney_keys_match_sorted_segments(rng, nblocks):
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g[:, :nseg], w)
         assert (g[:, nseg:] == pk._PAD_KEY).all()
+
+
+@pytest.mark.parametrize("m", [64, 200])
+@needs_tpu_interpret
+def test_tourney_keys_do_not_depend_on_the_tile(rng, m):
+    # a row's keys do not depend on which rows share its tile, nor on the
+    # tile's height: the block under its own tile (128 rows for 64, 256 for
+    # 200) against the same rows padded by the caller to 512, which takes
+    # the 512-row tile.  Random bf16 operands, inexact dots: bit for bit.
+    import jax.numpy as jnp
+
+    n, width = 3 * pk.TB, 128
+    a = jnp.asarray(rng.random(size=(m, width)), jnp.bfloat16)
+    b = jnp.asarray(rng.random(size=(n, width)) * 2, jnp.bfloat16)
+    own, full = pk.query_rows(m), pk.TM
+    assert pk.query_tile(own) == own < pk.query_tile(full) == full
+    with pltpu.force_tpu_interpret_mode():
+        small, large = ([np.asarray(x) for x in pk._tourney_keys(
+            jnp.pad(a, ((0, rows - m), (0, 0))), b)] for rows in (own, full))
+    assert [x.shape[0] for x in small] == [own] * 3
+    for s_, l_ in zip(small, large):
+        np.testing.assert_array_equal(s_[:m], l_[:m])
+        assert (s_[:m, :n // pk.SEG] != pk._PAD_KEY).all()
+
+
+@needs_tpu_interpret
+def test_search_fused_is_bit_identical_across_the_three_tiles(rng):
+    # the whole program on the same 64 rows handed over alone (128-row
+    # tile), among 200 (256-row tile) and among 512 (the 512-row tile):
+    # distances, indices and certificates bit for bit, refused rows included
+    import jax.numpy as jnp
+
+    f, fc, nb, k, n, m = 3, 6, 5, 5, 70_000, 64
+    codes_r = rng.integers(0, nb, size=(n, f)).astype(np.int32)
+    cont_r = rng.random(size=(n, fc)).astype(np.float32)
+    codes_q = rng.integers(0, nb, size=(pk.TM, f)).astype(np.int32)
+    cont_q = rng.random(size=(pk.TM, fc)).astype(np.float32)
+    # six adjacent copies of a row share a segment, which then hides more
+    # than two of the five nearest of a query equal to it: refused
+    codes_r[:96], cont_r[:96] = (np.repeat(x[:16], 6, axis=0)
+                                 for x in (codes_q, cont_q))
+    got = []
+    with pltpu.force_tpu_interpret_mode():
+        r_mat, n_real = pk.prepare_refs(codes_r, cont_r, nb)
+        for rows in (m, 200, pk.TM):
+            assert pk.query_tile(rows) == pk.query_rows(rows)
+            got.append([np.asarray(x)[:m] for x in pk.search_fused(
+                codes_q[:rows], cont_q[:rows], r_mat, jnp.asarray(codes_r),
+                jnp.asarray(cont_r), n_real, nb, k, f + fc)])
+    cert = got[0][2]
+    assert cert.any() and (~cert).any()
+    for other in got[1:]:
+        for x, y in zip(got[0], other):
+            np.testing.assert_array_equal(x, y)
 
 
 # positions within a 2048-reference segment whose distances are planted

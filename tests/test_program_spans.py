@@ -298,7 +298,9 @@ def test_fused_search_spans_tile_the_call_and_count_the_tile(
     first, second = rec["knn.search"]
     assert (first.attrs["path"], first.attrs["rows"],
             first.attrs["kernel_rows"], first.attrs["refused"]) == \
-        ("fused", 8, pallas_knn.TM, 2)
+        ("fused", 8, pallas_knn.query_rows(8), 2)
+    assert pallas_knn.query_rows(8) == 128      # the smallest tile
+    # a block over 512 rows still reads whole 512-row tiles
     assert (second.attrs["rows"], second.attrs["kernel_rows"],
             second.attrs["refused"]) == (600, 2 * pallas_knn.TM, 0)
     assert [(s.attrs["rows"], s.attrs["pad_to"])

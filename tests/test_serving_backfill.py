@@ -50,7 +50,7 @@ class _TileServable(ServableModel):
     sleeps ``hold_s``."""
 
     family = "tile"
-    tile_rows = TILE
+    tile_rows = (TILE,)
 
     def __init__(self, gated=True, hold_s=0.0):
         super().__init__()
@@ -192,6 +192,39 @@ def test_full_online_bucket_overlaps_a_tile_of_block_rows():
     assert block.wait(5.0) == [f"{r},ok" for r in _rows("f", 3 * TILE)]
     got = b.counters.as_dict()["Serving.m"]
     assert got["requests"] == BUCKET and got["backfill_only"] == got["batches"] - 1
+
+
+def test_fill_takes_the_cheapest_tile_and_widens_while_a_backlog_waits():
+    """One block waiting: its rows fill what the online rows leave of the
+    servable's FIRST tile.  A second block handed in before the first is
+    taken whole: the LAST tile, until one block is left again.  Both
+    shapes are warmed by the first block, and a dispatch is padded to the
+    smallest tile that holds it."""
+    servable, b = _plane()
+    wide = 2 * TILE
+    servable.tile_rows = (TILE, wide)
+    with b._cond:
+        first = b.submit_block("m", _rows("f", 2 * TILE), klass="backfill")
+        _online(b, "o", BUCKET)
+    assert {(TILE,), (wide,)} <= servable.compile_keys
+    assert servable.wait_entered()        # on pace: the cheapest tile
+    assert servable.calls[0] == _rows("o", BUCKET) + _rows("f", ROOM)
+    assert servable.pads == [TILE]
+    with b._cond:
+        second = b.submit_block("m", _rows("g", wide), klass="backfill")
+        _online(b, "p", BUCKET)
+    assert servable.wait_entered()        # behind: the widest, over both
+    assert servable.calls[1] == _rows("p", BUCKET) + \
+        _rows("f", 2 * TILE)[ROOM:] + _rows("g", wide - 2 * BUCKET - TILE)
+    assert servable.pads[1] == wide
+    _online(b, "q", BUCKET)
+    servable.release(0)
+    assert servable.wait_entered()        # one block left: the cheapest again
+    assert len(servable.calls[2]) == TILE and servable.pads[2] == TILE
+    _close(servable, b)
+    assert first.wait(5.0) == [f"{r},ok" for r in _rows("f", 2 * TILE)]
+    assert second.wait(5.0) == [f"{r},ok" for r in _rows("g", wide)]
+    assert set(servable.pads) == {TILE, wide}
 
 
 def test_no_block_waiting_dispatches_as_before():
@@ -359,11 +392,12 @@ def test_class_queue_depth_sheds_blocks_and_only_blocks():
 
 def test_a_model_without_a_tile_beyond_its_bucket_has_no_bulk_entry():
     servable, b = _plane(gated=False)
-    servable.tile_rows = BUCKET
-    with pytest.raises(RequestError, match="no bulk entry"):
-        b.submit_block("m", _rows("f", 3), klass="backfill")
+    for none in ((), (BUCKET,)):
+        servable.tile_rows = none
+        with pytest.raises(RequestError, match="no bulk entry"):
+            b.submit_block("m", _rows("f", 3), klass="backfill")
     with pytest.raises(RequestError, match="empty block"):
-        servable.tile_rows = TILE
+        servable.tile_rows = (TILE,)
         b.submit_block("m", [], klass="backfill")
     b.close()
 
@@ -531,8 +565,8 @@ def _answers(servable, call_index):
 
 def test_a_row_reads_the_same_whichever_way_it_travels(elearn):
     system, servable, b, lines = elearn
-    tile = servable.tile_rows
-    assert tile == 512
+    tile, wide = servable.tile_rows
+    assert (tile, wide) == (256, 512)
     probe = lines[:40]                    # the rows sent every way
     # 1. direct
     direct = servable.score_lines(probe, 64)
@@ -559,13 +593,25 @@ def test_a_row_reads_the_same_whichever_way_it_travels(elearn):
         reqs = [b.submit_nowait("knn", ln) for ln in probe]
     first = [r.wait(60.0) for r in reqs]
     block.wait(60.0)
-    assert direct == online == alone[:40] == mixed[60:] == first
+    n3 = len(servable.calls)
+    # 6. a backlog of two blocks: the widest tile, the probe among its rows
+    with b._cond:
+        blocks = [b.submit_block("knn", rows, klass="backfill") for rows in
+                  (lines[100:400] + probe, lines[400:700])]
+        reqs = [b.submit_nowait("knn", ln) for ln in lines[:24]]
+    behind = blocks[0].wait(60.0)
+    blocks[1].wait(60.0)
+    assert [r.wait(60.0) for r in reqs]
+    assert direct == online == alone[:40] == mixed[60:] == first \
+        == behind[300:]
     calls = servable.calls
+    assert len(calls[n3]["lines"]) == calls[n3]["pad_to"] == wide
     assert [len(c["lines"]) for c in calls[n2:n2 + 1]] == [24 + 60 + 40]
     assert calls[n2]["pad_to"] == tile and calls[n1]["pad_to"] == tile
     ways = {"direct": _answers(servable, n0 - 1),
             "online": {}, "alone": _answers(servable, n1),
-            "mixed": _answers(servable, n2)}
+            "mixed": _answers(servable, n2),
+            "behind": _answers(servable, n3)}
     for i in range(n0, n1):
         ways["online"].update(_answers(servable, i))
     last = next(i for i in range(len(calls) - 1, -1, -1)
