@@ -71,6 +71,19 @@ block never holds the device for longer than one tile.  Rows of a block may
 ride in both dispatches in flight and come back in either order; a block is
 replied whole, and blocks of a model in the order handed in.
 
+The hand-over (PR 38): under load a request's way through the batcher is
+paid in turns of the one interpreter lock, by 128 caller threads and two
+dispatchers, so what is done once a REQUEST is kept to a C call a side.  A
+request carries a one-shot latch — ONE raw lock, acquired at construction:
+``wait`` is the lock's own ``acquire(timeout)``, ``finish`` its ``release``
+— and no ``Event``, ``Condition`` or second lock.  ``_reply`` first releases
+every request of the dispatch, result and latch and nothing else, and then
+does what they share ONCE: the latency samples (``record_many``), the
+``serve.request`` spans, the counters (``reply_passes`` beside ``batches``),
+each with the value of the one clock read before the pass.  The time-out
+check asks for the oldest request of a batch and walks the batch only where
+that one has timed out.
+
 FleetServe (round 17): a batcher is now one REPLICA of a
 :class:`~avenir_tpu.serving.pool.ReplicaPool` — ``name`` labels its spans,
 errors and journal events; ``counters``/``latency`` may be shared across
@@ -89,6 +102,7 @@ from configuration alone.
 from __future__ import annotations
 
 import contextlib
+import operator
 import threading
 import time
 from collections import deque
@@ -113,6 +127,27 @@ from avenir_tpu.utils.metrics import Counters, LatencyTracker, serving_stats
 from avenir_tpu.utils.retry import FaultPlan, InjectedFault
 
 
+def _latch():
+    """A one-shot latch (:class:`PendingRequest`, :class:`PendingBlock`):
+    ONE raw lock, held from here until its one ``release`` opens it.  A
+    waiter blocks in the lock's own acquire — no condition, no allocation
+    on either side."""
+    lock = threading.Lock()
+    lock.acquire()
+    return lock
+
+
+def _await(latch, timeout_s: Optional[float]) -> bool:
+    """Wait for ``latch`` to open; False where it has not within
+    ``timeout_s``.  Passing through leaves it open, for a second wait from
+    any thread."""
+    if not latch.acquire(
+            timeout=-1 if timeout_s is None else max(timeout_s, 0.0)):
+        return False
+    latch.release()
+    return True
+
+
 class PendingRequest:
     """One in-flight request; ``wait`` blocks until scored (or failed).
 
@@ -134,7 +169,8 @@ class PendingRequest:
     tenant's requests out of a merged fleet journal."""
 
     __slots__ = ("model", "line", "enqueued", "queued", "result", "error",
-                 "_done", "_lock", "trace_ctx", "rid", "probe", "tenant")
+                 "_latch", "_token", "_set", "trace_ctx", "rid", "probe",
+                 "tenant")
 
     def __init__(self, model: str, line: str, rid: Optional[str] = None,
                  probe: bool = False, tenant: Optional[str] = None):
@@ -146,11 +182,15 @@ class PendingRequest:
         self.queued = 0.0
         self.result: Optional[str] = None
         self.error: Optional[ServingError] = None
-        self._done = threading.Event()
-        # ``finish`` may be raced by a reply and a replica's death, from two
-        # threads: exactly one may win
-        self._lock = threading.Lock()
-        self.trace_ctx = tel.tracer().current()
+        self._latch = _latch()
+        # what ``finish`` is raced for (see there), and whether it was won
+        self._token = [None]
+        self._set = False
+        # the two context-variable reads of a submit: the span is skipped
+        # where the tracer is off (one attribute read); the tenant label is
+        # semantics and no flag says that no scope is set, so it is read
+        tracer = tel.tracer()
+        self.trace_ctx = tracer.current() if tracer.enabled else None
         self.rid = rid
         self.probe = probe
         self.tenant = tenant if tenant is not None \
@@ -162,17 +202,31 @@ class PendingRequest:
         request that already scored must NEVER be re-finished with a
         replica-death error, and one a dying replica already failed over
         is never also reported scored (the at-most-once pillar of pool
-        failover — a done request is done)."""
-        with self._lock:
-            if self._done.is_set():
-                return False
-            self.result = result
-            self.error = error
-            self._done.set()
-            return True
+        failover — a done request is done).
+
+        A reply may race ``_die`` / ``fail_pending`` from another thread.
+        The winner is whoever pops the one-element ``_token``: ``list.pop``
+        is one call that the interpreter lock makes atomic, so exactly one
+        caller gets the element and every other gets ``IndexError`` — and
+        leaves ``result`` / ``error`` alone.  The winner writes both BEFORE
+        it releases the latch, so a waiter that gets through reads the
+        final values."""
+        try:
+            self._token.pop()
+        except IndexError:
+            return False
+        self.result = result
+        self.error = error
+        self._set = True
+        self._latch.release()
+        return True
+
+    def done(self) -> bool:
+        """True once ``result`` / ``error`` are final."""
+        return self._set
 
     def wait(self, timeout_s: Optional[float] = None) -> str:
-        if not self._done.wait(timeout_s):
+        if not self._set and not _await(self._latch, timeout_s):
             raise RequestTimeout(
                 f"no response for {self.model!r} request within "
                 f"{timeout_s}s (dispatcher wedged or closed?)")
@@ -193,11 +247,13 @@ class PendingBlock:
     replied in.  ``queued`` / ``finished`` are ``time.perf_counter()`` at
     hand-in and at the last row's reply (the ``serve.backfill.block`` span).
     ``taken`` and ``answered`` count rows popped by a take and rows replied;
-    they and ``results`` change under the batcher's lock only."""
+    they and ``results`` change under the batcher's lock only.  So does the
+    block's release (``_release``: ``_set``, then the latch, once — the
+    batcher's lock decides it, nothing races)."""
 
     __slots__ = ("model", "lines", "tenant", "rid", "seq", "queued",
                  "finished", "results", "taken", "answered", "error",
-                 "_done", "trace_ctx")
+                 "_latch", "_set", "trace_ctx")
 
     def __init__(self, model: str, lines: Sequence[str], tenant: str,
                  rid: Optional[str] = None):
@@ -212,14 +268,15 @@ class PendingBlock:
         self.taken = 0
         self.answered = 0
         self.error: Optional[ServingError] = None
-        self._done = threading.Event()
+        self._latch = _latch()
+        self._set = False
         self.trace_ctx = tel.tracer().current()
 
     def done(self) -> bool:
-        return self._done.is_set()
+        return self._set
 
     def wait(self, timeout_s: Optional[float] = None) -> List[str]:
-        if not self._done.wait(timeout_s):
+        if not self._set and not _await(self._latch, timeout_s):
             raise RequestTimeout(
                 f"no reply for a block of {len(self.lines)} {self.model!r} "
                 f"rows within {timeout_s}s (dispatcher wedged or closed?)")
@@ -233,6 +290,12 @@ def _typed(exc: BaseException) -> ServingError:
     return (exc if isinstance(exc, ServingError)
             else RequestError(f"{type(exc).__name__}: {exc}"))
 
+
+_ENQUEUED = operator.attrgetter("enqueued")
+
+# the flight ring's ``serve.submit`` record, by name: a request's is a tuple
+# of the values, which the ring's snapshot turns into the dict
+_SUBMIT_FIELDS = ("rid", "model", "tenant", "depth")
 
 # a run of one block's rows in one dispatch: (block, first row, past last)
 _Fill = Tuple[PendingBlock, int, int]
@@ -493,8 +556,8 @@ class BucketedMicrobatcher:
             # ring (trace.on or not, and outside the lock) — a SIGKILLed
             # replica's bundle shows WHICH rids were in flight
             blackbox.ring_record("serve.submit",
-                                 {"rid": req.rid, "model": model,
-                                  "tenant": req.tenant, "depth": depth})
+                                 (req.rid, model, req.tenant, depth),
+                                 _SUBMIT_FIELDS)
         if shed_depth is not None:
             if self.tenant:
                 # tenant-scoped door shed: booked under the tenant (above,
@@ -808,12 +871,13 @@ class BucketedMicrobatcher:
     def _dispatch_batch(self, batch: _Batch, span, flight: _Flight) -> None:
         model, reqs, fill = batch.model, batch.reqs, batch.fill
         scorable = [r for r in reqs if not r.probe]
-        for req in reqs:
-            if req.probe:
-                # breaker half-open liveness probe: answered by the
-                # dispatcher without scoring (and without counters) — it
-                # proves THIS thread is alive and draining its queue
-                req.finish(result="pong")
+        if len(scorable) != len(reqs):
+            for req in reqs:
+                if req.probe:
+                    # breaker half-open liveness probe: answered by the
+                    # dispatcher without scoring (and without counters) —
+                    # it proves THIS thread is alive and draining its queue
+                    req.finish(result="pong")
         if not scorable and not fill:
             return
         if self.fault is not None:
@@ -826,17 +890,26 @@ class BucketedMicrobatcher:
             self.fault.hit("serve.dispatch")
         group = f"Serving.{model}"
         now = time.monotonic()
-        live: List[PendingRequest] = []
-        for req in scorable:
-            if now - req.enqueued > self.request_timeout_s:
-                self.counters.increment(group, "timeouts")
-                req.finish(error=self._attribute(RequestTimeout(
-                    f"request waited past "
-                    f"{self.request_timeout_s * 1e3:.0f} ms before dispatch"
-                    + (f" on replica {self.name!r}" if self.name else "")),
-                    wait_s=now - req.enqueued))
-            else:
-                live.append(req)
+        # once a dispatch: where the OLDEST request has not timed out none
+        # has.  It is looked up, not taken for the batch's first: the queue
+        # is in the order the submitters took ``_cond`` and ``enqueued`` is
+        # stamped just before, so a submitter that lost the interpreter
+        # between the two sits behind a younger request
+        live = scorable
+        if scorable and \
+                now - min(map(_ENQUEUED, scorable)) > self.request_timeout_s:
+            live = []
+            for req in scorable:
+                if now - req.enqueued > self.request_timeout_s:
+                    self.counters.increment(group, "timeouts")
+                    req.finish(error=self._attribute(RequestTimeout(
+                        f"request waited past "
+                        f"{self.request_timeout_s * 1e3:.0f} ms before "
+                        f"dispatch"
+                        + (f" on replica {self.name!r}" if self.name else "")),
+                        wait_s=now - req.enqueued))
+                else:
+                    live.append(req)
         if not live and not fill:
             return
         entry = self.registry.get(model)
@@ -995,38 +1068,28 @@ class BucketedMicrobatcher:
             pid = prof_mod.program_id(model, pkey)
             if dispatch_s is not None:
                 prof.sample(pkey, model, dispatch_s)
-        tracker = self.latency[model]
-        answered = 0
-        for req, out in zip(live, outs):
-            if not req.finish(result=out):
-                # failed over by ``_die`` while this batch scored: it is
-                # another replica's request now, never reported here too
-                continue
-            answered += 1
-            wait_s = done - req.enqueued
-            tracker.record(wait_s)
+        # release the group in one pass: result and latch, nothing else —
+        # a woken caller can do nothing while this thread holds the
+        # interpreter, so whatever stands between two releases only delays
+        # the last caller's wake-up.  A request ``finish`` does not win was
+        # failed over by ``_die`` while this batch scored: it is another
+        # replica's request now, never reported here too
+        won = [req for req, out in zip(live, outs) if req.finish(out)]
+        answered = len(won)
+        if won:
+            # the bookkeeping of the requests released, after: every sample
+            # and span carries the value it had inside the pass (``done``)
+            waits = [done - req.enqueued for req in won]
+            self.latency[model].record_many(waits)
             if tracer.enabled:
-                # FleetServe attribution: which replica scored this
-                # request and how long it sat queued — a shed storm or
-                # p99 excursion is triaged to ONE replica from the
-                # merged fleet journal
-                attrs = {"model": model, "bucket": bucket,
-                         "wait_ms": round(wait_s * 1e3, 3)}
-                if self.name:
-                    attrs["replica"] = self.name
-                if req.rid is not None:
-                    attrs["rid"] = req.rid
-                if req.tenant:
-                    attrs["tenant"] = req.tenant
-                if pid is not None:
-                    attrs["program"] = pid
-                tracer.emit_span("serve.request", wait_s,
-                                 parent=req.trace_ctx, attrs=attrs)
+                self._request_spans(model, bucket, pid, won, waits)
         backfill = self._deliver(model, fill, outs[len(live):]) if fill else 0
         if not answered and not backfill:
             return
         if answered:
             self.counters.increment(group, "requests", answered)
+            # of ``batches``, those whose online rows went out in one pass
+            self.counters.increment(group, "reply_passes")
             if self.tenant:
                 self.counters.increment(f"Tenant.{self.tenant}", "rows",
                                         answered)
@@ -1042,6 +1105,28 @@ class BucketedMicrobatcher:
                 self.counters.increment(group, "backfill_only")
         if tracer.enabled:
             tracer.gauge(f"serve.queue.{model}", len(self._queues[model]))
+
+    def _request_spans(self, model: str, bucket: int, pid: Optional[str],
+                       reqs: List[PendingRequest],
+                       waits: List[float]) -> None:
+        """One retroactive ``serve.request`` span a request just released.
+        FleetServe attribution: which replica scored the request and how
+        long it sat queued — a shed storm or p99 excursion is triaged to
+        ONE replica from the merged fleet journal."""
+        tracer = tel.tracer()
+        for req, wait_s in zip(reqs, waits):
+            attrs = {"model": model, "bucket": bucket,
+                     "wait_ms": round(wait_s * 1e3, 3)}
+            if self.name:
+                attrs["replica"] = self.name
+            if req.rid is not None:
+                attrs["rid"] = req.rid
+            if req.tenant:
+                attrs["tenant"] = req.tenant
+            if pid is not None:
+                attrs["program"] = pid
+            tracer.emit_span("serve.request", wait_s,
+                             parent=req.trace_ctx, attrs=attrs)
 
     # -- blocks: replies, in order, whole -------------------------------------
     def _deliver(self, model: str, fill: Sequence[_Fill],
@@ -1070,7 +1155,8 @@ class BucketedMicrobatcher:
                          or open_[0].answered == len(open_[0].lines)):
             block = open_.popleft()
             block.finished = time.perf_counter()
-            block._done.set()
+            block._set = True
+            block._latch.release()
             released.append(block)
         return released
 

@@ -61,27 +61,32 @@ DEFAULT_RING_EVENTS = 4096
 
 # -- the flight ring ---------------------------------------------------------
 
-_RING: "deque[Tuple[float, str, Optional[dict]]]" = deque(
+_RING: "deque[Tuple[float, str, Any, Optional[Tuple[str, ...]]]]" = deque(
     maxlen=DEFAULT_RING_EVENTS)
 
 
-def ring_record(ev: str, fields: Optional[dict] = None) -> None:
+def ring_record(ev: str, fields: Any = None,
+                names: Optional[Tuple[str, ...]] = None) -> None:
     """Append one event to the flight ring — the always-on hot path.
 
     One ``time.time()`` read, one tuple, one (GIL-atomic) bounded-deque
     append; no lock, no serialization, no branching on configuration.
     The tracer's emit seams call this on BOTH sides of ``trace.on``, and
     instrumentation that must stay visible with tracing off (the serving
-    submit door) calls it directly."""
-    _RING.append((time.time(), ev, fields))
+    submit door) calls it directly.  ``fields`` is a dict, or — for a
+    caller that records once a request — a tuple of values with the shared
+    tuple ``names`` that names them: the snapshot builds the dict."""
+    _RING.append((time.time(), ev, fields, names))
 
 
 def ring_snapshot() -> List[Dict[str, Any]]:
     """The ring's contents, oldest first, as journal-shaped dicts."""
     out = []
-    for ts, ev, fields in list(_RING):
+    for ts, ev, fields, names in list(_RING):
         rec = {"ts": round(ts, 6), "ev": ev}
-        if fields:
+        if names is not None:
+            rec.update(zip(names, fields))
+        elif fields:
             rec.update(fields)
         out.append(rec)
     return out

@@ -139,6 +139,25 @@ class LatencyTracker:
             self._filled = min(self._filled + 1, len(self._buf))
             self.count += 1
 
+    def record_many(self, seconds: Sequence[float]) -> None:
+        """``record`` of each of ``seconds`` in order, under one lock and
+        as one slice assignment (two where the ring wraps): what a serving
+        dispatch records of its requests, once."""
+        vals = np.asarray(seconds, np.float64)
+        n = len(vals)
+        with self._lock:
+            cap = len(self._buf)
+            # samples that later ones of the same call would overwrite
+            skip = max(n - cap, 0)
+            vals = vals[skip:]
+            at = (self._next + skip) % cap
+            head = min(len(vals), cap - at)
+            self._buf[at:at + head] = vals[:head]
+            self._buf[:len(vals) - head] = vals[head:]
+            self._next = (self._next + n) % cap
+            self._filled = min(self._filled + n, cap)
+            self.count += n
+
     def percentile(self, q: float) -> float:
         """q-th percentile in seconds over the retained window (0.0 when
         no sample was recorded yet)."""

@@ -12,6 +12,8 @@ stage, and the shared RL-loop metrics schema.
 
 import json
 import os
+import queue
+import sys
 import threading
 import time
 import urllib.request
@@ -37,8 +39,11 @@ from avenir_tpu.serving import (
     ShedError,
     UnknownModelError,
 )
+from avenir_tpu.serving.batcher import PendingRequest, _Batch, _Flight
+from avenir_tpu.telemetry import blackbox
 from avenir_tpu.telemetry import spans as tel
 from avenir_tpu.telemetry.journal import read_events
+from avenir_tpu.utils.metrics import LatencyTracker
 from avenir_tpu.utils.retry import FaultPlan
 
 
@@ -872,3 +877,349 @@ def test_stalled_reads_the_oldest_dispatch_in_flight():
     assert [r.wait(5.0) for r in reqs]
     assert not b.stalled(0.0)                     # idle is never stalled
     b.close()
+
+
+# ---------------------------------------------------------------------------
+# the hand-over: a one-shot latch a request, the group released in one pass
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def eager_switches():
+    """Hand the interpreter round every microsecond: two threads walking the
+    same requests then meet inside ``finish`` thousands of times a run."""
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        yield
+    finally:
+        sys.setswitchinterval(old)
+
+
+def _parked_batcher():
+    """A batcher whose dispatcher threads have ended without touching a
+    request: the test is the only one to reply, die or reap."""
+    registry = ModelRegistry().add("m", _EchoServable())
+    b = BucketedMicrobatcher(registry, bucket_sizes=(1, 64),
+                             request_timeout_ms=60_000.0)
+    with b._cond:
+        b._halt = True
+        b._cond.notify_all()
+    for thread in b._threads:
+        thread.join(5.0)
+        assert not thread.is_alive()
+    return registry.get("m"), b
+
+
+@pytest.mark.parametrize("rival", ["fail_pending", "_die"])
+def test_reply_raced_by_a_dying_replica_finishes_each_request_once(
+        rival, eager_switches):
+    """``_reply`` on one thread, ``fail_pending`` (the requests still
+    queued) or ``_die`` (the requests of a dispatch in flight) on another,
+    over the same 10 000 requests, a bucket of 64 at a time and both from
+    the bucket's first: each is finished exactly once, by whoever popped
+    its token; the loser's ``finish`` reports False and leaves ``result`` /
+    ``error`` as the winner wrote them; what the reply won is what it
+    counted and recorded."""
+    n = 10_000
+    entry, b = _parked_batcher()
+    reqs = [PendingRequest("m", f"r{i}") for i in range(n)]
+    chunks = [reqs[lo:lo + 64] for lo in range(0, n, 64)]
+    flights = [_Flight([_Batch("m", chunk, [])], ahead=0) for chunk in chunks]
+    start = threading.Barrier(2)
+
+    def reply():
+        for chunk, flight in zip(chunks, flights):
+            start.wait()
+            b._reply(entry, "Serving.m", "m", chunk,
+                     [f"{r.line},ok" for r in chunk], 64, flight, None, ())
+
+    def down():
+        for chunk, flight in zip(chunks, flights):
+            with b._cond:
+                if rival == "fail_pending":
+                    b._queues["m"].extend(chunk)
+                else:
+                    b._flights[:] = [flight]
+            start.wait()
+            getattr(b, rival)()
+
+    threads = [threading.Thread(target=reply), threading.Thread(target=down)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60.0)
+        assert not t.is_alive()
+    scored = failed = 0
+    for req in reqs:
+        assert req.done()
+        assert not req.finish(result="late")          # a done request is done
+        if req.error is None:
+            assert req.result == f"{req.line},ok" == req.wait(0)
+            scored += 1
+        else:
+            assert isinstance(req.error, ReplicaDownError)
+            assert req.result is None
+            with pytest.raises(ReplicaDownError):
+                req.wait(0)
+            failed += 1
+    assert scored + failed == n
+    assert b.counters.get("Serving.m", "requests") == scored
+    assert b.latency["m"].count == scored
+    # a race, not a walk-over: both sides won some (a side that won none
+    # would make this a test of one thread)
+    assert scored and failed
+    b.close()
+
+
+def _interrupted(first, at, second, inbox, outbox):
+    """Run ``first`` on this thread and, when it stands before the
+    ``at``-th instruction of ``PendingRequest.finish``, run ``second``
+    WHOLE on the worker thread behind ``inbox`` before going on: the
+    interleaving a switch of the interpreter at that instruction gives.
+    True where ``first`` got that far."""
+    code = PendingRequest.finish.__code__
+    seen = [0]
+    ran = []
+
+    def in_finish(frame, event, arg):
+        if event == "opcode":
+            if seen[0] == at:
+                inbox.put(second)
+                ran.append(outbox.get(timeout=30.0))
+            seen[0] += 1
+        return in_finish
+
+    def on_call(frame, event, arg):
+        if event == "call" and frame.f_code is code:
+            frame.f_trace_opcodes = True
+            sys.settrace(on_call)         # 3.12 honours the flag only so
+            return in_finish
+        return None
+
+    sys.settrace(on_call)
+    try:
+        first()
+    finally:
+        sys.settrace(None)
+    if not ran:                                       # past the last one
+        inbox.put(second)
+        outbox.get(timeout=30.0)
+    return bool(ran)
+
+
+@pytest.mark.parametrize("interrupted", ["reply", "rival"])
+@pytest.mark.parametrize("rival", ["fail_pending", "_die"])
+def test_finish_switched_at_every_instruction_still_has_one_winner(
+        rival, interrupted):
+    """The race above, made certain: one side is stopped before each
+    instruction of ``finish`` in turn while the other side, on a second
+    thread, finishes the same request whole.  Wherever the switch falls
+    exactly one of the two wins, and the loser changes nothing."""
+    entry, b = _parked_batcher()
+    inbox, outbox = queue.SimpleQueue(), queue.SimpleQueue()
+
+    def work():
+        for fn in iter(inbox.get, None):
+            outbox.put(fn() or True)
+
+    worker = threading.Thread(target=work, daemon=True)
+    worker.start()
+    at, reached, n = 0, True, 0
+    counted = 0
+    while reached:                                    # until past the end
+        req = PendingRequest("m", f"r{n}")
+        flight = _Flight([_Batch("m", [req], [])], ahead=0)
+        with b._cond:
+            if rival == "fail_pending":
+                b._queues["m"].append(req)
+            else:
+                b._flights[:] = [flight]
+
+        def reply():
+            b._reply(entry, "Serving.m", "m", [req], [f"{req.line},ok"], 1,
+                     flight, None, ())
+
+        sides = (reply, getattr(b, rival))
+        if interrupted == "rival":
+            sides = sides[::-1]
+        reached = _interrupted(sides[0], at, sides[1], inbox, outbox)
+        assert req.done() and not req.finish(result="late")
+        scored = b.counters.get("Serving.m", "requests") - counted
+        counted += scored
+        if req.error is None:
+            assert scored == 1 and req.wait(0) == f"{req.line},ok"
+        else:
+            assert scored == 0 and req.result is None
+            assert isinstance(req.error, ReplicaDownError)
+        # stopped before its first instruction the interrupted side loses,
+        # stopped after its last it has won
+        at += 1
+        n += 1
+    inbox.put(None)
+    worker.join(5.0)
+    assert n > 10                                     # finish is not empty
+    assert b.latency["m"].count == counted and 0 < counted < n
+    b.close()
+
+
+@pytest.mark.parametrize("case", ["timeout-then-finish", "wait-twice",
+                                  "second-finish-loses", "no-timeout",
+                                  "error-raised-every-wait"])
+def test_pending_request_latch(case):
+    req = PendingRequest("m", "x")
+    assert not req.done()
+    if case == "timeout-then-finish":
+        for timeout in (0.01, 0, -1.0):               # a past deadline is 0
+            with pytest.raises(RequestTimeout):
+                req.wait(timeout)
+        assert not req.done()
+        assert req.finish(result="late but whole")
+        assert req.done() and req.wait(0) == "late but whole"
+    elif case == "wait-twice":
+        assert req.finish(result="ok")
+        assert req.wait(1.0) == "ok" and req.wait(1.0) == "ok"
+        assert req.wait() == "ok" and req.done()
+    elif case == "second-finish-loses":
+        assert req.finish(result="scored")
+        assert not req.finish(error=ReplicaDownError("died"))
+        assert not req.finish(result="again")
+        assert req.result == "scored" and req.error is None
+        assert req.wait(0) == "scored"
+    elif case == "no-timeout":
+        got = []
+        waiter = threading.Thread(target=lambda: got.append(req.wait()))
+        waiter.start()
+        time.sleep(0.05)
+        assert waiter.is_alive() and not got          # blocked in the latch
+        assert req.finish(result="ok")
+        waiter.join(5.0)
+        assert got == ["ok"]
+    else:
+        err = ReplicaDownError("died")
+        assert req.finish(error=err)
+        for _ in range(2):
+            with pytest.raises(ReplicaDownError) as raised:
+                req.wait(1.0)
+            assert raised.value is err
+        assert req.done() and req.result is None
+
+
+@pytest.mark.parametrize("capacity,chunks", [
+    (8, [3, 3, 3, 3]),            # wraps inside the third chunk
+    (8, [8]),                     # exactly the ring
+    (8, [5, 20]),                 # more than the ring in one call
+    (8, [0, 1, 0]),               # nothing to record
+    (16, [4, 4]),                 # never wraps
+    (1, [3]),
+], ids=["wrap", "exact", "overrun", "empty", "short", "one-slot"])
+def test_record_many_is_n_records(capacity, chunks):
+    """One lock and one slice assignment leave the ring, ``count`` and the
+    percentiles exactly as N ``record`` calls do, wrap-around included."""
+    one, many = LatencyTracker(capacity), LatencyTracker(capacity)
+    at = 0
+    for n in chunks:
+        vals = [(at + i + 1) / 1000.0 for i in range(n)]
+        at += n
+        for v in vals:
+            one.record(v)
+        many.record_many(vals)
+        assert many.count == one.count == at
+        assert (many._next, many._filled) == (one._next, one._filled)
+        np.testing.assert_array_equal(many._buf, one._buf)
+    for q in (0.0, 50.0, 95.0, 99.0, 100.0):
+        assert many.percentile(q) == one.percentile(q)
+    assert many.snapshot() == one.snapshot()
+
+
+@pytest.mark.parametrize("aged,late", [
+    ([0, 2], []),                 # the batch's first and another timed out
+    ([0, 1, 2, 3], []),           # the whole batch
+    ([], []),                     # nobody: no request is walked
+    ([2], []),                    # the oldest is NOT the batch's first
+    ([0], [1]),                   # a live one a hair under the limit
+], ids=["oldest-and-another", "all", "none", "oldest-behind-a-younger",
+        "oldest-beside-a-near-miss"])
+def test_timeout_sweep_fails_every_timed_out_request_and_no_live_one(
+        aged, late):
+    """The time-out sweep looks at the oldest request once a dispatch and
+    walks the batch only where that one has timed out: every request past
+    ``serve.request.timeout.ms`` still fails typed, wherever it stands in
+    the batch, and no live one does."""
+    timeout_s = 5.0
+    servable, b = _gated()
+    b.request_timeout_s = timeout_s
+    with b._cond:                                     # one take, one batch
+        reqs = _submit(b, "a", GATED_BUCKET)
+        for i in aged:
+            reqs[i].enqueued -= 2 * timeout_s
+        for i in late:
+            reqs[i].enqueued -= timeout_s - 1.0
+    live = [r for i, r in enumerate(reqs) if i not in aged]
+    if live:
+        assert servable.wait_entered()
+        assert servable.calls[0] == [r.line for r in live]
+        servable.release(0)
+    for i, req in enumerate(reqs):
+        if i in aged:
+            with pytest.raises(RequestTimeout) as raised:
+                req.wait(5.0)
+            assert raised.value.queue_wait_ms > 2 * timeout_s * 1e3
+        else:
+            assert req.wait(5.0) == f"{req.line},ok"
+    assert b.counters.get("Serving.m", "timeouts") == len(aged)
+    b.close()
+    assert b.counters.get("Serving.m", "requests") == len(live)
+    assert len(servable.calls) == (1 if live else 0)
+
+
+@pytest.mark.parametrize("rid,tenant", [("r-7", "alpha"), (None, None)],
+                         ids=["rid-and-tenant", "bare"])
+def test_flight_ring_submit_record_is_field_for_field_the_dict(rid, tenant):
+    """The submit door hands the ring a tuple; the snapshot (what a
+    SIGKILLed replica's bundle holds) reads as the dict it was, key for key
+    and in the same order."""
+    servable, b = _gated()
+    blackbox.ring_clear()
+    with tel.label_scope(tenant=tenant):
+        first = b.submit_nowait("m", "x", rid=rid)
+        second = b.submit_nowait("m", "y", rid=rid)
+    recs = [r for r in blackbox.ring_snapshot() if r["ev"] == "serve.submit"]
+    assert [list(r) for r in recs] == [
+        ["ts", "ev", "rid", "model", "tenant", "depth"]] * 2
+    assert [{k: v for k, v in r.items() if k != "ts"} for r in recs] == [
+        {"ev": "serve.submit", "rid": rid, "model": "m", "tenant": tenant,
+         "depth": depth} for depth in (1, 2)]
+    assert all(isinstance(r["ts"], float) for r in recs)
+    json.dumps(recs)                                  # a bundle serialises it
+    outs = _finish_all(servable, b, [first, second])
+    assert outs == ["x,ok", "y,ok"]
+
+
+def test_group_is_released_before_its_bookkeeping(traced):
+    """One pass releases a dispatch's requests, the latency samples and the
+    ``serve.request`` spans are written after it — with the values of the
+    one clock read before the pass: a span's ``wait_ms`` is its request's
+    sample, and ``reply_passes`` counts the dispatch beside ``batches``."""
+    servable, b = _gated()
+    reqs = _submit(b, "a", GATED_BUCKET)
+    assert servable.wait_entered()
+    reqs += _submit(b, "b", GATED_BUCKET)
+    outs = _finish_all(servable, b, reqs)
+    assert outs == [f"{r.line},ok" for r in reqs]
+    assert b.counters.get("Serving.m", "batches") == 2
+    assert b.counters.get("Serving.m", "reply_passes") == 2
+    tracker = b.latency["m"]
+    assert tracker.count == len(reqs)
+    samples = sorted(round(s * 1e3, 3) for s in tracker._buf[:len(reqs)])
+    spans = [e for e in read_events(traced.journal_path)
+             if e.get("ev") == "span.close" and e.get("name") == "serve.request"]
+    assert sorted(e["attrs"]["wait_ms"] for e in spans) == samples
+    assert sorted(e["dur_ms"] for e in spans) == samples
+    # one clock read a dispatch: within a dispatch (four samples in a row in
+    # the ring) two requests' waits differ by exactly what their enqueue
+    # times do
+    stamps = [np.sort([r.enqueued for r in reqs[lo:lo + GATED_BUCKET]])
+              for lo in (0, GATED_BUCKET)]
+    for lo in (0, GATED_BUCKET):
+        waits = np.sort(tracker._buf[lo:lo + GATED_BUCKET])[::-1]
+        assert any(np.ptp(waits + group) < 1e-6 for group in stamps)

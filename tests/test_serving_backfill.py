@@ -31,6 +31,7 @@ from avenir_tpu.serving import (
     ModelRegistry,
     ReplicaDownError,
     RequestError,
+    RequestTimeout,
     ScoreHTTPServer,
     ServableModel,
     ShedError,
@@ -236,7 +237,8 @@ def test_no_block_waiting_dispatches_as_before():
     assert sorted(servable.pads) == [1, BUCKET]
     assert (TILE,) not in servable.compile_keys
     got = b.counters.as_dict()["Serving.m"]
-    assert set(got) == {"requests", "batches", f"bucket.{BUCKET}", "bucket.1"}
+    assert set(got) == {"requests", "batches", "reply_passes",
+                        f"bucket.{BUCKET}", "bucket.1"}
     b.close()
 
 
@@ -288,6 +290,47 @@ def test_blocks_are_released_in_the_order_handed_in():
     assert two.wait(5.0) == ["y0,ok", "y1,ok", "y2,ok"]
     assert one.finished <= two.finished
     assert [r.wait(5.0) for r in a][0] == "a0,ok"
+    _close(servable, b)
+
+
+@pytest.mark.parametrize("ending", ["replied", "failed"])
+def test_a_blocks_latch_times_out_then_opens_for_every_later_wait(ending):
+    """A block's hand-over is the request's: one raw lock.  ``wait`` with a
+    time-out on a block still in flight raises and leaves it whole; once
+    released — replied, or failed by a dying replica — ``done`` holds and
+    every later ``wait``, from any thread, returns (or raises) the same."""
+    fault = FaultPlan({"serve.dispatch": 2}) if ending == "failed" else None
+    servable, b = _plane(fault=fault)
+    block = b.submit_block("m", _rows("x", TILE + 2), klass="backfill")
+    assert servable.wait_entered()
+    for timeout in (0.01, 0, -1.0):
+        with pytest.raises(RequestTimeout):
+            block.wait(timeout)
+    assert not block.done()
+    servable.release(0)                   # ``failed``: hit 2 kills the replica
+    got = []
+
+    def wait():
+        try:
+            got.append(block.wait(5.0))
+        except ReplicaDownError as exc:
+            got.append(exc)
+
+    waiters = [threading.Thread(target=wait) for _ in range(3)]
+    for t in waiters:
+        t.start()
+    if ending == "replied":
+        assert servable.wait_entered()
+        servable.release(1)
+    for t in waiters:
+        t.join(5.0)
+        assert not t.is_alive()
+    wait()                                # and once more, after the others
+    assert block.done() and len(got) == 4
+    if ending == "replied":
+        assert all(g == [f"{r},ok" for r in _rows("x", TILE + 2)] for g in got)
+    else:
+        assert all(g is block.error for g in got)
     _close(servable, b)
 
 
