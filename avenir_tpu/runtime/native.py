@@ -3,9 +3,10 @@
 Compiles the shared library on first use (g++, kept next to the source,
 never committed; rebuilt when the source is newer) and exposes :func:`encode_bytes` — CSV
 bytes → :class:`EncodedDataset` with semantics identical to
-``DatasetEncoder.transform``. All callers must treat this as an optional fast
-path: :func:`is_available` gates it, and ``DatasetEncoder`` stays the
-portable reference implementation.
+``DatasetEncoder.transform`` — and :class:`EncoderSpecs`, the same kernel
+with an encoder's specs built once for many calls. All callers must treat
+this as an optional fast path: :func:`is_available` gates it, and
+``DatasetEncoder`` stays the portable reference implementation.
 """
 
 from __future__ import annotations
@@ -22,6 +23,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "native", "csv_encode.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+_encode = None                         # avenir_csv_encode_mt, see _get_lib
 _build_error: Optional[str] = None
 
 
@@ -102,27 +104,27 @@ def _build() -> Optional[ctypes.CDLL]:
 
 
 def _get_lib() -> Optional[ctypes.CDLL]:
-    global _lib
+    global _lib, _encode
     with _lock:
         if _lib is None and _build_error is None:
             lib = _build()
             if lib is not None:
                 i32p = ctypes.POINTER(ctypes.c_int32)
-                lib.avenir_csv_encode.restype = ctypes.c_long
-                lib.avenir_csv_encode.argtypes = [
-                    ctypes.c_char_p, ctypes.c_long, ctypes.c_char, ctypes.c_int32,
-                    i32p, i32p,
-                    ctypes.POINTER(ctypes.c_double), ctypes.POINTER(ctypes.c_int64),
-                    i32p, ctypes.c_int32, ctypes.c_char_p,
-                    i32p, ctypes.c_long,
-                    ctypes.POINTER(ctypes.c_float), ctypes.c_long,
-                    i32p,
-                    ctypes.POINTER(ctypes.c_int64), i32p,
+                vp = ctypes.c_void_p
+                # a handle of its own (``lib[...]`` is not cached on the
+                # library), whose array arguments are plain addresses: a
+                # call converts ints, not numpy arrays into ctypes pointers
+                _encode = lib["avenir_csv_encode_mt"]
+                _encode.restype = ctypes.c_long
+                _encode.argtypes = [
+                    ctypes.c_char_p, ctypes.c_long, ctypes.c_char,
+                    ctypes.c_int32,
+                    vp, vp, vp, vp, vp, ctypes.c_int32, ctypes.c_char_p,
+                    vp, ctypes.c_long, vp, ctypes.c_long,
+                    vp, vp, vp,
                     ctypes.c_long, ctypes.POINTER(ctypes.c_long),
+                    ctypes.c_int32,
                 ]
-                lib.avenir_csv_encode_mt.restype = ctypes.c_long
-                lib.avenir_csv_encode_mt.argtypes = \
-                    lib.avenir_csv_encode.argtypes + [ctypes.c_int32]
                 lib.avenir_csv_count_rows.restype = ctypes.c_long
                 lib.avenir_csv_count_rows.argtypes = [ctypes.c_char_p, ctypes.c_long]
                 lib.avenir_gather_ids_u32.restype = ctypes.c_int32
@@ -144,7 +146,8 @@ def build_error() -> Optional[str]:
     return _build_error
 
 
-def _specs_from_encoder(encoder, with_labels: bool = True) -> tuple:
+def _specs_from_encoder(encoder, with_labels: bool = True,
+                        with_ids: bool = True) -> tuple:
     """Flatten a fitted DatasetEncoder into the parallel spec arrays."""
     kinds: List[int] = []
     ordinals: List[int] = []
@@ -181,7 +184,7 @@ def _specs_from_encoder(encoder, with_labels: bool = True) -> tuple:
         nbins.append(len(encoder.class_values))
         vocab_parts.append(
             b"".join(v.encode() + b"\x1f" for v in encoder.class_values) + b"\x1e")
-    if encoder.id_field is not None:
+    if with_ids and encoder.id_field is not None:
         kinds.append(KIND_ID)
         ordinals.append(encoder.id_field.ordinal)
         widths.append(0.0)
@@ -190,6 +193,103 @@ def _specs_from_encoder(encoder, with_labels: bool = True) -> tuple:
     return (np.asarray(kinds, np.int32), np.asarray(ordinals, np.int32),
             np.asarray(widths, np.float64), np.asarray(offsets, np.int64),
             np.asarray(nbins, np.int32), b"".join(vocab_parts))
+
+
+class EncoderSpecs:
+    """A fitted encoder flattened ONCE into the kernel's spec arrays, their
+    addresses taken once too, so that a call pays for the parse and little
+    else: what a caller that encodes many small batches under one encoder
+    (a servable) builds at construction.  Read-only after construction, so
+    threads may share one.  ``with_ids=False`` leaves the id column
+    unparsed (``ids`` is then None); ``encoder`` is read by attribute only.
+    Raises RuntimeError if the native library is unavailable."""
+
+    def __init__(self, encoder, with_labels: bool = True,
+                 with_ids: bool = True):
+        if _get_lib() is None:
+            raise RuntimeError(f"native library unavailable: {_build_error}")
+        kinds, ordinals, widths, offsets, nbins, self._vocab = \
+            _specs_from_encoder(encoder, with_labels=with_labels,
+                                with_ids=with_ids)
+        self._arrays = (kinds, ordinals, widths, offsets, nbins)  # kept alive
+        self._spec_args = tuple(a.ctypes.data for a in self._arrays) + \
+            (len(kinds), self._vocab)
+        self.n_binned = len(encoder.binned_fields)
+        self.n_cont = len(encoder.cont_fields)
+        self.has_labels = with_labels and encoder.class_field is not None \
+            and bool(encoder.class_values)
+        self.has_ids = with_ids and encoder.id_field is not None
+        self.n_bins = np.array(
+            [encoder.n_bins[f.ordinal] for f in encoder.binned_fields],
+            np.int32)
+        self.class_values = list(encoder.class_values)
+        self.binned_ordinals = [f.ordinal for f in encoder.binned_fields]
+        self.cont_ordinals = [f.ordinal for f in encoder.cont_fields]
+
+    def encode(self, data: bytes, ncols: int, delim: str = ",",
+               rows: Optional[int] = None, pad_to: int = 0,
+               nthreads: int = 1):
+        """CSV bytes → EncodedDataset.  ``rows``: the number of records
+        ``data`` must hold (None: whatever it holds).  ``pad_to``: the
+        arrays hold at least that many rows, those past the data zero, as
+        ``serving/registry.py::_pad_ds`` pads.  Raises ValueError on a data
+        error or a record count other than ``rows``."""
+        from avenir_tpu.core.encoding import EncodedDataset
+
+        max_rows = _lib.avenir_csv_count_rows(data, len(data)) \
+            if rows is None else rows
+        size = max(max_rows, pad_to)
+        nb, nc = max(self.n_binned, 1), max(self.n_cont, 1)
+        codes = np.zeros((size, nb), np.int32)
+        cont = np.zeros((size, nc), np.float32)
+        labels = np.zeros(max_rows, np.int32) if self.has_labels else None
+        id_off = np.zeros(max_rows, np.int64) if self.has_ids else None
+        id_len = np.zeros(max_rows, np.int32) if self.has_ids else None
+        err_row = ctypes.c_long(0)
+        got = _encode(
+            data, len(data), delim.encode(), ncols, *self._spec_args,
+            codes.ctypes.data, nb, cont.ctypes.data, nc,
+            None if labels is None else labels.ctypes.data,
+            None if id_off is None else id_off.ctypes.data,
+            None if id_len is None else id_len.ctypes.data,
+            max_rows, ctypes.byref(err_row), nthreads)
+        if got < 0:
+            raise ValueError(
+                f"{_ERRORS.get(got, 'parse error')} at row {err_row.value}")
+        if rows is not None and got != rows:
+            raise ValueError(f"{got} records where {rows} were expected")
+        ids = _gather_ids(data, id_off[:got], id_len[:got]) \
+            if self.has_ids and got else None
+        keep = max(got, pad_to)
+        return EncodedDataset(
+            codes=codes[:keep, :self.n_binned],
+            cont=cont[:keep, :self.n_cont],
+            labels=labels[:got] if labels is not None else None,
+            ids=ids, n_bins=self.n_bins.copy(),
+            class_values=list(self.class_values),
+            binned_ordinals=list(self.binned_ordinals),
+            cont_ordinals=list(self.cont_ordinals),
+            valid_rows=got if keep > got else None)
+
+
+def _gather_ids(data: bytes, off: np.ndarray, ln: np.ndarray) -> np.ndarray:
+    """The id fields at (offset, length) of ``data``.  A native gather of
+    the byte ranges, widened to UCS4, directly into U-dtype memory
+    (null-padded; numpy drops trailing nulls): one pass, no numpy
+    temporaries, no astype — the numpy gather + astype('U') pair this
+    replaces dominated encode time.  U-dtype (not object): no per-row
+    PyObject creation; elements compare equal to str."""
+    rows = len(off)
+    maxlen = max(int(ln.max()), 1)
+    chars = np.empty((rows, maxlen), np.uint32)  # gather fills every slot
+    ascii_ok = _lib.avenir_gather_ids_u32(
+        data, off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        ln.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        rows, chars.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)), maxlen)
+    if ascii_ok:
+        return chars.view(f"<U{maxlen}")[:, 0]
+    return np.array([data[off[i]:off[i] + ln[i]].decode()   # non-ASCII ids:
+                     for i in range(rows)], dtype=object)  # slow exact path
 
 
 def encode_bytes(data: bytes, encoder, ncols: int, delim: str = ",",
@@ -202,82 +302,10 @@ def encode_bytes(data: bytes, encoder, ncols: int, delim: str = ",",
     ``nthreads`` worker threads (default: up to 8 or the CPU count) with
     output identical to the single-threaded path.
     """
-    from avenir_tpu.core.encoding import EncodedDataset
-
-    lib = _get_lib()
-    if lib is None:
-        raise RuntimeError(f"native library unavailable: {_build_error}")
-    kinds, ordinals, widths, offsets, nbins, vocab_blob = \
-        _specs_from_encoder(encoder, with_labels=with_labels)
-    n_binned = len(encoder.binned_fields)
-    n_cont = len(encoder.cont_fields)
-    max_rows = lib.avenir_csv_count_rows(data, len(data))
-    codes = np.zeros((max_rows, max(n_binned, 1)), np.int32)
-    cont = np.zeros((max_rows, max(n_cont, 1)), np.float32)
-    has_labels = with_labels and encoder.class_field is not None and \
-        bool(encoder.class_values)
-    labels = np.zeros(max_rows, np.int32) if has_labels else None
-    has_ids = encoder.id_field is not None
-    id_off = np.zeros(max_rows, np.int64) if has_ids else None
-    id_len = np.zeros(max_rows, np.int32) if has_ids else None
-    err_row = ctypes.c_long(0)
     if nthreads is None:
         nthreads = min(os.cpu_count() or 1, 8)
-    rows = lib.avenir_csv_encode_mt(
-        data, len(data), ctypes.c_char(delim.encode()), ncols,
-        kinds.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        ordinals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        widths.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        nbins.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        len(kinds), vocab_blob,
-        codes.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        max(n_binned, 1),
-        cont.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        max(n_cont, 1),
-        (labels.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
-         if labels is not None else None),
-        (id_off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
-         if id_off is not None else None),
-        (id_len.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
-         if id_len is not None else None),
-        max_rows, ctypes.byref(err_row), nthreads)
-    if rows < 0:
-        raise ValueError(
-            f"{_ERRORS.get(rows, 'parse error')} at row {err_row.value}")
-    ids = None
-    if has_ids and rows:
-        # id extraction: native gather of the id byte ranges, widened to
-        # UCS4, directly into U-dtype memory (null-padded; numpy drops
-        # trailing nulls). One pass, no numpy temporaries, no astype — the
-        # numpy gather + astype('U') pair this replaces dominated encode
-        # time. U-dtype (not object): no per-row PyObject creation;
-        # elements compare equal to str.
-        off = id_off[:rows]
-        ln = id_len[:rows]
-        maxlen = max(int(ln.max()), 1)
-        chars = np.empty((rows, maxlen), np.uint32)  # gather fills every slot
-        ascii_ok = lib.avenir_gather_ids_u32(
-            data, off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            ln.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            rows, chars.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            maxlen)
-        if ascii_ok:
-            ids = chars.view(f"<U{maxlen}")[:, 0]
-        else:                            # non-ASCII ids: slow exact path
-            ids = np.array([data[off[i]:off[i] + ln[i]].decode()
-                            for i in range(rows)], dtype=object)
-    return EncodedDataset(
-        codes=codes[:rows, :n_binned] if n_binned else np.zeros((rows, 0), np.int32),
-        cont=cont[:rows, :n_cont] if n_cont else np.zeros((rows, 0), np.float32),
-        labels=labels[:rows] if labels is not None else None,
-        ids=ids,
-        n_bins=np.array([encoder.n_bins[f.ordinal] for f in encoder.binned_fields],
-                        np.int32),
-        class_values=list(encoder.class_values),
-        binned_ordinals=[f.ordinal for f in encoder.binned_fields],
-        cont_ordinals=[f.ordinal for f in encoder.cont_fields],
-    )
+    return EncoderSpecs(encoder, with_labels=with_labels).encode(
+        data, ncols, delim, nthreads=nthreads)
 
 
 def iter_encoded_native(path: str, encoder, ncols: int, delim: str = ",",

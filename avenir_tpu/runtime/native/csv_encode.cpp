@@ -142,6 +142,10 @@ struct ColumnSpec {
 
 bool parse_double_slow(const char* s, size_t n, double* out) {
   if (n == 0) return false;
+  // strtod also reads hex ("0x10") and "nan(...)": Python's float() refuses
+  // both, and so does the encoder it mirrors
+  for (size_t i = 0; i < n; ++i)
+    if (s[i] == 'x' || s[i] == 'X' || s[i] == '(') return false;
   // fields are short: stack buffer avoids a heap allocation per field
   char tmp[64];
   if (n < sizeof(tmp)) {
@@ -260,7 +264,7 @@ long encode_range(
     const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
     const char* line_end = nl ? nl : end;
     const char* trimmed = line_end;
-    if (trimmed > p && trimmed[-1] == '\r') --trimmed;
+    while (trimmed > p && trimmed[-1] == '\r') --trimmed;  // as rstrip("\r")
     // skip blank AND whitespace-only lines: the Python ingest path filters
     // on line.strip(), so a line of spaces/tabs must not parse as a 1-field
     // row here and fail the ragged-record check
@@ -388,7 +392,7 @@ long count_rows_range(const char* p, const char* end) {
     const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
     const char* line_end = nl ? nl : end;
     const char* trimmed = line_end;
-    if (trimmed > p && trimmed[-1] == '\r') --trimmed;
+    while (trimmed > p && trimmed[-1] == '\r') --trimmed;  // as rstrip("\r")
     if (trimmed > p) ++rows;
     p = nl ? nl + 1 : end;
   }

@@ -88,6 +88,63 @@ def _parse_rows(lines: Sequence[str], delim: str,
     return rows
 
 
+class _LineEncoder:
+    """Request lines → the encoded batch padded to ``pad_to``, in one pass
+    of the C++ CSV encoder the job ingest uses (``runtime/native.py``), its
+    specs built once, here.  ``_parse_rows`` + ``transform`` stay the
+    fallback and the oracle: they run whole wherever the library is
+    missing, the first line is narrower than the schema reads, a line holds
+    a newline, or the native pass refuses the bytes or finds another number
+    of records than lines — so a bad request fails with exactly the typed
+    error it always did.  The id column is not parsed (no scorer reads
+    ``ds.ids``).  ``enc`` is read by attribute only; nothing here changes
+    after construction, so the batcher's dispatcher threads share one.
+
+    Spans: ``servable.encode`` over the whole call (``rows``; ``native_rows``
+    = ``rows`` on the native pass, 0 on the fallback), ``servable.parse``
+    inside it on the fallback only."""
+
+    def __init__(self, enc: DatasetEncoder, delim: str):
+        from avenir_tpu.runtime import native
+
+        self.enc, self.delim = enc, delim
+        self.max_ordinal = enc.max_ordinal(False)
+        self.specs = None
+        # the library is found (or built) here, at load, never on a call
+        if native.is_available() and len(delim.encode()) == 1 and \
+                (enc._fitted or enc.schema_complete(with_labels=False)):
+            self.specs = native.EncoderSpecs(enc, with_labels=False,
+                                             with_ids=False)
+
+    def __call__(self, lines: Sequence[str], pad_to: int) -> EncodedDataset:
+        tracer = tel.tracer()
+        n = len(lines)
+        with tracer.span("servable.encode",
+                         {"rows": n, "native_rows": n}) as span:
+            ds = self._native(lines, pad_to)
+            if ds is None:
+                span.set("native_rows", 0)
+                with tracer.span("servable.parse"):
+                    rows = _parse_rows(lines, self.delim, self.max_ordinal)
+                ds = _pad_ds(self.enc.transform(rows, with_labels=False),
+                             pad_to)
+        return ds
+
+    def _native(self, lines: Sequence[str],
+                pad_to: int) -> Optional[EncodedDataset]:
+        if self.specs is None or not lines or pad_to < len(lines):
+            return None
+        ncols = lines[0].rstrip("\r").count(self.delim) + 1
+        text = "\n".join(lines)
+        if ncols <= self.max_ordinal or text.count("\n") != len(lines) - 1:
+            return None
+        try:
+            return self.specs.encode(text.encode(), ncols, self.delim,
+                                     rows=len(lines), pad_to=pad_to)
+        except ValueError:        # a refused field; UnicodeEncodeError too
+            return None
+
+
 def _complete_encoder(conf: JobConfig) -> DatasetEncoder:
     """A transform-ready encoder straight from the schema: online scoring
     has no training pass to fit vocabularies from, so the schema must fully
@@ -153,6 +210,7 @@ class NaiveBayesServable(ServableModel):
         self.delim = delim
         self.cost = cost
         self.ambiguity_threshold = ambiguity_threshold
+        self._encode = _LineEncoder(encoder, delim)
         model.scoring_params()            # device upload happens at load
 
     @classmethod
@@ -183,8 +241,7 @@ class NaiveBayesServable(ServableModel):
             ambiguity_threshold=self.ambiguity_threshold)
 
     def score_lines(self, lines: Sequence[str], pad_to: int) -> List[str]:
-        rows = _parse_rows(lines, self.delim, self.enc.max_ordinal(False))
-        ds = _pad_ds(self.enc.transform(rows, with_labels=False), pad_to)
+        ds = self._encode(lines, pad_to)
         self.compile_keys.add((pad_to,))
         result = self._score_ds(ds)
         out = []
@@ -221,6 +278,7 @@ class LogisticServable(ServableModel):
         self.delim = delim
         self.threshold = threshold
         self.weights = jnp.asarray(np.asarray(weights), jnp.float32)
+        self._encode = _LineEncoder(encoder, delim)
 
     @classmethod
     def from_conf(cls, conf: JobConfig) -> "LogisticServable":
@@ -249,8 +307,7 @@ class LogisticServable(ServableModel):
     def score_lines(self, lines: Sequence[str], pad_to: int) -> List[str]:
         from avenir_tpu.models import logistic as mlr
 
-        rows = _parse_rows(lines, self.delim, self.enc.max_ordinal(False))
-        x = self._design(self.enc.transform(rows, with_labels=False))
+        x = self._design(self._encode(lines, len(lines)))
         x = np.pad(x, ((0, pad_to - x.shape[0]), (0, 0)))
         self.compile_keys.add((pad_to,))
         probs, pred = mlr.predict_batch(self.weights, x,
@@ -359,6 +416,7 @@ class KNNServable(ServableModel):
         self.model = model
         self.enc = encoder
         self.delim = delim
+        self._encode = _LineEncoder(encoder, delim)
 
     @classmethod
     def from_conf(cls, conf: JobConfig) -> "KNNServable":
@@ -400,18 +458,14 @@ class KNNServable(ServableModel):
         return cls(est, model, enc, delim=conf.field_delim)
 
     def score_lines(self, lines: Sequence[str], pad_to: int) -> List[str]:
-        # the work is a call of its own so that its locals (the parsed
-        # rows, the encoded batch) are freed inside the span too
+        # the work is a call of its own so that its locals (the encoded
+        # batch) are freed inside the span too
         with tel.tracer().span("servable.score",
                                {"rows": len(lines), "pad_to": pad_to}):
             return self._score(lines, pad_to)
 
     def _score(self, lines: Sequence[str], pad_to: int) -> List[str]:
-        tracer = tel.tracer()
-        with tracer.span("servable.parse"):
-            rows = _parse_rows(lines, self.delim, self.enc.max_ordinal(False))
-        with tracer.span("servable.encode"):
-            ds = _pad_ds(self.enc.transform(rows, with_labels=False), pad_to)
+        ds = self._encode(lines, pad_to)
         self.compile_keys.add((pad_to,))
         result = self.est.predict(self.model, ds)
         if result.refused:
@@ -421,7 +475,7 @@ class KNNServable(ServableModel):
             # is this call's own (``KNNResult.refused``): the model's
             # counters also move with the other dispatch in flight
             self.compile_keys.add(("fallback", result.refused))
-        with tracer.span("servable.format"):
+        with tel.tracer().span("servable.format"):
             return [
                 f"{line}{self.delim}"
                 f"{self.model.class_values[int(result.predicted[i])]}"

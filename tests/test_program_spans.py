@@ -267,13 +267,17 @@ def test_batcher_round_trip_is_one_tree_a_request(rng, recorder, session):
         assert search.attrs["kernel_rows"] == search.attrs["rows"]
     for name, parent in (("serve.slot", "serve.dispatch"),
                          ("serve.reply", "serve.dispatch"),
-                         ("servable.parse", "servable.score"),
                          ("servable.encode", "servable.score"),
                          ("servable.format", "servable.score"),
                          ("knn.weights", "knn.classify"),
                          ("knn.vote", "knn.classify")):
         assert len(rec[name]) == len(dispatches)
         assert all(by_id[r.parent_id].name == parent for r in rec[name])
+    # every line took the native encoder: no Python parse opened
+    assert "servable.parse" not in rec
+    for e in rec["servable.encode"]:
+        d = dispatches[by_id[e.parent_id].parent_id]
+        assert e.attrs["rows"] == e.attrs["native_rows"] == d.attrs["rows"]
     scores = rec["servable.score"]
     assert [s.attrs["pad_to"] for s in scores] == \
         [d.attrs["bucket"] for d in dispatches.values()]

@@ -360,24 +360,28 @@ def test_bad_request_rows_fail_typed(ws):
 def test_bad_request_does_not_poison_batch_neighbors(ws):
     """A malformed row coalesced into the same bucket as valid concurrent
     requests must fail alone: the batcher isolates a failed batch and
-    re-scores each member, so the valid rows still succeed."""
+    re-scores each member, so the valid rows still succeed.  In the kNN
+    bucket the native encoder refuses the ragged block first and the
+    Python path raises the typed error the batcher isolates on."""
     j, churn = ws["j"], ws["churn"]
     good = read_lines(j("test.csv"))[:3]
-    b, _, _ = _batcher({**churn, "bayesian.model.file.path": j("nb_model"),
-                        "serve.models": "naiveBayes",
-                        "serve.bucket.sizes": "1,8",
-                        "serve.flush.deadline.ms": "100"})
-    try:
-        oracle = [b.submit("naiveBayes", ln) for ln in good]
-        pend = [b.submit_nowait("naiveBayes", ln)
-                for ln in [good[0], "too,few", good[1], good[2]]]
-        assert pend[0].wait(30.0) == oracle[0]
-        with pytest.raises(RequestError):
-            pend[1].wait(30.0)
-        assert [pend[2].wait(30.0), pend[3].wait(30.0)] == oracle[1:]
-        assert b.counters.get("Serving.naiveBayes", "errors") == 1
-    finally:
-        b.close()
+    for model, artifact in (
+            ("naiveBayes", {"bayesian.model.file.path": j("nb_model")}),
+            ("knn", {"training.data.path": j("train.csv")})):
+        b, _, _ = _batcher({**churn, **artifact, "serve.models": model,
+                            "serve.bucket.sizes": "1,8",
+                            "serve.flush.deadline.ms": "100"})
+        try:
+            oracle = [b.submit(model, ln) for ln in good]
+            pend = [b.submit_nowait(model, ln)
+                    for ln in [good[0], "too,few", good[1], good[2]]]
+            assert pend[0].wait(30.0) == oracle[0]
+            with pytest.raises(RequestError):
+                pend[1].wait(30.0)
+            assert [pend[2].wait(30.0), pend[3].wait(30.0)] == oracle[1:]
+            assert b.counters.get(f"Serving.{model}", "errors") == 1
+        finally:
+            b.close()
 
 
 def test_registry_config_errors(ws):
