@@ -114,27 +114,27 @@ class KNNModel:
         return c
 
     def device_packed(self, num_bins: int):
-        """Packed bf16 operand for the fused pallas kernel (cached: repeated
-        queries must not re-pack or re-upload the reference set)."""
+        """The index placed on the one device (cached per ``num_bins``):
+        (packed bf16 operand, codes, normalised continuous columns, N) — the
+        one-chip twin of :meth:`device_sharded`.  The rows are uploaded once:
+        the exact re-rank gathers from them, and the device builds the fused
+        kernel's operand from them (ops/pallas_knn.py::pack_refs).  The host
+        computes only the rows' squared norms."""
         from avenir_tpu.ops import pallas_knn
         with self._lock:
             cache = self.__dict__.setdefault("_dev_packed", {})
             if num_bins not in cache:
-                cache[num_bins] = pallas_knn.prepare_refs(
-                    self.codes, self.cont01(), num_bins)
+                n = self.num_refs
+                with tel.tracer().span("knn.place", {
+                        "refs": n, "shards": 1, "shard_rows": n,
+                        "operand_rows": pallas_knn.operand_rows(n)}):
+                    codes, cont01 = (jax.device_put(a) for a in
+                                     (self.codes, self.cont01()))
+                    r_mat = pallas_knn.pack_refs(
+                        codes, cont01, _row_norms(self.cont01()), num_bins)
+                    cache[num_bins] = (jax.block_until_ready(r_mat), codes,
+                                       cont01, n)
             return cache[num_bins]
-
-    def device_rerank_arrays(self):
-        """Reference codes + normalized continuous columns resident on
-        device (cached) — the fused search's exact re-rank gathers candidate
-        rows from these instead of running single-core numpy per batch."""
-        import jax.numpy as jnp
-        with self._lock:
-            c = self.__dict__.get("_dev_rerank")
-            if c is None:
-                c = self.__dict__["_dev_rerank"] = (
-                    jnp.asarray(self.codes), jnp.asarray(self.cont01()))
-            return c
 
     def sharded_index(self, mesh):
         """The index placed over ``mesh`` by :meth:`device_sharded`, or None
@@ -143,13 +143,13 @@ class KNNModel:
         return self.__dict__.get("_dev_sharded_index", {}).get(mesh)
 
     def device_sharded(self, mesh, num_bins: int):
-        """The sharded twin of :meth:`device_packed` and
-        :meth:`device_rerank_arrays` (cached per mesh): the reference rows in
-        ``data`` contiguous row shards, each device holding its shard's codes
-        and normalised coordinates — what the exact re-rank gathers from and
-        what the exact scan of refused rows reads — and the packed bf16
-        operand it built from them itself (parallel/collectives.py::
-        sharded_knn_pack).  The host computes only the rows' squared norms."""
+        """The sharded twin of :meth:`device_packed` (cached per mesh): the
+        reference rows in ``data`` contiguous row shards, each device holding
+        its shard's codes and normalised coordinates — what the exact re-rank
+        gathers from and what the exact scan of refused rows reads — and the
+        packed bf16 operand it built from them itself (parallel/collectives.py
+        ::sharded_knn_pack).  The host computes only the rows' squared
+        norms."""
         from avenir_tpu.ops import pallas_knn
         from avenir_tpu.parallel import collectives
         from avenir_tpu.parallel.mesh import data_sharding, pad_batch
@@ -474,8 +474,7 @@ def _nearest_neighbors_fused(model: KNNModel, test: EncodedDataset, k: int,
     m, f = test.codes.shape
     fc = test.cont.shape[1]
     if mesh is None:
-        r_mat, n = model.device_packed(nb)
-        codes_r, cont01_r = model.device_rerank_arrays()
+        r_mat, codes_r, cont01_r, n = model.device_packed(nb)
 
         def launch(cont01_q):
             return pallas_knn.search_fused(test.codes, cont01_q, r_mat,

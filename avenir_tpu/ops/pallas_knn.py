@@ -166,8 +166,8 @@ def _pack(codes: np.ndarray, cont01: np.ndarray, num_bins: int,
 
 def prepare_refs(codes: np.ndarray, cont01: np.ndarray, num_bins: int
                  ) -> Tuple[jax.Array, int]:
-    """Packed device-resident reference operand [operand_rows(N), K] bf16,
-    built on the host, and N."""
+    """Packed reference operand [operand_rows(N), K] bf16 built on the host,
+    and N.  No route calls it: it is the tests' oracle for pack_refs_dev."""
     n = codes.shape[0]
     return _pack(codes, cont01, num_bins, operand_rows(n), True, _PADC), n
 
@@ -466,11 +466,14 @@ def search_fused(codes_q: np.ndarray, cont01_q: np.ndarray, r_mat: jax.Array,
 # ---------------------------------------------------------------------------
 # the reference operand packed on the device that holds the rows
 # ---------------------------------------------------------------------------
-# A row-sharded index (models/knn.py::KNNModel.device_sharded) packs every
-# shard where it lives, under shard_map: four one-core host packs in a row
-# would be minutes of set-up at 13 x 2^20 rows a shard.  The operand is the
-# host pack's bit for bit (tests/test_knn_sharded.py), so a shard's search is
-# the one-chip search over the same rows.
+# An index is packed where it lives: on one chip (models/knn.py::KNNModel.
+# device_packed, :func:`pack_refs`) and on every shard of a row-sharded one
+# (KNNModel.device_sharded, under shard_map), from the rows uploaded for the
+# re-rank.  The host pack (:func:`_pack`: one core's numpy over 7 GB of f32
+# staging at 13 x 2^20 rows) is the tests' oracle: the operand equals it
+# value for value, a pad column's sign of zero aside (tests/
+# test_knn_sharded.py), so a shard's search is the one-chip search over the
+# same rows.
 
 def query_tile(m: int) -> int:
     """Height of the kernel's query tile for a block of ``m`` rows: the
@@ -550,3 +553,11 @@ def pack_refs_dev(codes: jax.Array, cont01: jax.Array, norm: jax.Array,
 
     return jax.lax.fori_loop(0, rows // chunk, body,
                              jnp.zeros((rows, width), jnp.bfloat16))
+
+
+@functools.partial(jax.jit, static_argnames="num_bins")
+def pack_refs(codes: jax.Array, cont01: jax.Array, norm: jax.Array,
+              num_bins: int) -> jax.Array:
+    """The packed operand of an index on one device, built there from its
+    rows: :func:`pack_refs_dev` with every row given real."""
+    return pack_refs_dev(codes, cont01, norm, codes.shape[0], num_bins)

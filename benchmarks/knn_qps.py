@@ -106,8 +106,7 @@ def measure(verify: bool = False, n_queries: int | None = None,
 
     from avenir_tpu.ops import pallas_knn
     nb = int(model.n_bins.max())
-    r_mat, n = model.device_packed(nb)
-    cr_dev, cx_dev = model.device_rerank_arrays()
+    r_mat, cr_dev, cx_dev, n = model.device_packed(nb)
     # bare distance-dot canary against the ACTUAL packed reference buffer:
     # the measured lower bound the fused kernel is judged against — if QPS
     # moves while this stays put, the kernel regressed; if both move

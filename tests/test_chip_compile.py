@@ -195,3 +195,23 @@ def test_sharded_fused_knn_compiles_for_four_v5e_chips(topo):
     mem = packed.memory_analysis()
     assert mem.output_size_in_bytes == shard * 128 * 2      # bf16, one shard
     assert mem.temp_size_in_bytes < 1e9
+
+
+def test_one_chip_knn_pack_compiles_for_v5e(one_chip):
+    """The one-chip cells' placement (perfbench/configs/elearn_knn.json):
+    13 x 2^20 elearn-shaped references packed on the chip that holds them
+    (models/knn.py::KNNModel.device_packed), in place.  Its limb split must
+    reach the compiled program as reduce-precision: the TPU compiler drops
+    an ``astype`` round trip as excess precision (PERF.md, PR 23,
+    Finding 7)."""
+    from avenir_tpu.ops import pallas_knn as pk
+
+    n, fc = 13 << 20, 9
+    compiled = pk.pack_refs.lower(
+        _shape((n, 0), jnp.int32, one_chip),
+        _shape((n, fc), jnp.float32, one_chip),
+        _shape((n,), jnp.float32, one_chip), num_bins=1).compile()
+    assert compiled.as_text().count("reduce-precision(") >= 6
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == n * pk._width(0, 1, fc) * 2
+    assert mem.temp_size_in_bytes < 1e9

@@ -275,8 +275,8 @@ def stub_fused(monkeypatch):
 
     monkeypatch.setattr(knn_mod, "_pallas_available", lambda metric, k: True)
     monkeypatch.setattr(pallas_knn, "fused_serves", lambda n_real, k: True)
-    monkeypatch.setattr(pallas_knn, "prepare_refs",
-                        lambda codes, cont01, nb: (None, cont01.shape[0]))
+    monkeypatch.setattr(pallas_knn, "pack_refs",
+                        lambda codes, cont01, norm, nb: None)
     monkeypatch.setattr(pallas_knn, "search_fused", fake)
     return state
 

@@ -1,7 +1,8 @@
 """The row-sharded kNN index (models/knn.py::KNNModel.device_sharded) and the
 fused search over it (parallel/collectives.py::sharded_knn_fused) against a
-numpy brute force over the concatenated references; and the one-chip route
-through the same body (models/knn.py::_nearest_neighbors_fused).
+numpy brute force over the concatenated references; the one-chip index
+(KNNModel.device_packed), packed on the device as the host packs; and the
+one-chip route through the same body (models/knn.py::_nearest_neighbors_fused).
 
 Four of the eight forced host devices, the Pallas kernels in Mosaic interpret
 mode; ``_pallas_available`` is patched to say what it says on a TPU."""
@@ -138,6 +139,83 @@ def test_sharded_index_holds_each_shards_own_host_pack(rng, mesh):
     np.testing.assert_array_equal(np.asarray(codes_s)[:n], codes)
 
 
+def _host_pack_refused(*_a, **_k):
+    raise AssertionError("a route called the host pack")
+
+
+@pytest.mark.parametrize("n,f,fc,nb", [
+    (2 * pk.TB, 0, 9, 1),       # the deployment's width, whole TB blocks
+    (3000, 3, 4, 5),            # categorical codes, one block mostly pad
+    (40_001, 2, 3, 7),          # n a multiple of neither TB nor the chunk
+])
+def test_one_chip_index_is_packed_on_the_device_as_the_host_packs(
+        rng, monkeypatch, n, f, fc, nb):
+    codes, cont = _random(rng, n, f, fc, nb)
+    model = mknn.fit_knn(_ds(codes, cont, nb))
+    host_pack = pk._pack
+    monkeypatch.setattr(pk, "_pack", _host_pack_refused)
+    r_mat, codes_r, cont01_r, n_real = model.device_packed(nb)
+    assert n_real == n and r_mat.dtype == jnp.bfloat16
+    assert r_mat.shape == (pk.operand_rows(n), pk._width(f, nb, fc))
+    want = host_pack(codes, model.cont01(), nb, pk.operand_rows(n), True,
+                     pk._PADC)
+    np.testing.assert_array_equal(np.asarray(r_mat, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(codes_r), codes)
+    np.testing.assert_array_equal(np.asarray(cont01_r), model.cont01())
+
+
+def test_one_chip_search_never_takes_the_host_pack(rng, on_tpu, monkeypatch,
+                                                   recorder):
+    n, m, k = 20_000, 8, 5
+    model = mknn.fit_knn(_ds(*_random(rng, n, 0, 9)))
+    test = _ds(*_random(rng, m, 0, 9))
+    monkeypatch.setattr(pk, "_pack", _host_pack_refused)
+    d, idx = mknn.nearest_neighbors(model, test, k)
+    assert _search_span(recorder()).attrs["path"] == "fused"
+    want_d, want_idx, next_d = _brute(model, test, k)
+    np.testing.assert_allclose(d, want_d, atol=3e-6)
+    _same_neighbours(idx, want_idx, want_d, next_d)
+
+
+def test_one_chip_operand_is_packed_from_the_rerank_arrays(rng, monkeypatch):
+    """One upload: the operand is built from the very device arrays the
+    exact re-rank gathers from, and the host gives only the norms."""
+    model = mknn.fit_knn(_ds(*_random(rng, 5000, 2, 3)))
+    read, pack = [], pk.pack_refs
+
+    def spy(*args, **kwargs):
+        read.append(args)
+        return pack(*args, **kwargs)
+
+    monkeypatch.setattr(pk, "pack_refs", spy)
+    placed = model.device_packed(5)
+    assert model.device_packed(5) is placed and len(read) == 1
+    codes, cont01, norm, _nb = read[0]
+    assert codes is placed[1] and cont01 is placed[2]
+    np.testing.assert_array_equal(norm, mknn._row_norms(model.cont01()))
+
+
+def test_one_chip_index_is_placed_once_under_one_span(rng, recorder):
+    import threading
+
+    n = 5000
+    model = mknn.fit_knn(_ds(*_random(rng, n, 2, 3)))
+    threads = [threading.Thread(target=model.device_packed, args=(5,))
+               for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    model.device_packed(5)
+    places = [r for r in recorder() if r.name == "knn.place"]
+    assert len(places) == 1
+    assert {a: places[0].attrs[a] for a in
+            ("refs", "shards", "shard_rows", "operand_rows")} == {
+        "refs": n, "shards": 1, "shard_rows": n,
+        "operand_rows": pk.operand_rows(n)}
+
+
 # -- the route ------------------------------------------------------------------
 
 def test_sharded_route_off_a_tpu_is_the_scan(mesh):
@@ -210,10 +288,9 @@ def test_sharded_fused_search_matches_brute_force_and_one_chip(
             jnp.asarray(test.codes), jnp.asarray(q01), r_mat, codes_s,
             cont01_s, jnp.int32(n)))
     assert by_shard.tolist() == span.attrs["refused_by_shard"]
-    one_mat, _n = model.device_packed(nb)
+    one_mat, codes_r, cont01_r, _n = model.device_packed(nb)
     od, oi, ocert = (np.asarray(a) for a in pk.search_fused(
-        test.codes, q01, one_mat, *model.device_rerank_arrays(), n, nb, k,
-        f + fc))
+        test.codes, q01, one_mat, codes_r, cont01_r, n, nb, k, f + fc))
     both = scert & ocert
     assert both.sum() >= m // 2
     np.testing.assert_array_equal(sd[both], od[both])

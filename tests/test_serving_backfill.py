@@ -717,9 +717,7 @@ def test_pad_rows_that_fail_the_certificate_are_not_rescanned(monkeypatch):
     monkeypatch.setattr(pallas_knn, "search_fused", search)
     monkeypatch.setattr(mknn, "_nearest_neighbors_xla", scan)
     monkeypatch.setattr(mknn.KNNModel, "device_packed",
-                        lambda self, nb: (None, self.num_refs))
-    monkeypatch.setattr(mknn.KNNModel, "device_rerank_arrays",
-                        lambda self: (None, None))
+                        lambda self, nb: (None, None, None, self.num_refs))
     counts = {}
     d, idx = mknn.nearest_neighbors(model, test, k, counts=counts)
     assert scanned == [1] and counts == {"refused": 1}
